@@ -23,9 +23,8 @@ from __future__ import annotations
 def bench_buckets() -> None:
     from bench import _on_tpu, emit, run_finetune
 
-    # batch 48 is the measured-best padded config (BENCH_EXTRA.md batch
-    # sweep: 64 pays ~10% in XLA spill copies at 512 width) — the padded
-    # baseline must run at ITS best, or the bucketing win is inflated
+    # batch 48 is the headline's padded config — the padded baseline
+    # must run at ITS best, or the bucketing win is inflated
     # by the baseline's self-inflicted spills
     kwargs = dict(model_kwargs={}, per_chip_batch=48 if _on_tpu() else 8,
                   min_len=50, max_len=600, batches=14, warmup_epochs=1)

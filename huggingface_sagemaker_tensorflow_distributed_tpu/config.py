@@ -272,19 +272,6 @@ class TrainConfig:
         default_factory=lambda: _env("TPU_MODEL_DIR", "SM_MODEL_DIR", default="/tmp/model")
     )
 
-    # --- compilation ---
-    # persistent XLA compilation cache: recompiles across runs (and across
-    # bucket widths, restarts, resumes) become disk hits. Empty string
-    # disables. ~3x faster warm startup measured on TPU.
-    # HSTD_COMPILE_CACHE_DIR is the documented env knob (the launcher
-    # sets it per job root so every host of a job shares one cache);
-    # TPU_COMPILATION_CACHE_DIR kept as the legacy spelling.
-    compilation_cache_dir: str = field(
-        default_factory=lambda: _env(
-            "HSTD_COMPILE_CACHE_DIR", "TPU_COMPILATION_CACHE_DIR",
-            default=os.path.join(os.path.expanduser("~"), ".cache", "hstd-xla"))
-    )
-
     # --- observability ---
     log_every_steps: int = 10
     profile: bool = False          # capture a jax.profiler trace of a few steps

@@ -259,7 +259,7 @@ class T5Attention(nn.Module):
                 mask = step_mask if mask is None else mask + step_mask
                 cache_offset = cur
 
-        # ring mode (sequence parallelism, VERDICT r1 weak #7): the first
+        # ring mode (sequence parallelism): the first
         # block threads the RAW [num_buckets, heads] bias table (ndim 2)
         # instead of a materialized [1, h, q, k] bias, and the encoder
         # self-attention recomputes per-step bias tiles inside the ring —

@@ -26,14 +26,13 @@ from typing import Optional
 ENV_PEAK = "HSTD_PEAK_TFLOPS"
 
 # bf16 peak matmul TFLOP/s per chip, by jax device_kind substring
-# (public spec-sheet numbers; lowercase substring → peak). Order
-# matters: more specific markers first.
+# (public spec-sheet numbers; lowercase substring → peak). A device the
+# table does not know has no peak: None, never a neighbour's number.
 PEAK_TFLOPS_TABLE = (
     ("v6", 918.0),        # v6e / Trillium
     ("v5p", 459.0),
     ("v5 lite", 197.0),   # v5e reports device_kind "TPU v5 lite"
     ("v5e", 197.0),
-    ("v5", 459.0),        # bare "v5" after the lite variants: v5p
     ("v4", 275.0),
     ("v3", 123.0),
     ("v2", 46.0),

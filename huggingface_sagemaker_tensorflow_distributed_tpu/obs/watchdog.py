@@ -207,12 +207,20 @@ def compile_budget_env() -> Optional[float]:
 class CompileTracker:
     """Counts every XLA compilation via ``jax.monitoring`` listeners.
 
-    Emits one ``compile`` event per observed compilation with the
-    running count and cumulative seconds — the compile-vs-data-vs-step
-    attribution the throughput accounting needs (persistent-cache disk
-    hits surface as near-zero durations). Listener registration is
-    process-global in jax and cannot be unregistered, so ``install``
-    wires one module-level hook that follows the live ObsState.
+    ``count`` is the number of backend compile requests
+    (``backend_compile_duration``: one per executable, persistent-cache
+    disk hits included, with near-zero durations); ``cum_secs`` adds the
+    lowering stage, and one ``compile`` event is emitted per observed
+    stage — the compile-vs-data-vs-step attribution the throughput
+    accounting needs. ``jaxpr_trace_duration`` is NOT observed: jax
+    records it for every nested trace (thousands per model, each outer
+    one containing the inner ones) and on every miss of jit's C++
+    dispatch cache (e.g. the first call that passes a device array
+    where warm-up passed numpy) even when the traced program and its
+    executable are already cached, so it is neither a compilation nor
+    additive. Listener registration is process-global in jax and
+    cannot be unregistered, so ``install`` wires one module-level hook
+    that follows the live ObsState.
 
     With a compile budget (``HSTD_COMPILE_BUDGET_S``, ROADMAP
     "Compile-time budget"), the first crossing of cumulative compile
@@ -221,7 +229,8 @@ class CompileTracker:
     (via ``obs.compile_budget_exceeded``) to stop minting new widths.
     """
 
-    _MARKERS = ("compile", "tracing", "lowering")
+    LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
     def __init__(self, state: ObsState, budget_s: Optional[float] = None):
         self.state = state
@@ -231,13 +240,13 @@ class CompileTracker:
         self.budget_exceeded = False
         self._lock = threading.Lock()
 
-    def observe(self, event: str, secs: float) -> None:
-        low = event.lower()
-        if not any(m in low for m in self._MARKERS):
+    def observe(self, event: str, secs: float, **_kwargs) -> None:
+        if event not in (self.LOWERING, self.BACKEND_COMPILE):
             return
         crossed = False
         with self._lock:
-            self.count += 1
+            if event == self.BACKEND_COMPILE:
+                self.count += 1
             self.cum_secs += secs
             count, cum = self.count, self.cum_secs
             if (self.budget_s is not None and cum > self.budget_s
@@ -252,8 +261,7 @@ class CompileTracker:
             msg = (f"cumulative XLA compile time {cum:.1f}s exceeds "
                    f"{ENV_COMPILE_BUDGET}={self.budget_s:g}s after "
                    f"{count} compilations — bucket ladders will stop "
-                   "minting new widths; consider a persistent compile "
-                   "cache (HSTD_COMPILE_CACHE_DIR) or fewer bucket rungs")
+                   "minting new widths; consider fewer bucket rungs")
             if self.state.events is not None:
                 self.state.events.emit("alert", {
                     "name": "compile_budget", "message": msg,
@@ -269,18 +277,14 @@ _INSTALLED: list[CompileTracker] = []
 
 def install_compile_tracker(state: ObsState) -> Optional[CompileTracker]:
     """Idempotent per ObsState; returns the tracker (None if telemetry
-    is disabled or jax.monitoring is unavailable)."""
+    is disabled)."""
     if not state.enabled:
         return None
     for tracker in _INSTALLED:
         if tracker.state is state:
             return tracker
-    try:
-        from jax import monitoring
-    except ImportError:
-        return None
-    if not hasattr(monitoring, "register_event_duration_secs_listener"):
-        return None
+    from jax import monitoring
+
     tracker = CompileTracker(state)
     monitoring.register_event_duration_secs_listener(tracker.observe)
     _INSTALLED.append(tracker)

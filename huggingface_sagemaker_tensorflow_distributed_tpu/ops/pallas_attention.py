@@ -184,6 +184,7 @@ def _flash_fwd_call(q, k, v, mask, scale, block_q, block_k, causal, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),        # running sum
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return (outs[0], outs[1]) if want_lse else (outs[0], None)
 
@@ -352,6 +353,7 @@ def _flash_bwd_call(q, k, v, mask, o, lse, do, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32),
                         pltpu.VMEM((block_q, 128), jnp.float32)],  # Δ
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*base_args)
 
     # kv-major grid: (b, h, ik, iq) with q innermost
@@ -396,6 +398,7 @@ def _flash_bwd_call(q, k, v, mask, o, lse, do, scale, block_q, block_k,
         out_shape=out_shapes,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*base_args)
 
     if has_mask:
@@ -449,8 +452,44 @@ def flash_attention(q, k, v, mask=None, scale=None, block_q: int = 512,
         return xla_attention(q, k, v, mask=mask, scale=scale)
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    return _flash_vjp(q, k, v, mask, scale, block_q, block_k, causal,
-                      interpret, window)
+
+    def kernel(q, k, v, mask):
+        return _flash_vjp(q, k, v, mask, scale, block_q, block_k, causal,
+                          interpret, window)
+
+    return _shard_over_mesh(kernel, q.shape)(q, k, v, mask)
+
+
+def _shard_over_mesh(kernel, q_shape):
+    """Under an ambient mesh of more than one device (the Trainer's
+    jitted steps, the TP serve engine), run ``kernel(q, k, v, mask)``
+    per shard: a Mosaic kernel cannot be partitioned by GSPMD — jax
+    refuses to lower one under a multi-device jit ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map") — so the batch is split over the data axes and, where
+    the head count divides, the heads over ``tensor``, exactly the
+    layout the surrounding matmuls already have. Same shape as the
+    fused vocab-CE wrapping in ``train/trainer.py``."""
+    from jax.sharding import PartitionSpec as P
+
+    from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
+        AXIS_TENSOR,
+        data_axis_names,
+        maybe_current_mesh,
+    )
+
+    mesh = maybe_current_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel
+    heads = (AXIS_TENSOR
+             if q_shape[1] % mesh.shape.get(AXIS_TENSOR, 1) == 0 else None)
+    qkv = P(data_axis_names(), heads, None, None)
+    # check_vma=False: pallas_call does not annotate varying-mesh axes
+    # on its outputs, which the default vma check rejects
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(qkv, qkv, qkv, P(data_axis_names(), None, None, None)),
+        out_specs=qkv, check_vma=False)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))

@@ -10,25 +10,34 @@ not FLOPs (the read-amplification PagedAttention's motivating analysis
 names; Kwon et al. 2023 pay a single fused read here). This kernel
 folds the gather into the attention read:
 
-- **grid** ``(slot, kv_head, context_block)`` with the context-block
-  axis innermost, so the online-softmax state (running max / sum /
-  output accumulator, Dao et al. 2022 — the same recurrence
+- **grid** ``(slot, context_block)`` with the context-block axis
+  innermost, so the online-softmax state (running max / sum / output
+  accumulator, Dao et al. 2022 — the same recurrence
   ``ops/pallas_attention.py`` blocks over) lives in VMEM scratch across
-  one slot-head's context walk;
+  one slot's context walk. One tile holds ALL kv heads of one pool
+  block, ``(1, block_size, H_kv, D)``: Mosaic requires a block's last
+  two dimensions to equal the array's or be multiples of (8, 128), and
+  a one-head slice of a ``[blocks, block_size, H_kv, D]`` pool is
+  neither (the shape this kernel had until it first met the compiler);
 - **block-table indirection in the BlockSpec index maps**: the tables
   (and per-slot context lengths) ride scalar prefetch
   (``pltpu.PrefetchScalarGridSpec``), so tile ``i`` of slot ``s`` DMAs
   pool block ``tables[s, i]`` straight from the paged pool — no dense
   intermediate ever exists in HBM;
+- **vector-unit arithmetic, heads on sublanes**: a decode query is one
+  row per head, so QKᵀ and PV are a lane reduction and a block-axis
+  reduction over the ``[block_size, H_kv, D]`` tile instead of
+  M=1 matmuls — every head advances in the same instruction and no
+  per-head slice or relayout of the tile is needed;
 - **context masking in-kernel**: keys at logical positions ≥
   ``context_lens[s]`` (stale block tails, null-block junk) are masked
   to −1e30 in-tile, and whole tiles past the context skip compute via
   ``pl.when`` (the dynamic analogue of ``pallas_attention._tile_runs``
   — the grid is static per width bucket, the work is not);
 - **GQA query grouping**: the ``H // H_kv`` query heads of one KV head
-  attend in one tile (``[G, D]`` query block), so grouped-query models
-  read each KV block exactly once — the repeat the XLA path
-  materializes never happens;
+  attend against the same resident tile (a static loop over the group),
+  so grouped-query models read each KV block exactly once — the repeat
+  the XLA path materializes never happens;
 - **sliding-window banding**: with ``window`` set, tiles entirely
   BELOW the band (newest key ≤ ``ctx − 1 − window``) skip compute too
   — the banded-tile inequality of ``_tile_runs``, driven by the
@@ -66,14 +75,17 @@ _NEG_INF = -1e30
 
 def _paged_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                   o_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
-                  window):
-    """One (slot, kv_head, context_block) tile. ``tbl_ref``/``ctx_ref``
-    are the scalar-prefetched block tables / context lengths (also
-    consumed by the BlockSpec index maps — the gather indirection);
-    ``ks_ref``/``vs_ref`` are None on fp pools."""
+                  window, groups):
+    """One (slot, context_block) tile over all kv heads.
+    ``tbl_ref``/``ctx_ref`` are the scalar-prefetched block tables /
+    context lengths (also consumed by the BlockSpec index maps — the
+    gather indirection); ``ks_ref``/``vs_ref`` are None on fp pools.
+    ``q_ref``/``o_ref`` are ``[1, G, H_kv, D]`` and the scratch
+    ``[G, H_kv, ·]``: group-major, so one group's heads are a leading
+    index away."""
     s_idx = pl.program_id(0)
-    i = pl.program_id(2)
-    num_blocks = pl.num_programs(2)
+    i = pl.program_id(1)
+    num_blocks = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _init():
@@ -92,45 +104,42 @@ def _paged_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)               # [G, D]
-        k = k_ref[0, :, 0, :]                             # [bs, D]
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0].astype(jnp.float32)                  # [bs, Hkv, D]
+        v = v_ref[0].astype(jnp.float32)
         if ks_ref is not None:
             # in-tile dequant: int8 block × fp32 per-(pos, head) scale
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0, :]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0, :]
-        s_log = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [G, bs] fp32
+            k = k * ks_ref[0]                             # [bs, Hkv, 1]
+            v = v * vs_ref[0]
         pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, s_log.shape, 1)
+            jnp.int32, (k.shape[0], k.shape[1], 1), 0)
         keep = pos < ctx
         if window is not None:
             # the decode query sits at position ctx-1: Mistral's band
             # keeps key j iff 0 <= (ctx-1) - j < window
             keep = jnp.logical_and(keep, pos > ctx - 1 - window)
-        s_log = jnp.where(keep, s_log, _NEG_INF)
-
-        m_prev = m_ref[:, :1]                             # [G, 1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s_log, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_log - m_new)                        # [G, bs] fp32
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        pv = jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [G, D] fp32
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        for g in range(groups):
+            q = q_ref[0, g].astype(jnp.float32)           # [Hkv, D]
+            s_log = jnp.sum(k * q[None], axis=-1,
+                            keepdims=True) * scale        # [bs, Hkv, 1]
+            s_log = jnp.where(keep, s_log, _NEG_INF)
+            m_prev = m_ref[g][:, :1][None]                # [1, Hkv, 1]
+            l_prev = l_ref[g][:, :1][None]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(s_log, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s_log - m_new)                    # [bs, Hkv, 1]
+            l_new = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
+            m_ref[g] = jnp.broadcast_to(m_new[0], m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new[0], l_ref.shape[1:])
+            acc_ref[g] = acc_ref[g] * alpha[0] + jnp.sum(p * v, axis=0)
 
     @pl.when(i == num_blocks - 1)
     def _finish():
-        l = l_ref[:, :1]
-        # a context-0 (inactive) row runs no tile: l == 0, output 0
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        for g in range(groups):
+            l = l_ref[g][:, :1]
+            # a context-0 (inactive) row runs no tile: l == 0, output 0
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, g] = (acc_ref[g] / safe_l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -143,26 +152,26 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens,
     _, bs, Hkv, _ = k_pool.shape
     G = Hq // Hkv
     nb = block_tables.shape[1]
-    qg = q.reshape(S, Hkv, G, D)
+    qg = q.reshape(S, Hkv, G, D).transpose(0, 2, 1, 3)    # [S, G, Hkv, D]
 
     # index maps receive the scalar-prefetch refs after the grid ids:
     # the kv maps read the BLOCK TABLE to pick the pool block each tile
     # DMAs — the gather, folded into the attention read
-    def q_map(s, h, i, tbl, ctx):
-        return (s, h, 0, 0)
+    def q_map(s, i, tbl, ctx):
+        return (s, 0, 0, 0)
 
-    def kv_map(s, h, i, tbl, ctx):
-        return (tbl[s, i], 0, h, 0)
+    def kv_map(s, i, tbl, ctx):
+        return (tbl[s, i], 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, G, D), q_map),
-        pl.BlockSpec((1, bs, 1, D), kv_map),
-        pl.BlockSpec((1, bs, 1, D), kv_map),
+        pl.BlockSpec((1, G, Hkv, D), q_map),
+        pl.BlockSpec((1, bs, Hkv, D), kv_map),
+        pl.BlockSpec((1, bs, Hkv, D), kv_map),
     ]
     args = [qg, k_pool, v_pool]
     if int8:
-        in_specs += [pl.BlockSpec((1, bs, 1, 1), kv_map),
-                     pl.BlockSpec((1, bs, 1, 1), kv_map)]
+        in_specs += [pl.BlockSpec((1, bs, Hkv, 1), kv_map),
+                     pl.BlockSpec((1, bs, Hkv, 1), kv_map)]
         args += [k_scale_pool, v_scale_pool]
 
     def kernel(*refs):
@@ -172,27 +181,28 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens,
             tbl, ctx, q_, k_, v_, o_, acc_, m_, l_ = refs
             ks_ = vs_ = None
         _paged_kernel(tbl, ctx, q_, k_, v_, ks_, vs_, o_, acc_, m_, l_,
-                      scale=scale, block_size=bs, window=window)
+                      scale=scale, block_size=bs, window=window, groups=G)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, Hkv, nb),
+        grid=(S, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D), q_map),
+        out_specs=pl.BlockSpec((1, G, Hkv, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),     # output accumulator
-            pltpu.VMEM((G, 128), jnp.float32),   # running max (lanes)
-            pltpu.VMEM((G, 128), jnp.float32),   # running sum (lanes)
+            pltpu.VMEM((G, Hkv, D), jnp.float32),     # output accumulator
+            pltpu.VMEM((G, Hkv, 128), jnp.float32),   # running max (lanes)
+            pltpu.VMEM((G, Hkv, 128), jnp.float32),   # running sum (lanes)
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Hkv, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, G, Hkv, D), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       *args)
-    return out.reshape(S, Hq, D)
+    return out.transpose(0, 2, 1, 3).reshape(S, Hq, D)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,

@@ -154,6 +154,7 @@ def _fused_ce_fwd_call(hidden, weight, labels, vocab_size, block_n, block_v,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="vocab_ce_fwd",
     )(hidden, weight, lab)
     loss, lse, pred = outs
     return loss[:, 0], lse[:, 0], pred[:, 0]
@@ -267,6 +268,7 @@ def _fused_ce_bwd_call(hidden, weight, labels, lse, g, vocab_size,
         out_shape=jax.ShapeDtypeStruct(hidden.shape, hidden.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, h_dim), jnp.float32)],
         interpret=interpret,
+        name="vocab_ce_bwd_dh",
     )(hidden, weight, lab, lse_b, g_b)
 
     # v-major grid, n innermost
@@ -285,6 +287,7 @@ def _fused_ce_bwd_call(hidden, weight, labels, lse, g, vocab_size,
         out_shape=jax.ShapeDtypeStruct(weight.shape, weight.dtype),
         scratch_shapes=[pltpu.VMEM((block_v, h_dim), jnp.float32)],
         interpret=interpret,
+        name="vocab_ce_bwd_dw",
     )(hidden, weight, lab, lse_b, g_b)
     return dh, dw
 

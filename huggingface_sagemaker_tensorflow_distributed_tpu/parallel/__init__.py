@@ -23,6 +23,10 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.sharding import (
     replicated,
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.distributed import (  # noqa: F401
+    NoAcceleratorError,
+    compilation_cache_dir,
+    device_memory_peaks,
     enable_compilation_cache,
     initialize_distributed,
+    require_accelerator,
 )

@@ -19,20 +19,11 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis from inside shard_map, across
-    jax versions: newer jax has ``lax.axis_size``; on 0.4.x the
-    ``psum(1, axis)`` idiom constant-folds to a Python int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ppermute_shift(x, axis_name: str, shift: int = 1):
     """Ring shift along a mesh axis — the KV-rotation step of ring
     attention (``parallel/ring_attention.py``). ``shift=1`` sends to the
     next device on the ring; ``shift=-1`` to the previous."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -81,7 +72,6 @@ def make_replica_divergence_fn(mesh, shardings):
         AXIS_DCN,
         AXIS_EXPERT,
         AXIS_SEQ,
-        shard_map_compat,
     )
 
     axes = tuple(mesh.axis_names)
@@ -112,7 +102,7 @@ def make_replica_divergence_fn(mesh, shardings):
     # graftlint: allow[R3] no static key: the only argument is the traced param pytree; mesh/specs are closed over at build time (one compile per divergence-checker instance)
     @jax.jit
     def compute(p):
-        plain_grid, expert_grid = shard_map_compat(
+        plain_grid, expert_grid = jax.shard_map(
             local_checksum, mesh=mesh,
             in_specs=(in_specs,), out_specs=(P(*axes), P(*axes)))(p)
         dev = jnp.zeros((), jnp.float32)

@@ -29,18 +29,70 @@ logger = get_logger(__name__)
 _INITIALIZED = False
 
 
-def enable_compilation_cache(cache_dir: str) -> None:
-    """Persistent XLA compilation cache (capability the reference gets
-    implicitly from TF's graph caching): recompiles across runs, resumes
-    and length-bucket widths become disk hits (~3x warm startup on TPU).
-    """
-    if not cache_dir:
-        return
-    import jax
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache (git-ignored): the path is part of the cache
+# key's environment, so it is fixed, never per process or per job
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+
+def compilation_cache_dir() -> str:
+    """Where the persistent XLA compile cache lives: wherever
+    ``JAX_COMPILATION_CACHE_DIR`` says, else one fixed directory inside
+    the checkout. Every entry point, child process and ``chip_smoke.py``
+    resolves it through this one rule."""
+    return os.environ.get(ENV_CACHE_DIR) or _CHECKOUT_CACHE_DIR
+
+
+def enable_compilation_cache() -> str:
+    """Turn the persistent compile cache on at
+    :func:`compilation_cache_dir`. With ``JAX_COMPILATION_CACHE_DIR``
+    set, jax's own reading of it is the only setting and nothing is
+    configured here. ``JAX_ENABLE_COMPILATION_CACHE=false`` (the test
+    suite) keeps jax from reading or writing the directory at all."""
+    if not os.environ.get(ENV_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    return compilation_cache_dir()
+
+
+class NoAcceleratorError(RuntimeError):
+    """The default JAX backend is not a TPU and the CPU was not asked
+    for by name."""
+
+
+def require_accelerator() -> dict:
+    """Initialize the backend and refuse a silent CPU fallback.
+
+    jax falls back to the CPU with a warning when libtpu cannot
+    initialize, and everything downstream then picks its CPU branch
+    (XLA attention, interpret-mode Pallas, small presets) and exits 0.
+    So unless ``jax_platforms`` (``JAX_PLATFORMS``, or the test suite's
+    ``jax.config.update``) asks for ``cpu``, a default backend that is
+    not a TPU is an error. Returns the device description every printed
+    result carries."""
+    devices = jax.devices()
+    dev = devices[0]
+    # the first platform listed is the default backend: "tpu,cpu" asks
+    # for the TPU (and jax itself fails if a listed platform is missing)
+    asked = (jax.config.jax_platforms or "").split(",")[0].strip().lower()
+    if dev.platform != "tpu" and asked != "cpu":
+        raise NoAcceleratorError(
+            f"default JAX backend is {dev.platform!r} "
+            f"({dev.device_kind}), not a TPU, and jax_platforms="
+            f"{jax.config.jax_platforms!r} does not ask for the CPU — "
+            "refusing to fall back; set JAX_PLATFORMS=cpu to run on the "
+            "CPU on purpose")
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(devices), "jax_version": jax.__version__}
+
+
+def device_memory_peaks() -> list[int] | None:
+    """``peak_bytes_in_use`` of every local device, in device order;
+    None where the backend keeps no memory statistics (the CPU)."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    return None if None in peaks else [int(p) for p in peaks]
 
 
 def initialize_distributed() -> tuple[int, int]:

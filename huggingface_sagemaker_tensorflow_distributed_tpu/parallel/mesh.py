@@ -216,22 +216,6 @@ def tensor_parallel_mesh(tp: int) -> Mesh:
     return build_mesh(MeshConfig(dp=1, tp=tp), devices=devices[:tp])
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions. Newer jax exposes it at
-    top level with the ``check_vma`` switch; 0.4.x ships
-    ``jax.experimental.shard_map.shard_map`` where the same switch
-    (skip the output varying/replication check that pallas_call outputs
-    fail) is spelled ``check_rep``. Every in-repo shard_map goes through
-    here so one jax upgrade never strands half the call sites again."""
-    try:
-        from jax import shard_map as _sm
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def world_size(mesh: Mesh) -> int:
     """Total device count — ``hvd.size()`` parity (reference train.py:112)."""
     return math.prod(mesh.devices.shape)

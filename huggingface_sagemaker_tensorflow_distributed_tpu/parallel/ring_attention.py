@@ -106,11 +106,7 @@ def _ring_shard(q, k, v, mask, rel_table=None, *, scale, axis_name, causal,
     """Per-shard ring attention. q/k/v: local [b, h, s_local, d]; mask:
     local additive [b, 1, 1, kv_local] or None; rel_table: local
     [num_buckets, h] bias table or None. Stats kept in fp32."""
-    from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.collectives import (
-        axis_size,
-    )
-
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     q32 = q.astype(jnp.float32)
@@ -173,11 +169,7 @@ def ring_attention(q, k, v, mask=None, scale=None, *, mesh: Mesh,
         t_ = rest.pop(0) if has_rel else None
         return _ring_shard(q_, k_, v_, m_, t_, **kw)
 
-    from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
-        shard_map_compat,
-    )
-
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=qkv_spec,
         check_vma=False,
     )(*args)

@@ -1675,12 +1675,24 @@ class ServeEngine:
                             self._d_plan, bucket, self.speculate_k,
                             mode)
                     else:
-                        tok, self._pools = self._decode_fn(
-                            self.model, self.params, self._pools, si,
-                            np.zeros((S, nb), np.int32), si,
-                            np.zeros((S,), bool), sf, si, sf,
-                            np.zeros((S, 2), np.uint32), si, self._plan,
-                            bucket, mode)
+                        def decode(tokens):
+                            return self._decode_fn(
+                                self.model, self.params, self._pools,
+                                tokens, np.zeros((S, nb), np.int32), si,
+                                np.zeros((S,), bool), sf, si, sf,
+                                np.zeros((S, 2), np.uint32), si,
+                                self._plan, bucket, mode)
+
+                        tok, self._pools = decode(si)
+                        if self.overlap:
+                            # the dispatch-ahead loop feeds the previous
+                            # step's device-resident tokens straight
+                            # back in. Under a mesh a committed array's
+                            # sharding is part of the executable's key,
+                            # so that feed is a second compile (found on
+                            # four chips: two 5 s compiles mid-serve);
+                            # on one device it is a cache hit
+                            tok, self._pools = decode(tok)
             if (self.overlap and not self.speculative
                     and not self._warmed_modes):
                 # precompile the dispatch-ahead token-feed select (the

@@ -258,15 +258,12 @@ def _make_sharded_fused_ce(block_n: int, block_v: int,
     batch_axes = data_axis_names()
     if mesh is not None and any(
             mesh.shape.get(a, 1) > 1 for a in batch_axes):
-        from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
-            shard_map_compat,
-        )
         # check_vma=False: pallas_call does not annotate varying-mesh
         # axes on its outputs, which the default vma check rejects
-        ce = shard_map_compat(ce, mesh=mesh,
-                              in_specs=(P(batch_axes), P(), P(batch_axes)),
-                              out_specs=(P(batch_axes), P(batch_axes)),
-                              check_vma=False)
+        ce = jax.shard_map(ce, mesh=mesh,
+                           in_specs=(P(batch_axes), P(), P(batch_axes)),
+                           out_specs=(P(batch_axes), P(batch_axes)),
+                           check_vma=False)
     return ce
 
 
@@ -415,12 +412,9 @@ def make_fused_mlm_loss(model, mask_cap: float = 0.25, block_n: int = 256,
         batch_axes = data_axis_names()
         if mesh is not None and any(
                 mesh.shape.get(a, 1) > 1 for a in batch_axes):
-            from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
-                shard_map_compat,
-            )
             # check_vma=False: pallas_call does not annotate varying-mesh
             # axes on its outputs, which the default vma check rejects
-            ce = shard_map_compat(
+            ce = jax.shard_map(
                 ce, mesh=mesh,
                 in_specs=(P(batch_axes), P(), P(), P(batch_axes),
                           P(batch_axes)),
@@ -628,6 +622,7 @@ class Trainer:
             self._divergence_fn = self._with_mesh(make_replica_divergence_fn(
                 self.mesh, self.state_shardings.params))
         rel = float(jax.device_get(self._divergence_fn(self.state.params)))
+        obs.scalar("train/replica_divergence", rel)
         if rel > self.config.divergence_tol:
             raise ReplicaDivergenceError(
                 f"parameter replicas diverge (relative deviation {rel:.3e} > "

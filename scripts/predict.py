@@ -37,6 +37,10 @@ import numpy as np
 
 from huggingface_sagemaker_tensorflow_distributed_tpu.data import load_tokenizer
 from huggingface_sagemaker_tensorflow_distributed_tpu.models import auto as auto_models
+from huggingface_sagemaker_tensorflow_distributed_tpu.parallel import (
+    enable_compilation_cache,
+    require_accelerator,
+)
 
 
 def _encode_mlm_with_mask(tokenizer, texts, max_length, mask_id):
@@ -454,6 +458,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if not args.text and not args.input_file:
         ap.error("provide --text or --input_file")
+    require_accelerator()
+    enable_compilation_cache()
     for row in predict(args):
         print(json.dumps(row))
 

@@ -335,7 +335,14 @@ def main() -> None:
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args()
 
+    import jax
+
     from huggingface_sagemaker_tensorflow_distributed_tpu import obs
+    from huggingface_sagemaker_tensorflow_distributed_tpu.parallel import (
+        device_memory_peaks,
+        enable_compilation_cache,
+        require_accelerator,
+    )
     from huggingface_sagemaker_tensorflow_distributed_tpu.serve.loadgen import (
         OpenLoopDriver,
         bursty_arrivals,
@@ -358,6 +365,9 @@ def main() -> None:
         raise SystemExit(f"serve: {e}")
 
     obs.configure()
+    tracker = obs.compile_tracker()      # None with telemetry off
+    device = require_accelerator()
+    enable_compilation_cache()
     model, params = load_model(args)
     max_len = args.max_model_len or (
         model.config.max_position_embeddings
@@ -394,6 +404,9 @@ def main() -> None:
     # sample, so no request pays a mid-serve compile
     router.warmup(sampled=any(kw.get("temperature", 0) > 0
                               for _, _, kw in trace))
+    # every executable the trace needs exists now: a compile counted
+    # from here on is a mid-serve stall
+    compiles_at_warm = tracker.count if tracker else None
     driver = None
     if arrival is not None:
         # open loop: the trace arrives on the seeded schedule through
@@ -430,6 +443,15 @@ def main() -> None:
         router.run()
         wall = time.perf_counter() - t0
 
+    # what the run was measured on, carried by both summary shapes
+    run_facts = {
+        **device,
+        "compiles_after_warmup": (tracker.count - compiles_at_warm
+                                  if tracker else None),
+        "param_bytes_per_device": sum(
+            leaf.addressable_shards[0].data.nbytes
+            for leaf in jax.tree.leaves(engine.params)),
+        "peak_bytes_in_use": device_memory_peaks()}
     total = 0
     for req in reqs:
         ids = router.output_ids(req)
@@ -526,6 +548,7 @@ def main() -> None:
             "kv_dtype": engine.kv_cache_dtype,
             "tp": engine.tp,
             "per_replica": rslo.get("per_replica"),
+            **run_facts,
             **({"roles": rslo.get("roles"),
                 "per_role": rslo.get("per_role"),
                 "migrations": router.migrations,
@@ -618,6 +641,7 @@ def main() -> None:
             stats.kv_bytes_read / stats.decode_steps, 1)
             if stats.decode_steps else None),
         "kv_peak_utilization": round(stats.kv_peak_utilization, 3),
+        **run_facts,
         **({"swap_policy": stats.swap_policy,
             "swap_outs": stats.swap_outs,
             "swap_ins": stats.swap_ins,

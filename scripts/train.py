@@ -42,8 +42,10 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.models import auto as auto
 from huggingface_sagemaker_tensorflow_distributed_tpu.parallel import (
     MeshConfig,
     build_mesh,
+    device_memory_peaks,
     enable_compilation_cache,
     initialize_distributed,
+    require_accelerator,
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.train import Trainer
 from huggingface_sagemaker_tensorflow_distributed_tpu.train.checkpoint import Checkpointer
@@ -229,12 +231,13 @@ def build_dataset(config: TrainConfig, tokenizer, split: str, max_len: int,
 def main(argv=None) -> dict:
     config = parse_args(argv)
     process_index, process_count = initialize_distributed()
-    enable_compilation_cache(config.compilation_cache_dir)
+    device = require_accelerator()
+    cache_dir = enable_compilation_cache()
     setup_logging(process_index=process_index, all_hosts=config.log_all_hosts)
     logger = get_logger("train")
     logger.info("config: %s", config.to_json())
-    logger.info("process %d/%d, %d devices", process_index, process_count,
-                len(jax.devices()))
+    logger.info("process %d/%d, backend %s, compile cache %s", process_index,
+                process_count, device, cache_dir)
     # per-host contract, like the reference's SM_NUM_GPUS (train.py:50) —
     # so compare against this host's devices, not the global mesh
     n_local = len(jax.local_devices())
@@ -376,6 +379,8 @@ def main(argv=None) -> dict:
                 logger.info("exporting best epoch %d (%s = %.4f)",
                             trainer.best_epoch, config.best_metric,
                             trainer._best_metric)
+            # what the run was measured on rides in the results file
+            history.update(device, peak_bytes_in_use=device_memory_peaks())
             trainer.write_train_results(history)
             results["train"] = history
 

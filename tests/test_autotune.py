@@ -256,11 +256,11 @@ def test_compile_budget_alert_and_latch(obs_dir, capsys):
     tracker = obs.compile_tracker()
     tracker.budget_s = 0.5
     assert not obs.compile_budget_exceeded()
-    tracker.observe("backend_compile_time", 0.3)
+    tracker.observe(tracker.BACKEND_COMPILE, 0.3)
     assert not obs.compile_budget_exceeded()
-    tracker.observe("backend_compile_time", 0.4)   # crosses 0.5s
+    tracker.observe(tracker.BACKEND_COMPILE, 0.4)   # crosses 0.5s
     assert obs.compile_budget_exceeded()
-    tracker.observe("backend_compile_time", 0.4)   # alert fires ONCE
+    tracker.observe(tracker.BACKEND_COMPILE, 0.4)   # alert fires ONCE
     alerts = [e for e in _events(obs_dir) if e["type"] == "alert"]
     assert len(alerts) == 1
     assert alerts[0]["name"] == "compile_budget"
@@ -302,7 +302,7 @@ def test_bucket_ladder_capped_when_over_budget(obs_dir):
     assert widths() == [16, 48]                   # unconstrained ladder
     tracker = obs.compile_tracker()
     tracker.budget_s = 0.1
-    tracker.observe("backend_compile_time", 1.0)  # blow the budget
+    tracker.observe(tracker.BACKEND_COMPILE, 1.0)  # blow the budget
     # a FRESH batcher (no used widths yet) must fall back to full width
     # for both batches instead of minting 16 then 48
     assert widths() == [64, 64]
@@ -343,7 +343,7 @@ def test_bucket_ladder_multihost_caps_only_on_agreement(obs_dir):
 
     tracker = obs.compile_tracker()
     tracker.budget_s = 0.1
-    tracker.observe("backend_compile_time", 1.0)   # local crossing only
+    tracker.observe(tracker.BACKEND_COMPILE, 1.0)   # local crossing only
     assert obs.compile_budget_exceeded()
     assert not obs.compile_budget_capped(2)
     assert widths() == [16, 48]                    # still minting

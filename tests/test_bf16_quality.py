@@ -1,5 +1,5 @@
-"""bf16 training-quality evidence (SURVEY.md §7 hard-part 5, VERDICT r1
-next-steps #7): the default TPU compute dtype must not cost accuracy.
+"""bf16 training-quality evidence (SURVEY.md §7 hard-part 5): the
+default TPU compute dtype must not cost accuracy.
 
 Trains the synthetic seq-cls config twice from the same init — fp32
 compute vs bf16 compute (params/optimizer state stay fp32 in both, the

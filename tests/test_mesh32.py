@@ -1,4 +1,4 @@
-"""32-virtual-device full-mesh correctness (VERDICT r1 next-steps #8).
+"""32-virtual-device full-mesh correctness.
 
 Spawns a child with a forced 32-device CPU backend (the conftest pins
 this process to 8, so the wider mesh needs its own process) and asserts

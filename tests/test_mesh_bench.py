@@ -1,6 +1,6 @@
 """Scaling-instrument tests: the --mesh bench's trace capture + XPlane
-parsing must find real collective time on a dp8 mesh (VERDICT r1
-next-steps #8 — the instrument for the ≥90% 8→32 scaling north star)."""
+parsing must find real collective time on a dp8 mesh (the instrument
+for the ≥90% 8→32 scaling north star)."""
 
 import numpy as np
 import pytest

@@ -231,17 +231,38 @@ def test_memory_sampler_noop_on_cpu(obs_dir):
 
 
 def test_compile_tracker_counts_compile_events(obs_dir):
+    """jax 0.9 calls duration listeners with keyword arguments
+    (``fun_name=``); a backend compile is a compilation, the lowering
+    stage only adds to the cumulative seconds, and a trace event (jax
+    records one per nested trace and per dispatch-cache miss) is
+    neither."""
     tracker = CompileTracker(obs.state())
-    tracker.observe("/jax/core/compile/backend_compile_duration", 1.5)
-    tracker.observe("/jax/core/something_else", 9.0)  # ignored
-    tracker.observe("/jax/pjit/compile", 0.5)
+    tracker.observe("/jax/core/compile/jaxpr_trace_duration", 9.0,
+                    fun_name="step")                  # ignored
+    tracker.observe(tracker.LOWERING, 0.25, fun_name="jit(step)")
+    tracker.observe(tracker.BACKEND_COMPILE, 1.5, fun_name="jit(step)")
+    tracker.observe(tracker.BACKEND_COMPILE, 0.25)
     assert tracker.count == 2
     assert tracker.cum_secs == pytest.approx(2.0)
     compiles = [e for e in _events(obs_dir) if e["type"] == "compile"]
-    assert [c["count"] for c in compiles] == [1, 2]
+    assert [c["count"] for c in compiles] == [0, 1, 2]
     assert compiles[-1]["cum"] == pytest.approx(2.0)
     for c in compiles:
         assert obs.validate_event(c) == []
+
+
+def test_installed_tracker_survives_a_real_jit(obs_dir):
+    """The registered listener must accept whatever the installed jax
+    passes it: one real compilation counts once, and a second call of
+    the same program counts nothing."""
+    tracker = obs.compile_tracker()
+    fn = jax.jit(lambda x: x * 3 + 1)
+    x = np.ones((5,), np.float32)   # numpy in: no eager op compiles
+    count0 = tracker.count
+    fn(x).block_until_ready()
+    assert tracker.count == count0 + 1
+    fn(x).block_until_ready()
+    assert tracker.count == count0 + 1
 
 
 # -- StepMeter compile exclusion --------------------------------------------

@@ -1,8 +1,9 @@
 """Pallas fused-attention numerics vs the XLA reference implementation
 (interpret mode on CPU; the same kernel runs compiled on TPU)."""
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
     make_attention_mask,
@@ -184,3 +185,65 @@ def test_flash_sliding_window_matches_banded_xla():
         for a, b in zip(gf, gx):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-5, rtol=1e-3)
+
+
+def test_flash_under_dp_tp_mesh_through_trainer_step(devices8):
+    """A Mosaic kernel cannot be partitioned by GSPMD (jax refuses to
+    lower one under a multi-device jit), so flash is shard_mapped over
+    the batch axes and, under tp, the heads axis. Run impl="flash"
+    (interpret mode here) through the Trainer's jitted step on a
+    dp=2 x tp=2 mesh: same losses as XLA attention on the same mesh,
+    and replicas still identical afterwards."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.config import (
+        TrainConfig,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.data import (
+        ArrayDataset,
+        ShardedBatcher,
+        WordHashTokenizer,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.data.sources import (
+        synthetic_text_classification,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.auto import (
+        init_params,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.bert import (
+        BertForSequenceClassification,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.layers import (
+        EncoderConfig,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.parallel import (
+        MeshConfig,
+        build_mesh,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.train import Trainer
+
+    seq = 32
+    tok = WordHashTokenizer(vocab_size=256)
+    texts, labels = synthetic_text_classification(16, seed=0)
+    ds = ArrayDataset.from_texts(tok, texts, labels, max_length=seq)
+    mesh = build_mesh(MeshConfig(dp=2, tp=2), devices=devices8[:4])
+    losses = {}
+    for impl in ("xla", "flash"):
+        cfg = EncoderConfig(vocab_size=256, hidden_size=32, num_layers=1,
+                            num_heads=2, intermediate_size=64,
+                            max_position_embeddings=seq,
+                            attention_impl=impl, hidden_dropout=0.0,
+                            attention_dropout=0.0)
+        model = BertForSequenceClassification(cfg, num_labels=2)
+        trainer = Trainer(
+            TrainConfig(dtype="float32", learning_rate=1e-3,
+                        scale_lr_by_world_size=False, log_every_steps=0),
+            model, init_params(model, cfg, seed=0), mesh)
+        batcher = ShardedBatcher(ds, 8, mesh, shuffle=False)
+        run = []
+        for batch in batcher.global_arrays(0):
+            trainer.state, metrics = trainer._train_step(trainer.state,
+                                                         batch)
+            run.append(float(jax.device_get(metrics["loss"])))
+        losses[impl] = run
+        assert trainer.check_replica_divergence() == 0.0
+    assert len(losses["flash"]) == 2
+    np.testing.assert_allclose(losses["flash"], losses["xla"], atol=1e-5)

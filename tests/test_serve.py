@@ -1595,6 +1595,37 @@ def test_tp_engine_token_exact_across_bucket_boundary(gpt2_setup,
     assert slo["kv_pool_bytes_per_device"] == eng.blocks.pool_bytes
 
 
+def test_tp_warmup_covers_the_device_token_feed(gpt2_setup, devices8,
+                                                tmp_path):
+    """Under a mesh the dispatch-ahead loop's feed — the previous
+    step's device-resident tokens — is an executable of its own (a
+    committed array's sharding is part of the key; first seen as two
+    5 s compiles mid-serve on four chips). Warm-up compiles it: the
+    run, across both gather buckets, compiles nothing."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
+        ServeEngine,
+    )
+
+    cfg, model, params = gpt2_setup
+    obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
+    try:
+        tracker = obs.compile_tracker()
+        eng = ServeEngine(model, params, mesh=2, num_slots=3, block_size=4,
+                          num_blocks=40, prefill_chunk=8, max_model_len=32,
+                          gather_buckets=[16, 32])
+        eng.warmup()
+        count0 = tracker.count
+        rng = np.random.RandomState(31)
+        for p, m in [(5, 9), (15, 6), (12, 8)]:
+            eng.submit(rng.randint(1, 120, (p,)).astype(np.int32), m)
+        eng.run()
+        assert eng.bucket_switches > 0
+        assert tracker.count == count0, \
+            "TP serving compiled after warm-up"
+    finally:
+        obs.reset()
+
+
 def test_tp_engine_token_exact_under_forced_preemption(gpt2_setup,
                                                        devices8):
     """The ISSUE 13 tier-1 exactness gate, half 2: recompute

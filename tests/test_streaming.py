@@ -66,8 +66,8 @@ def test_line_corpus_txt_and_trailing_newline(tmp_path):
 def test_streaming_causal_lm_matches_materialized(corpus_file):
     """causal-lm has no randomness: streaming and materialized must
     produce bit-identical batches from the same ShardedBatcher seed —
-    hence identical loss curves at equal data, the equivalence the
-    VERDICT asks for, checked at the strictest level."""
+    hence identical loss curves at equal data, checked at the strictest
+    level."""
     path, texts, _ = corpus_file
     tok = WordHashTokenizer(vocab_size=512)
     mesh = build_mesh(MeshConfig())

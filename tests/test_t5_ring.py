@@ -1,4 +1,4 @@
-"""T5 + sequence parallelism (VERDICT r1 weak #7): the encoder's
+"""T5 + sequence parallelism: the encoder's
 relative-bias attention must run the ring path on an sp mesh and match
 the XLA path exactly — forward, loss, and gradients."""
 
