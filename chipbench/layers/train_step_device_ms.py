@@ -1,0 +1,10 @@
+"""Median device time of the train-step XLA module. Source: trace."""
+
+from chipbench import reduce, stats
+
+
+def read(o):
+    if o.trace is None:
+        return None
+    durs = reduce.module_seconds(o.trace, "train_step")
+    return 1e3 * stats.median(durs) if durs else None
