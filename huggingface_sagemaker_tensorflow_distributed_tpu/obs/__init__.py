@@ -17,8 +17,9 @@ Environment contract (documented in README "Observability"):
 - ``HSTD_TELEMETRY_DIR=<dir>`` writes ``events.jsonl`` (streamed,
   crash-safe append) and ``trace.json`` (Chrome trace viewer / Perfetto,
   atomically replaced) into ``<dir>``. Unset → spans/metrics are no-ops
-  (the instrumentation is opt-in per run); no files or span buffers
-  accumulate in un-instrumented processes.
+  (the instrumentation is opt-in per run); no files, threads or span
+  buffers accumulate in un-instrumented processes (a few life-cycle
+  spans, :func:`lifecycle_span`, wait for a later ``configure``).
 - ``HSTD_HEARTBEAT_SECS`` sets the liveness cadence (default 60).
 
 Multi-host: host 0 owns the files; other hosts buffer in memory.
@@ -34,6 +35,7 @@ by ``scripts/obsctl.py``).
 
 from __future__ import annotations
 
+import atexit
 import os
 from typing import Optional
 
@@ -68,6 +70,7 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.obs.flight import (  # noq
 from huggingface_sagemaker_tensorflow_distributed_tpu.obs.watchdog import (  # noqa: F401
     CompileTracker,
     Heartbeat,
+    PauseMeter,
     install_compile_tracker,
     sample_device_memory,
     thread_stacks,
@@ -77,6 +80,8 @@ _state = ObsState()
 _tracer = Tracer(_state)
 _metrics = MetricsSink(_state)
 _heartbeat: Optional[Heartbeat] = None
+_pause_meter: Optional[PauseMeter] = None
+_shutdown_at_exit = False
 _detector: Optional[AnomalyDetector] = None
 
 
@@ -105,7 +110,18 @@ def configured() -> bool:
 
 def configure(out_dir: Optional[str] = None,
               enabled: Optional[bool] = None) -> None:
+    """Set the output directory and/or the enabled flag. While this
+    process streams events to a directory the host-pause meter runs
+    beside it (a 10 ms ticker thread, ``watchdog.PauseMeter``);
+    :func:`shutdown` stops it and writes what it saw, at the
+    interpreter's exit at the latest (with the spans still pending)."""
+    global _pause_meter, _shutdown_at_exit
     _state.configure(out_dir=out_dir, enabled=enabled)
+    if _pause_meter is None and has_sink():
+        _pause_meter = PauseMeter(_state).start()
+        if not _shutdown_at_exit:
+            _shutdown_at_exit = True
+            atexit.register(shutdown)
 
 
 def set_host(index: int, count: int) -> None:
@@ -116,6 +132,13 @@ def span(name: str, args: Optional[dict] = None):
     """Nestable wall-time span (context manager). Allocation-free when
     telemetry is disabled."""
     return _tracer.span(name, args)
+
+
+def lifecycle_span(name: str, args: Optional[dict] = None):
+    """A span of the process's life cycle (``serve/warmup``): kept in a
+    small buffer even before a directory is configured and replayed
+    into ``events.jsonl`` when one is. Never inside a step loop."""
+    return _tracer.lifecycle_span(name, args)
 
 
 def scalar(name: str, value, step: Optional[int] = None,
@@ -267,15 +290,20 @@ def compile_tracker() -> Optional[CompileTracker]:
 
 
 def flush() -> None:
-    """Write/refresh trace.json from the span buffer; flush event file."""
+    """Write the pending spans to the event file and refresh trace.json
+    from the span buffer."""
+    _state.flush_spans()
     _state.flush_trace()
 
 
 def shutdown() -> None:
-    global _heartbeat, _detector
+    global _heartbeat, _detector, _pause_meter
     if _heartbeat is not None:
         _heartbeat.stop()
         _heartbeat = None
+    if _pause_meter is not None:
+        _pause_meter.stop()
+        _pause_meter = None
     if _detector is not None:
         _detector.shutdown()     # close any open profiler window
         _detector = None
@@ -294,5 +322,5 @@ def reset(out_dir: Optional[str] = None,
     _metrics = MetricsSink(_state)
     _heartbeat = None
     if out_dir is not None or enabled is not None:
-        _state.configure(out_dir=out_dir, enabled=enabled)
+        configure(out_dir=out_dir, enabled=enabled)
     return _state

@@ -6,14 +6,24 @@ Design constraints (ISSUE 1 acceptance):
 - ``HSTD_TELEMETRY=0`` must cost exactly zero allocations on the trainer
   hot loop: every public entry point early-returns on a cached bool, and
   the disabled ``span()`` returns one shared singleton context manager.
-- Enabled-but-unconfigured (no output dir) runs buffer spans in a
-  bounded in-memory list and write no files — unit tests stay clean.
+- Enabled-but-unconfigured (no output dir) runs record nothing but
+  the LIFE-CYCLE spans (``lifecycle_span``: warm-up and the like, at
+  most ``_MAX_LIFECYCLE_SPANS``), which wait in memory for a later
+  ``configure`` — unit tests stay clean.
 - File emission is append + flush per line, so a SIGKILL tears at most
   the final line (``schema.iter_events`` skips a torn tail); fsync runs
   every ``_FSYNC_EVERY`` lines to bound data loss on power-cut-class
-  failures without paying fsync latency per event.
-- No jax imports anywhere in this module: the host/rank id comes from
-  the launcher env contract (``TPU_PROCESS_ID``) or an explicit
+  failures without paying fsync latency per event. Spans are the
+  exception: they are kept in memory and written in one batch at
+  ``flush_spans`` (``obs.flush`` / ``shutdown`` / every heartbeat, and
+  whenever ``_SPAN_BATCH`` are pending), so a loop that opens a dozen
+  spans an iteration pays no write inside it; a kill loses the spans
+  since the last batch.
+- A span is also a ``jax.profiler.TraceAnnotation`` (``hstd/<name>``)
+  when jax is ALREADY imported, so the program's spans lie on the
+  device trace's clock in any profiled run. No jax import anywhere in
+  this module (``sys.modules`` only): the host/rank id comes from the
+  launcher env contract (``TPU_PROCESS_ID``) or an explicit
   ``set_host`` call from ``parallel.distributed``.
 """
 
@@ -45,6 +55,9 @@ ENV_ALL_HOSTS = "HSTD_TELEMETRY_ALL_HOSTS"
 
 _FSYNC_EVERY = 64
 _MAX_BUFFERED_SPANS = 200_000
+_SPAN_BATCH = 10_000          # pending span records that force a write
+_MAX_LIFECYCLE_SPANS = 256    # kept while no sink exists yet
+ANNOTATION_PREFIX = "hstd/"   # a span's name in the profiler's trace
 
 
 def _env_enabled() -> bool:
@@ -96,12 +109,22 @@ class EventLog:
                           default=str) + "\n"
 
     def emit(self, etype: str, fields: dict) -> None:
-        record = self.stamp_record(etype, fields)
-        if self.ring is not None:
-            # flight recorder (obs/flight.py): every written event also
-            # lands in the bounded ring an anomaly dump snapshots
-            self.ring.record(record)
-        line = json.dumps(record, default=str) + "\n"
+        self.emit_many(etype, (fields,))
+
+    def emit_many(self, etype: str, rows) -> None:
+        """One event of ``etype`` per element of ``rows``, all stamped
+        now and written with ONE write + flush."""
+        lines = []
+        for fields in rows:
+            record = self.stamp_record(etype, fields)
+            if self.ring is not None:
+                # flight recorder (obs/flight.py): every written event
+                # also lands in the bounded ring an anomaly dump
+                # snapshots
+                self.ring.record(record)
+            lines.append(json.dumps(record, default=str) + "\n")
+        if not lines:
+            return
         with self._lock:
             if self._file is None:
                 os.makedirs(os.path.dirname(self.path) or ".", exist_ok=True)
@@ -110,9 +133,9 @@ class EventLog:
                     hdr_type, hdr_fields = self._header
                     self._header = None
                     self._file.write(self._stamp(hdr_type, hdr_fields))
-            self._file.write(line)
+            self._file.write("".join(lines))
             self._file.flush()
-            self._since_fsync += 1
+            self._since_fsync += len(lines)
             if self._since_fsync >= _FSYNC_EVERY:
                 os.fsync(self._file.fileno())
                 self._since_fsync = 0
@@ -136,14 +159,18 @@ class ObsState:
         self.dir: Optional[str] = None
         self.events: Optional[EventLog] = None
         self.mono0 = time.perf_counter()
-        self.spans: list = []          # (name, mono_start, dur, tid, depth)
+        # span records: (name, mono_start, dur, tid, depth, parent, args)
+        self.spans: list = []          # all of them, for trace.json
         self.spans_dropped = 0
+        self._pending: list = []       # not yet written to events.jsonl
+        self._lifecycle: list = []     # recorded before any sink existed
         # flight recorder (obs/flight.py): bounded ring of recent event
         # records, dumped by the anomaly detector at an incident.
         # HSTD_FLIGHT_RING=0 disables it.
         self.ring: Optional[FlightRecorder] = FlightRecorder.from_env()
         self._tl = threading.local()
         self._lock = threading.Lock()
+        self._span_lock = threading.Lock()
         env_dir = os.environ.get(ENV_DIR, "").strip()
         if self.enabled and env_dir:
             self._open_dir(env_dir)
@@ -170,6 +197,12 @@ class ObsState:
         self.events = EventLog(
             os.path.join(self.dir, event_filename(self.host)), self.host,
             header=header, ring=self.ring)
+        # life-cycle spans that ended before this log existed are
+        # replayed into it under their own stamps
+        with self._span_lock:
+            replay, self._lifecycle = self._lifecycle, []
+            self.spans.extend(replay)
+            self._pending.extend(replay)
 
     def configure(self, out_dir: Optional[str] = None,
                   enabled: Optional[bool] = None) -> None:
@@ -178,6 +211,7 @@ class ObsState:
                 self.enabled = enabled
             if out_dir and self.enabled and self.dir != out_dir:
                 if self.events is not None:
+                    self.flush_spans()
                     self.events.close()
                     self.events = None
                 self._open_dir(out_dir)
@@ -200,20 +234,48 @@ class ObsState:
     # -- span recording -----------------------------------------------------
 
     def add_span(self, name: str, mono_start: float, dur: float,
-                 args: Optional[dict]) -> None:
+                 args: Optional[dict], depth: int = 0,
+                 parent: Optional[str] = None,
+                 lifecycle: bool = False) -> None:
+        """Keep one finished span in memory. With a sink it waits for
+        the next :meth:`flush_spans`; without one only a ``lifecycle``
+        span is kept, for the replay when a sink opens."""
         tid = threading.get_ident() & 0x7FFFFFFF
-        depth = getattr(self._tl, "depth", 0)
-        if len(self.spans) < _MAX_BUFFERED_SPANS:
-            self.spans.append((name, mono_start, dur, tid, depth))
-        else:
-            self.spans_dropped += 1
-        if self.events is not None:
+        record = (name, mono_start, dur, tid, depth, parent, args)
+        with self._span_lock:
+            if self.events is None:
+                if lifecycle and len(self._lifecycle) < _MAX_LIFECYCLE_SPANS:
+                    self._lifecycle.append(record)
+                return
+            if len(self.spans) < _MAX_BUFFERED_SPANS:
+                self.spans.append(record)
+            else:
+                self.spans_dropped += 1
+            self._pending.append(record)
+            full = len(self._pending) >= _SPAN_BATCH
+        if full:
+            self.flush_spans()
+
+    def flush_spans(self) -> int:
+        """Write the pending span records to ``events.jsonl`` in one
+        batch; returns how many. The envelope ``t`` of a span is this
+        moment: a span's own times are ``mono`` and ``dur``."""
+        events = self.events
+        if events is None:
+            return 0
+        with self._span_lock:
+            pending, self._pending = self._pending, []
+        rows = []
+        for name, mono, dur, tid, depth, parent, args in pending:
             fields = {"name": name, "dur": round(dur, 9),
-                      "mono": round(mono_start, 9), "tid": tid,
-                      "depth": depth}
+                      "mono": round(mono, 9), "tid": tid, "depth": depth}
+            if parent is not None:
+                fields["parent"] = parent
             if args:
                 fields["args"] = args
-            self.events.emit("span", fields)
+            rows.append(fields)
+        events.emit_many("span", rows)
+        return len(rows)
 
     # -- trace.json projection ----------------------------------------------
 
@@ -228,7 +290,7 @@ class ObsState:
         events = [
             {"name": name, "ph": "X", "ts": round(mono * 1e6, 3),
              "dur": round(dur * 1e6, 3), "pid": self.host, "tid": tid}
-            for name, mono, dur, tid, _depth in list(self.spans)
+            for name, mono, dur, tid, *_rest in list(self.spans)
         ]
         doc = {"traceEvents": events, "displayTimeUnit": "ms",
                "otherData": {"schema_version": SCHEMA_VERSION,
@@ -243,6 +305,7 @@ class ObsState:
     def shutdown(self) -> None:
         self.flush_trace()
         if self.events is not None:
+            self.flush_spans()
             self.events.close()
             self.events = None
 
@@ -262,26 +325,47 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_state", "_name", "_args", "_t0")
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name``, or None while
+    jax has not been imported: this module never imports it."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    cls = getattr(profiler, "TraceAnnotation", None)
+    return None if cls is None else cls(ANNOTATION_PREFIX + name)
 
-    def __init__(self, state: ObsState, name: str, args: Optional[dict]):
+
+class _Span:
+    __slots__ = ("_state", "_name", "_args", "_lifecycle", "_t0", "_ann")
+
+    def __init__(self, state: ObsState, name: str, args: Optional[dict],
+                 lifecycle: bool = False):
         self._state = state
         self._name = name
         self._args = args
+        self._lifecycle = lifecycle
 
     def __enter__(self):
         tl = self._state._tl
-        tl.depth = getattr(tl, "depth", 0) + 1
+        stack = getattr(tl, "stack", None)
+        if stack is None:
+            stack = tl.stack = []
+        stack.append(self._name)
+        self._ann = _annotation(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
-        tl = self._state._tl
-        tl.depth = max(getattr(tl, "depth", 1) - 1, 0)
-        self._state.add_span(self._name, self._t0 - self._state.mono0,
-                             dur, self._args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = self._state._tl.stack
+        stack.pop()
+        self._state.add_span(self._name, self._t0 - self._state.mono0, dur,
+                             self._args, depth=len(stack),
+                             parent=stack[-1] if stack else None,
+                             lifecycle=self._lifecycle)
         return False
 
 
@@ -301,6 +385,16 @@ class Tracer:
         if not state.enabled or state.dir is None:
             return NULL_SPAN
         return _Span(state, name, args)
+
+    def lifecycle_span(self, name: str, args: Optional[dict] = None):
+        """A span of the process's life cycle (warm-up, a program's
+        first run): recorded whether or not a directory is configured
+        yet, and replayed under its own stamps when one is. For what
+        happens a few times a process; never inside a step loop (the
+        buffer holds ``_MAX_LIFECYCLE_SPANS``)."""
+        if not self._state.enabled:
+            return NULL_SPAN
+        return _Span(self._state, name, args, lifecycle=True)
 
 
 class MetricsSink:

@@ -10,7 +10,9 @@ One event = one JSON object on one line of ``events.jsonl``. Envelope
 fields present on EVERY event:
 
     v     int    schema version (SCHEMA_VERSION)
-    t     float  unix wall-clock seconds at emission
+    t     float  unix wall-clock seconds at emission (for a span, which
+                 is written in batches: when its batch was written; a
+                 span's own times are ``mono`` and ``dur``)
     host  int    process index (rank); 0 on single-host runs
     pid   int    OS process id
     type  str    one of EVENT_TYPES
@@ -33,9 +35,16 @@ _NUM = (int, float)
 
 # type name -> {field: allowed python types}
 REQUIRED_FIELDS: dict[str, dict[str, tuple]] = {
-    # a completed wall-time span; "mono" is the monotonic start time so
-    # spans order/nest without wall-clock steps
+    # a completed wall-time span; "mono" is the monotonic start time
+    # (seconds since the process's telemetry state was made) so spans
+    # order/nest without wall-clock steps — order spans by it, never
+    # by the envelope "t"
     "span": {"name": (str,), "dur": _NUM, "mono": _NUM, "tid": (int,)},
+    # one wake-up of the host-pause meter (obs/watchdog.py PauseMeter)
+    # that came more than its threshold late: "mono" is when it was
+    # due, on the spans' clock, "dur" by how much it was late — for
+    # that long no Python of the process ran
+    "host_pause": {"mono": _NUM, "dur": _NUM},
     # one scalar sample of a named series (loss, lr, samples/sec, ...)
     "metric": {"name": (str,), "value": _NUM + (type(None),)},
     # liveness: emitted every HSTD_HEARTBEAT_SECS by the heartbeat thread
@@ -83,6 +92,9 @@ REQUIRED_FIELDS: dict[str, dict[str, tuple]] = {
 # prompt tokens served from shared KV blocks and the hit rate; the
 # final report event the aggregates + block-sharing peaks)
 OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
+    # nesting: how many spans enclose this one on its thread, and the
+    # innermost one's name (absent on a top-level span)
+    "span": {"depth": (int,), "parent": (str,), "args": (dict,)},
     "serve": {"gather_bucket": (int,), "sampled": (bool,),
               "request": (int,), "speculate_k": (int,),
               # per-event context riders surfaced by graftlint R4
@@ -179,6 +191,17 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               "blocked_reason": (str,),
               "iteration": (int,),
               "dur_s": _NUM,
+              # the iteration's own account of its wall time (ISSUE
+              # 25): four disjoint parts of dur_s — host work before a
+              # dispatch, time inside the jitted calls, time blocked on
+              # a device fetch, host work after a fetch — so that
+              # dur_s >= their sum on every ledger line, and the
+              # caller's time since the previous iteration returned
+              "stage_s": _NUM,
+              "dispatch_s": _NUM,
+              "fetch_wait_s": _NUM,
+              "commit_s": _NUM,
+              "gap_s": _NUM,
               "prefill_chunks": (int,),
               "prefill_dispatches": (int,),
               "decode_slots": (int,),

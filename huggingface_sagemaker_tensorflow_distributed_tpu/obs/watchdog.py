@@ -1,5 +1,5 @@
-"""Watchdogs: liveness heartbeat (+ stall stack dump), XLA compile
-tracker, device-memory sampler.
+"""Watchdogs: liveness heartbeat (+ stall stack dump), host-pause
+meter, XLA compile tracker, device-memory sampler.
 
 Exactly the instrumentation that would have made the BENCH r05 rc=124
 timeout diagnosable: a run that dies mid-compile leaves heartbeat lines
@@ -15,6 +15,7 @@ supervisor), and ``obs`` must stay importable without jax.
 
 from __future__ import annotations
 
+import heapq
 import os
 import sys
 import threading
@@ -126,6 +127,9 @@ class Heartbeat:
                 "progress_age": round(age, 3)})
         if self.sample_memory:
             sample_device_memory(self._state)
+        # spans wait in memory for a batch: a beat is one, so a later
+        # SIGKILL loses at most an interval of them
+        self._state.flush_spans()
         # keep trace.json current: a later SIGKILL still leaves a valid,
         # recent Chrome trace on disk (atomic replace). The rewrite is
         # O(buffered spans), so skip it unless enough NEW spans landed
@@ -188,6 +192,73 @@ class Heartbeat:
                 self._beat_once()
             except Exception:  # noqa: BLE001 — liveness must not kill runs
                 logger.exception("heartbeat emission failed")
+
+
+class PauseMeter:
+    """Daemon thread that sleeps ``interval`` and records by how much
+    each wake-up came LATE: while this thread cannot run, nothing of
+    the process's Python can (the machine paused the guest, a garbage
+    collection or another call held the interpreter for its whole
+    length). It tells "the host stood still" from "the device did not
+    answer", which a blocked fetch alone cannot.
+
+    :meth:`stop` emits one ``metric`` ``host/pause_max_s`` and one
+    ``host_pause`` event (``mono``, ``dur``: on the spans' clock, from
+    when the wake-up was due) for each wake-up more than ``threshold``
+    late, the ``keep`` longest at most. ``clock`` and ``sleep`` are
+    injectable so that a test can make a wake-up late by hand.
+    """
+
+    def __init__(self, state: ObsState, interval: float = 0.010,
+                 threshold: float = 0.020, keep: int = 256,
+                 clock=time.perf_counter, sleep=time.sleep):
+        self._state = state
+        self.interval, self.threshold, self.keep = interval, threshold, keep
+        self._clock, self._sleep = clock, sleep
+        self.max_late_s = 0.0
+        self._pauses: list = []        # min-heap of (late, due)
+        self._stop = False
+        self._thread: Optional[threading.Thread] = None
+
+    def cycle(self) -> None:
+        """One sleep and its lateness."""
+        due = self._clock() + self.interval
+        self._sleep(self.interval)
+        late = self._clock() - due
+        if late > self.max_late_s:
+            self.max_late_s = late
+        if late > self.threshold:
+            push = (heapq.heappush if len(self._pauses) < self.keep
+                    else heapq.heappushpop)
+            push(self._pauses, (late, due))
+
+    def _run(self) -> None:
+        while not self._stop:
+            self.cycle()
+
+    def start(self) -> "PauseMeter":
+        if self._thread is None:
+            self._stop = False
+            self._thread = threading.Thread(
+                target=self._run, name="hstd-pause-meter", daemon=True)
+            self._thread.start()
+        return self
+
+    def stop(self) -> None:
+        """Stop the thread and write what it saw."""
+        self._stop = True
+        if self._thread is not None:
+            self._thread.join(timeout=1.0 + 10 * self.interval)
+            self._thread = None
+        events = self._state.events
+        if events is None:
+            return
+        events.emit("metric", {"name": "host/pause_max_s",
+                               "value": round(self.max_late_s, 6)})
+        mono0 = self._state.mono0
+        events.emit_many("host_pause", [
+            {"mono": round(due - mono0, 6), "dur": round(late, 6)}
+            for late, due in sorted(self._pauses, key=lambda p: p[1])])
 
 
 ENV_COMPILE_BUDGET = "HSTD_COMPILE_BUDGET_S"

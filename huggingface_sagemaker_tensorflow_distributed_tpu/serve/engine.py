@@ -139,6 +139,19 @@ gather bucket, speculative window acceptance, COW copies, admission
 ``obsctl timeline|slo|tail`` reconstruct. All stamps are host-side
 ``perf_counter`` reads: the accounting mints zero compiled variants,
 and ``timeline='off'`` is byte-identical to the pre-tracing stream.
+
+Every iteration also accounts for its own wall time, sink or no sink
+(ISSUE 25): ``_lap`` stamps the clock where the host changes what it is
+doing and adds the stretch since the last stamp to one of four disjoint
+parts — ``stage_s`` (host work before a dispatch or a fetch),
+``dispatch_s`` (inside the jitted calls), ``fetch_wait_s`` (blocked on
+the device), ``commit_s`` (host work on what a fetch or a dispatch
+returned) — so ``dur_s >= stage_s + dispatch_s + fetch_wait_s +
+commit_s`` on every ledger line, what is left being the gauges.
+``gap_s`` is the caller's time since the previous iteration returned
+(the ledger's own write falls there). :meth:`ServeEngine.
+host_loop_totals` has the run's sums; the ``serve/*`` spans are the
+same stretches by name, on the profiler's clock too (``obs/core.py``).
 """
 
 from __future__ import annotations
@@ -692,6 +705,13 @@ def _copy_block(pools, src, dst):
 def _copy_block_jit(donate: bool):
     # graftlint: allow[R3] no static key by design: pools are traced arrays and src/dst are traced scalars, so ONE compile covers every COW a pool geometry performs
     return jax.jit(_copy_block, donate_argnums=(0,) if donate else ())
+
+
+# the parts of an iteration's wall time: indices of `_iter_parts` and,
+# with the iteration's length and the gap before it, of `_host_totals`
+_STAGE, _DISPATCH, _FETCH, _COMMIT, _DUR, _GAP = range(6)
+HOST_LOOP_FIELDS = ("stage_s", "dispatch_s", "fetch_wait_s", "commit_s",
+                    "dur_s", "gap_s")
 
 
 class _PendingDecode(NamedTuple):
@@ -1363,6 +1383,14 @@ class ServeEngine:
         self._iter_prefill_s = 0.0
         self._iter_decode_s = 0.0
         self._iter_decode_slots = 0
+        # the iteration's account of its own wall time (always on:
+        # stamps and float adds): the four parts of THIS iteration, the
+        # last stamp, when the previous iteration returned, and the
+        # run's sums of the parts, of dur_s and of gap_s
+        self._iter_parts = [0.0, 0.0, 0.0, 0.0]
+        self._t_lap = 0.0
+        self._t_step_end: Optional[float] = None
+        self._host_totals = [0.0, 0.0, 0.0, 0.0, 0.0, 0.0]
         # host-RAM KV spill tier (ISSUE 17). `off` leaves every hook
         # uninstalled — scheduler, BlockManager and telemetry behave
         # byte-identically to the pre-tier engine. Otherwise the
@@ -1585,26 +1613,29 @@ class ServeEngine:
         if req.sampled:
             self._keys[req.rid] = np.asarray(jax.random.PRNGKey(req.seed),
                                              np.uint32)
-        extra = {}
         if req.arrival_s is not None:
             self._has_arrivals = True
-            extra["arrival_s"] = round(req.arrival_s, 6)
         if req.has_slo:
             self._has_slo = True
+        if req.priority:
+            self._has_priorities = True
+        if obs.has_sink():
+            extra = {}
+            if req.arrival_s is not None:
+                extra["arrival_s"] = round(req.arrival_s, 6)
             if req.slo_ttft_s is not None:
                 extra["slo_ttft_s"] = req.slo_ttft_s
             if req.slo_tpot_s is not None:
                 extra["slo_tpot_s"] = req.slo_tpot_s
-        if req.deadline_s is not None:
-            extra["deadline_s"] = req.deadline_s
-        if req.priority:
-            self._has_priorities = True
-            extra["priority"] = req.priority
-        obs.serve("submit", request=req.rid,
-                  prompt_len=len(req.prompt),
-                  max_new_tokens=req.max_new_tokens,
-                  sampled=req.sampled, **self._replica_kw(),
-                  **self._trace_kw(req), **extra)
+            if req.deadline_s is not None:
+                extra["deadline_s"] = req.deadline_s
+            if req.priority:
+                extra["priority"] = req.priority
+            obs.serve("submit", request=req.rid,
+                      prompt_len=len(req.prompt),
+                      max_new_tokens=req.max_new_tokens,
+                      sampled=req.sampled, **self._replica_kw(),
+                      **self._trace_kw(req), **extra)
         return req
 
     def output_ids(self, req: Request) -> np.ndarray:
@@ -1632,7 +1663,11 @@ class ServeEngine:
         modes = [m for m in modes if m not in self._warmed_modes]
         if not modes:
             return
-        with self._mesh_ctx(), obs.span("serve/warmup"):
+        # life-cycle spans: kept even though no telemetry directory is
+        # configured yet (a benchmark switches telemetry on after
+        # warm-up), one child per program, each ending when the device
+        # has run it
+        with self._mesh_ctx(), obs.lifecycle_span("serve/warmup"):
             C = self.sched.prefill_chunk
             nb = self.max_blocks_per_seq
             S = self.num_slots
@@ -1644,55 +1679,61 @@ class ServeEngine:
                 # draft's prefill rides the target's greedy variant
                 # only — drafts never sample at prefill)
                 for G in sorted({1, self.prefill_batch}):
-                    zf = np.zeros((G,), np.float32)
-                    zi = np.zeros((G,), np.int32)
-                    tok, self._pools = self._prefill_fn(
-                        self.model, self.params, self._pools,
-                        np.zeros((G, C), np.int32),
-                        np.zeros((G, nb), np.int32),
-                        zi, np.full((G,), -1, np.int32), zf, zi, zf,
-                        np.zeros((G, 2), np.uint32), zi, self._plan,
-                        mode)
-                    if self.speculative and not mode:
-                        tok, self._d_pools = self._prefill_fn(
-                            self.draft_model, self.draft_params,
-                            self._d_pools,
+                    with obs.lifecycle_span(f"serve/warmup/prefill_g{G}"):
+                        zf = np.zeros((G,), np.float32)
+                        zi = np.zeros((G,), np.int32)
+                        tok, self._pools = self._prefill_fn(
+                            self.model, self.params, self._pools,
                             np.zeros((G, C), np.int32),
                             np.zeros((G, nb), np.int32),
                             zi, np.full((G,), -1, np.int32), zf, zi, zf,
-                            np.zeros((G, 2), np.uint32), zi,
-                            self._d_plan, False)
-                for bucket in self.gather_buckets:
-                    if self.speculative:
-                        (_, _, tok, self._pools,
-                         self._d_pools) = self._spec_fn(
-                            self.model, self.params, self.draft_model,
-                            self.draft_params, self._pools,
-                            self._d_pools, si,
-                            np.zeros((S, nb), np.int32), si,
-                            np.zeros((S,), bool), sf, si, sf,
-                            np.zeros((S, 2), np.uint32), si, self._plan,
-                            self._d_plan, bucket, self.speculate_k,
+                            np.zeros((G, 2), np.uint32), zi, self._plan,
                             mode)
-                    else:
-                        def decode(tokens):
-                            return self._decode_fn(
-                                self.model, self.params, self._pools,
-                                tokens, np.zeros((S, nb), np.int32), si,
+                        if self.speculative and not mode:
+                            tok, self._d_pools = self._prefill_fn(
+                                self.draft_model, self.draft_params,
+                                self._d_pools,
+                                np.zeros((G, C), np.int32),
+                                np.zeros((G, nb), np.int32),
+                                zi, np.full((G,), -1, np.int32), zf, zi,
+                                zf, np.zeros((G, 2), np.uint32), zi,
+                                self._d_plan, False)
+                        jax.block_until_ready(tok)
+                for bucket in self.gather_buckets:
+                    with obs.lifecycle_span(
+                            f"serve/warmup/decode_b{bucket}"):
+                        if self.speculative:
+                            (_, _, tok, self._pools,
+                             self._d_pools) = self._spec_fn(
+                                self.model, self.params, self.draft_model,
+                                self.draft_params, self._pools,
+                                self._d_pools, si,
+                                np.zeros((S, nb), np.int32), si,
                                 np.zeros((S,), bool), sf, si, sf,
                                 np.zeros((S, 2), np.uint32), si,
-                                self._plan, bucket, mode)
+                                self._plan, self._d_plan, bucket,
+                                self.speculate_k, mode)
+                        else:
+                            def decode(tokens):
+                                return self._decode_fn(
+                                    self.model, self.params, self._pools,
+                                    tokens, np.zeros((S, nb), np.int32),
+                                    si, np.zeros((S,), bool), sf, si, sf,
+                                    np.zeros((S, 2), np.uint32), si,
+                                    self._plan, bucket, mode)
 
-                        tok, self._pools = decode(si)
-                        if self.overlap:
-                            # the dispatch-ahead loop feeds the previous
-                            # step's device-resident tokens straight
-                            # back in. Under a mesh a committed array's
-                            # sharding is part of the executable's key,
-                            # so that feed is a second compile (found on
-                            # four chips: two 5 s compiles mid-serve);
-                            # on one device it is a cache hit
-                            tok, self._pools = decode(tok)
+                            tok, self._pools = decode(si)
+                            if self.overlap:
+                                # the dispatch-ahead loop feeds the
+                                # previous step's device-resident tokens
+                                # straight back in. Under a mesh a
+                                # committed array's sharding is part of
+                                # the executable's key, so that feed is
+                                # a second compile (found on four chips:
+                                # two 5 s compiles mid-serve); on one
+                                # device it is a cache hit
+                                tok, self._pools = decode(tok)
+                        jax.block_until_ready(tok)
             if (self.overlap and not self.speculative
                     and not self._warmed_modes):
                 # precompile the dispatch-ahead token-feed select (the
@@ -1749,6 +1790,24 @@ class ServeEngine:
         if summary:
             obs.serve("report", **summary)
         return self.finished
+
+    def host_loop_totals(self) -> dict:
+        """The run's sums of every iteration's account of its wall time
+        (seconds): ``dur_s`` and its four disjoint parts, ``gap_s``
+        between iterations, and ``iterations``. Kept with or without a
+        telemetry sink; with one, they are the sums of the
+        ``iteration_ledger`` lines' fields."""
+        out = dict(zip(HOST_LOOP_FIELDS, self._host_totals))
+        out["iterations"] = self.iterations
+        return out
+
+    def _lap(self, part: int) -> float:
+        """Stamp the clock; the stretch since the last stamp was spent
+        on ``part`` of this iteration. Returns the stamp."""
+        now = time.perf_counter()
+        self._iter_parts[part] += now - self._t_lap
+        self._t_lap = now
+        return now
 
     def slo_summary(self) -> dict:
         """TTFT / end-to-end latency percentiles + scheduler/gather
@@ -2031,101 +2090,114 @@ class ServeEngine:
                 else contextlib.nullcontext())
 
     def _step(self) -> None:
-        t_iter0 = time.perf_counter()
+        t_iter0 = self._t_lap = time.perf_counter()
+        gap_s = (0.0 if self._t_step_end is None
+                 else t_iter0 - self._t_step_end)
+        parts = self._iter_parts
+        parts[:] = (0.0, 0.0, 0.0, 0.0)
         tokens0 = self.tokens_generated
         chunks0, disp0 = self.prefill_chunks, self.prefill_dispatches
         self._iter_prefill_s = 0.0
         self._iter_decode_s = 0.0
         self._iter_decode_slots = 0
-        for slot in self.sched.admit():
-            n_cow = len(slot.pending_copies)
-            if self.timeline:
-                # stamp BEFORE the COW copies run: the queue/preempted
-                # interval ends at admission, and the copy dispatches
-                # land in overhead (the documented contract)
-                self._stamp_admit(slot, n_cow)
-            self._apply_restores(slot)
-            self._apply_cow(slot)
-            extra = {}
-            if self.prefix_cache:
-                extra["prefix_cached_tokens"] = slot.prefill_pos
-            obs.serve("admit", request=slot.request.rid, slot=slot.index,
-                      queue_depth=len(self.sched.waiting),
-                      **self._replica_kw(),
-                      **self._trace_kw(slot.request), **extra)
-        if self.timeline and self.sched.waiting:
-            # admission-block attribution: only the policy's TOP-RANKED
-            # candidate is ever capacity-blocked (everyone behind it is
-            # blocked BY it) — under fifo that is the queue head, under
-            # slo the ranked front — name why it is still waiting
-            head = self.sched.blocked_head()
-            head.blocked_iters += 1
-            head.blocked_reason = (
-                "no_free_slot"
-                if all(not s.free for s in self.sched.slots)
-                else "kv_capacity")
-        self.peak_resident = max(
-            self.peak_resident,
-            sum(1 for s in self.sched.slots if not s.free))
-        C = self.sched.prefill_chunk
-        budget = self.sched.prefill_token_budget(
-            len(self.sched.decode_slots()))
-        while budget >= C:
-            # charged at DISPATCH cost (incl. pad rows of a partially
-            # filled batch), not real chunks — the budget bounds the
-            # decode stall, and the stall is what the device computes
-            dispatched_rows = self._prefill_batch(budget // C)
-            if not dispatched_rows:
-                break
-            budget -= dispatched_rows * C
-        if self.prefill_only:
-            # disaggregated prefill replica (ISSUE 18): no decode phase
-            # at all — no capacity math either, since parked DECODE
-            # slots never grow their tables here (the router migrates
-            # them to a decode replica between iterations, and "zero
-            # decode iterations on a prefill replica" is the bench's
-            # role-separation gate)
-            pass
-        elif not self.overlap:
-            self._capacity_phase()
-            self._decode_all()
-        elif self.speculative:
-            # the in-flight window overlapped the admission/prefill
-            # work above; it must land before the capacity math (the
-            # context advance is data-dependent) and the next dispatch
-            self._commit_spec(self._pending_spec)
-            self._pending_spec = None
-            self._capacity_phase()
-            self._pending_spec = self._dispatch_spec()
-        else:
-            # plain/bucketed/kernel families: flush the pipeline only
-            # when the capacity math could preempt (the recompute path
-            # must see committed state), dispatch N, then commit N−1's
-            # tokens while N runs on the device
-            if (self._pending is not None
-                    and not self._capacity_covered()):
-                self._flush("kv_pressure")
-            self._capacity_phase()
-            if self._lone_stream():
-                # low-load auto-flush (ISSUE 13, the PR 12 TTFT
-                # follow-up): a LONE stream with nothing waiting has
-                # no concurrent host work for the pipeline to hide —
-                # dispatch-ahead would only defer every token's fetch
-                # (and the final token's delivery) by one iteration.
-                # Run this iteration serially instead: land any
-                # in-flight dispatch (a plain commit, not a forced
-                # drain — overlap_flushes counts mandatory drains
-                # only), then dispatch+fetch in one go, exactly the
-                # overlap='off' schedule. The condition re-evaluates
-                # every iteration, so the pipeline re-engages the
-                # moment a second stream admits.
-                prev, self._pending = self._pending, None
-                self._commit_decode(prev)
+        sink = obs.has_sink()
+        with obs.span("serve/step",
+                      {"iteration": self.iterations} if sink else None):
+            with obs.span("serve/admit"):
+                for slot in self.sched.admit():
+                    n_cow = len(slot.pending_copies)
+                    if self.timeline:
+                        # stamp BEFORE the COW copies run: the
+                        # queue/preempted interval ends at admission,
+                        # and the copy dispatches land in overhead (the
+                        # documented contract)
+                        self._stamp_admit(slot, n_cow)
+                    self._apply_restores(slot)
+                    self._apply_cow(slot)
+                    if sink:
+                        extra = {}
+                        if self.prefix_cache:
+                            extra["prefix_cached_tokens"] = slot.prefill_pos
+                        obs.serve("admit", request=slot.request.rid,
+                                  slot=slot.index,
+                                  queue_depth=len(self.sched.waiting),
+                                  **self._replica_kw(),
+                                  **self._trace_kw(slot.request), **extra)
+                if self.timeline and self.sched.waiting:
+                    # admission-block attribution: only the policy's
+                    # TOP-RANKED candidate is ever capacity-blocked
+                    # (everyone behind it is blocked BY it) — under fifo
+                    # that is the queue head, under slo the ranked front
+                    # — name why it is still waiting
+                    head = self.sched.blocked_head()
+                    head.blocked_iters += 1
+                    head.blocked_reason = (
+                        "no_free_slot"
+                        if all(not s.free for s in self.sched.slots)
+                        else "kv_capacity")
+                self.peak_resident = max(
+                    self.peak_resident,
+                    sum(1 for s in self.sched.slots if not s.free))
+            self._lap(_STAGE)
+            C = self.sched.prefill_chunk
+            budget = self.sched.prefill_token_budget(
+                len(self.sched.decode_slots()))
+            while budget >= C:
+                # charged at DISPATCH cost (incl. pad rows of a partially
+                # filled batch), not real chunks — the budget bounds the
+                # decode stall, and the stall is what the device computes
+                dispatched_rows = self._prefill_batch(budget // C)
+                if not dispatched_rows:
+                    break
+                budget -= dispatched_rows * C
+            if self.prefill_only:
+                # disaggregated prefill replica (ISSUE 18): no decode phase
+                # at all — no capacity math either, since parked DECODE
+                # slots never grow their tables here (the router migrates
+                # them to a decode replica between iterations, and "zero
+                # decode iterations on a prefill replica" is the bench's
+                # role-separation gate)
+                pass
+            elif not self.overlap:
+                self._capacity_phase()
                 self._decode_all()
+            elif self.speculative:
+                # the in-flight window overlapped the admission/prefill
+                # work above; it must land before the capacity math (the
+                # context advance is data-dependent) and the next dispatch
+                self._commit_spec(self._pending_spec)
+                self._pending_spec = None
+                self._capacity_phase()
+                self._pending_spec = self._dispatch_spec()
             else:
-                prev, self._pending = (self._pending,
-                                       self._dispatch_decode())
-                self._commit_decode(prev)
+                # plain/bucketed/kernel families: flush the pipeline only
+                # when the capacity math could preempt (the recompute path
+                # must see committed state), dispatch N, then commit N−1's
+                # tokens while N runs on the device
+                if (self._pending is not None
+                        and not self._capacity_covered()):
+                    self._flush("kv_pressure")
+                self._capacity_phase()
+                if self._lone_stream():
+                    # low-load auto-flush (ISSUE 13, the PR 12 TTFT
+                    # follow-up): a LONE stream with nothing waiting has
+                    # no concurrent host work for the pipeline to hide —
+                    # dispatch-ahead would only defer every token's fetch
+                    # (and the final token's delivery) by one iteration.
+                    # Run this iteration serially instead: land any
+                    # in-flight dispatch (a plain commit, not a forced
+                    # drain — overlap_flushes counts mandatory drains
+                    # only), then dispatch+fetch in one go, exactly the
+                    # overlap='off' schedule. The condition re-evaluates
+                    # every iteration, so the pipeline re-engages the
+                    # moment a second stream admits.
+                    prev, self._pending = self._pending, None
+                    self._commit_decode(prev)
+                    self._decode_all()
+                else:
+                    prev, self._pending = (self._pending,
+                                           self._dispatch_decode())
+                    self._commit_decode(prev)
         # per-iteration scheduler gauges (SLO telemetry): queue pressure
         # and slot occupancy as series, one sample per engine iteration
         waiting = len(self.sched.waiting)
@@ -2142,7 +2214,47 @@ class ServeEngine:
             self._arrival_backlog_peak = max(
                 self._arrival_backlog_peak, backlog)
             arrival_kw["arrival_backlog"] = backlog
-        if obs.has_sink():
+        # the iteration ends here: what follows (the ledger's own
+        # write) falls into the next iteration's gap_s, so dur_s and
+        # gap_s together tile the loop's wall time
+        t_end = self._t_step_end = time.perf_counter()
+        dur_s = t_end - t_iter0
+        totals = self._host_totals
+        totals[_STAGE] += parts[_STAGE]
+        totals[_DISPATCH] += parts[_DISPATCH]
+        totals[_FETCH] += parts[_FETCH]
+        totals[_COMMIT] += parts[_COMMIT]
+        totals[_DUR] += dur_s
+        totals[_GAP] += gap_s
+        if sink and self.timeline:
+            # the engine ledger: one line per iteration with the phase
+            # mix (prefill vs decode dispatch seconds inside the
+            # iteration wall), the iteration's account of its wall time
+            # (`_lap`), the bucket, the slot/token throughput, and
+            # queue and pool pressure — what `obsctl tail` follows live
+            obs.serve(
+                "iteration_ledger", iteration=self.iterations,
+                dur_s=round(dur_s, 6),
+                prefill_s=round(self._iter_prefill_s, 6),
+                decode_s=round(self._iter_decode_s, 6),
+                stage_s=round(parts[_STAGE], 6),
+                dispatch_s=round(parts[_DISPATCH], 6),
+                fetch_wait_s=round(parts[_FETCH], 6),
+                commit_s=round(parts[_COMMIT], 6),
+                gap_s=round(gap_s, 6),
+                gather_bucket=self._bucket,
+                prefill_chunks=self.prefill_chunks - chunks0,
+                prefill_dispatches=self.prefill_dispatches - disp0,
+                decode_slots=self._iter_decode_slots,
+                tokens=self.tokens_generated - tokens0,
+                waiting=waiting,
+                preemptions=self.sched.n_preemptions,
+                kv_used_frac=round(self.blocks.utilization(), 4),
+                **arrival_kw, **self._replica_kw())
+        elif sink:
+            # timeline off: the per-iteration gauges as series, which
+            # `obsctl tail` falls back to (with it on, the ledger line
+            # above carries all four)
             obs.scalar("serve/waiting_depth", waiting, self.iterations)
             obs.scalar("serve/running_slots",
                        len(self.sched.decode_slots()), self.iterations)
@@ -2150,41 +2262,27 @@ class ServeEngine:
                        self.iterations)
             obs.scalar("serve/gather_bucket", self._bucket,
                        self.iterations)
-            if self.timeline:
-                # the engine ledger: one line per iteration with the
-                # phase mix (prefill vs decode dispatch seconds inside
-                # the iteration wall), the bucket, the slot/token
-                # throughput, and pool pressure — what `obsctl tail`
-                # follows live
-                obs.serve(
-                    "iteration_ledger", iteration=self.iterations,
-                    dur_s=round(time.perf_counter() - t_iter0, 6),
-                    prefill_s=round(self._iter_prefill_s, 6),
-                    decode_s=round(self._iter_decode_s, 6),
-                    gather_bucket=self._bucket,
-                    prefill_chunks=self.prefill_chunks - chunks0,
-                    prefill_dispatches=self.prefill_dispatches - disp0,
-                    decode_slots=self._iter_decode_slots,
-                    tokens=self.tokens_generated - tokens0,
-                    waiting=waiting,
-                    kv_used_frac=round(self.blocks.utilization(), 4),
-                    **arrival_kw, **self._replica_kw())
         self.iterations += 1
+
 
     def _capacity_phase(self) -> None:
         """Decode-side block capacity for the next dispatch, preempting
         when the pool runs dry (serial semantics — under overlap the
         caller drained the pipeline first when this could preempt)."""
-        for req in self.sched.ensure_decode_capacity():
-            obs.serve("preempt", request=req.rid,
-                      reason="kv_pool_exhausted", **self._replica_kw(),
-                      **self._trace_kw(req))
-            if self.timeline:
-                # the preempted interval runs from here to re-admission;
-                # emit the partial timeline NOW so a request that never
-                # comes back (a killed run) still left its history
-                req.preempt_t = time.perf_counter()
-                self._emit_timeline(req, "preempt", req.preempt_t)
+        with obs.span("serve/capacity"):
+            for req in self.sched.ensure_decode_capacity():
+                if obs.has_sink():
+                    obs.serve("preempt", request=req.rid,
+                              reason="kv_pool_exhausted",
+                              **self._replica_kw(), **self._trace_kw(req))
+                if self.timeline:
+                    # the preempted interval runs from here to
+                    # re-admission; emit the partial timeline NOW so a
+                    # request that never comes back (a killed run) still
+                    # left its history
+                    req.preempt_t = time.perf_counter()
+                    self._emit_timeline(req, "preempt", req.preempt_t)
+        self._lap(_STAGE)
 
     def _lone_stream(self) -> bool:
         """True when decode-batch occupancy is exactly one and the
@@ -2263,37 +2361,38 @@ class ServeEngine:
             min(max_rows, self.prefill_batch))
         if not slots:
             return 0
-        G = 1 if len(slots) == 1 else self.prefill_batch
-        C = self.sched.prefill_chunk
-        chunks = np.full((G, C), self.pad_token_id, np.int32)
-        tables = np.zeros((G, self.max_blocks_per_seq), np.int32)
-        start = np.zeros((G,), np.int32)
-        rel = np.full((G,), -1, np.int32)
-        temps = np.zeros((G,), np.float32)
-        top_ks = np.zeros((G,), np.int32)
-        top_ps = np.zeros((G,), np.float32)
-        keys = np.zeros((G, 2), np.uint32)
-        folds = np.zeros((G,), np.int32)
-        finals = []
-        sampled = False
-        for i, slot in enumerate(slots):
-            req = slot.request
-            pos = slot.prefill_pos
-            real = req.prompt[pos:pos + C]
-            chunks[i, :len(real)] = real
-            tables[i, :len(slot.table)] = slot.table
-            start[i] = pos
-            if pos + C >= self.sched.padded_prompt_len(req):
-                rel[i] = (len(req.prompt) - 1) - pos
-                finals.append((i, slot))
-                if req.sampled:
-                    sampled = True
-                    temps[i] = req.temperature
-                    top_ks[i] = req.top_k
-                    top_ps[i] = req.top_p
-                    keys[i] = self._keys[req.rid]
-                    folds[i] = self._generated(req)
-        t0 = time.perf_counter()
+        with obs.span("serve/stage_prefill"):
+            G = 1 if len(slots) == 1 else self.prefill_batch
+            C = self.sched.prefill_chunk
+            chunks = np.full((G, C), self.pad_token_id, np.int32)
+            tables = np.zeros((G, self.max_blocks_per_seq), np.int32)
+            start = np.zeros((G,), np.int32)
+            rel = np.full((G,), -1, np.int32)
+            temps = np.zeros((G,), np.float32)
+            top_ks = np.zeros((G,), np.int32)
+            top_ps = np.zeros((G,), np.float32)
+            keys = np.zeros((G, 2), np.uint32)
+            folds = np.zeros((G,), np.int32)
+            finals = []
+            sampled = False
+            for i, slot in enumerate(slots):
+                req = slot.request
+                pos = slot.prefill_pos
+                real = req.prompt[pos:pos + C]
+                chunks[i, :len(real)] = real
+                tables[i, :len(slot.table)] = slot.table
+                start[i] = pos
+                if pos + C >= self.sched.padded_prompt_len(req):
+                    rel[i] = (len(req.prompt) - 1) - pos
+                    finals.append((i, slot))
+                    if req.sampled:
+                        sampled = True
+                        temps[i] = req.temperature
+                        top_ks[i] = req.top_k
+                        top_ps[i] = req.top_p
+                        keys[i] = self._keys[req.rid]
+                        folds[i] = self._generated(req)
+        t0 = self._lap(_STAGE)
         with obs.span("serve/prefill_chunk",
                       {"chunks": len(slots)} if obs.has_sink() else None):
             tok, self._pools = self._prefill_fn(
@@ -2308,11 +2407,11 @@ class ServeEngine:
                     self.draft_model, self.draft_params, self._d_pools,
                     chunks, tables, start, rel, temps, top_ks, top_ps,
                     keys, folds, self._d_plan, False)
+        dur = self._lap(_DISPATCH) - t0
         if self.timeline:
             # dispatch-enqueue wall time (an async backend's device
             # wait surfaces at the next sync and lands in overhead —
             # attribution stays disjoint, never double-counted)
-            dur = time.perf_counter() - t0
             self._iter_prefill_s += dur
             for slot in slots:
                 self._accrue_prefill(slot, t0, dur)
@@ -2321,28 +2420,34 @@ class ServeEngine:
         self.prefill_chunks += len(slots)
         self.prefill_dispatches += 1
         if finals:
+            self._lap(_COMMIT)
             # fetch the continuation tokens; also the sync point that
             # makes TTFT an honest end-to-end wall time
-            # graftlint: allow[R2] first-token fetch at prompt completion: the value gates the slot's prefill->decode flip and is the sync that keeps TTFT an honest wall time
-            tok_host = np.asarray(jax.device_get(tok))
-            for i, slot in finals:
-                req = slot.request
-                self.sched.finish_prefill(slot)
-                if self.speculative and self._generated(req) > 0:
-                    # preemption-resumed speculative request: its next
-                    # token's index is mid-stream, and mid-stream
-                    # tokens come from verify windows — emitting the
-                    # prefill sample here would consume a different
-                    # RNG draw than the uninterrupted run's window did
-                    # (breaking bitwise seed-reproducibility across
-                    # preemption). Hand the slot to the window loop
-                    # instead: its newest committed token is the
-                    # folded prompt's last id, whose K/V the next
-                    # window re-writes at context_len (same value the
-                    # prefill just wrote — an idempotent overwrite)
-                    slot.context_len -= 1
-                else:
-                    self._append(slot, int(tok_host[i]))
+            with obs.span("serve/first_token_fetch"):
+                # graftlint: allow[R2] first-token fetch at prompt completion: the value gates the slot's prefill->decode flip and is the sync that keeps TTFT an honest wall time
+                tok_host = np.asarray(jax.device_get(tok))
+            self._lap(_FETCH)
+            with obs.span("serve/commit"):
+                for i, slot in finals:
+                    req = slot.request
+                    self.sched.finish_prefill(slot)
+                    if self.speculative and self._generated(req) > 0:
+                        # preemption-resumed speculative request: its
+                        # next token's index is mid-stream, and
+                        # mid-stream tokens come from verify windows —
+                        # emitting the prefill sample here would consume
+                        # a different RNG draw than the uninterrupted
+                        # run's window did (breaking bitwise
+                        # seed-reproducibility across preemption). Hand
+                        # the slot to the window loop instead: its
+                        # newest committed token is the folded prompt's
+                        # last id, whose K/V the next window re-writes
+                        # at context_len (same value the prefill just
+                        # wrote — an idempotent overwrite)
+                        slot.context_len -= 1
+                    else:
+                        self._append(slot, int(tok_host[i]))
+        self._lap(_COMMIT)
         return G
 
     def _decode_all(self) -> None:
@@ -2351,49 +2456,50 @@ class ServeEngine:
         ds = self.sched.decode_slots()
         if not ds:
             return
-        bucket = self._select_bucket(self.sched.max_decode_context())
-        S = self.num_slots
-        tokens = np.zeros((S,), np.int32)
-        tables = np.zeros((S, self.max_blocks_per_seq), np.int32)
-        ctx = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        temps = np.zeros((S,), np.float32)
-        top_ks = np.zeros((S,), np.int32)
-        top_ps = np.zeros((S,), np.float32)
-        keys = np.zeros((S, 2), np.uint32)
-        folds = np.zeros((S,), np.int32)
-        sampled = False
-        for slot in ds:
-            req = slot.request
-            i = slot.index
-            tokens[i] = req.output[-1]
-            tables[i, :len(slot.table)] = slot.table
-            ctx[i] = slot.context_len
-            active[i] = True
-            if req.sampled:
-                sampled = True
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
-                top_ps[i] = req.top_p
-                keys[i] = self._keys[req.rid]
-                folds[i] = self._generated(req)
-        self.blocks.note_gather([s.context_len + 1 for s in ds], bucket)
-        # the step's KV read traffic in POOL bytes (every slot row of
-        # the dispatch × the bucket width × bytes/token across pools —
-        # int8 pools halve this, which is the point): one scalar per
-        # decode step, aggregated into the SLO report
-        step_bytes = self.num_slots * bucket * self.blocks.token_bytes
-        self.kv_bytes_read += step_bytes
-        if obs.has_sink():
-            obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
-        # blocks_saved() == 0 means no block is shared right now — the
-        # per-slot table walk would only accumulate zeros, so skip it
-        # (the common case for non-templated traffic with the cache on)
-        if self.prefix_cache and self.blocks.blocks_saved() > 0:
-            self.blocks.note_shared_reads(sum(
-                self.blocks.shared_read_tokens(s.table, s.context_len)
-                for s in ds))
-        t0 = time.perf_counter()
+        with obs.span("serve/stage_decode"):
+            bucket = self._select_bucket(self.sched.max_decode_context())
+            S = self.num_slots
+            tokens = np.zeros((S,), np.int32)
+            tables = np.zeros((S, self.max_blocks_per_seq), np.int32)
+            ctx = np.zeros((S,), np.int32)
+            active = np.zeros((S,), bool)
+            temps = np.zeros((S,), np.float32)
+            top_ks = np.zeros((S,), np.int32)
+            top_ps = np.zeros((S,), np.float32)
+            keys = np.zeros((S, 2), np.uint32)
+            folds = np.zeros((S,), np.int32)
+            sampled = False
+            for slot in ds:
+                req = slot.request
+                i = slot.index
+                tokens[i] = req.output[-1]
+                tables[i, :len(slot.table)] = slot.table
+                ctx[i] = slot.context_len
+                active[i] = True
+                if req.sampled:
+                    sampled = True
+                    temps[i] = req.temperature
+                    top_ks[i] = req.top_k
+                    top_ps[i] = req.top_p
+                    keys[i] = self._keys[req.rid]
+                    folds[i] = self._generated(req)
+            self.blocks.note_gather([s.context_len + 1 for s in ds], bucket)
+            # the step's KV read traffic in POOL bytes (every slot row of
+            # the dispatch × the bucket width × bytes/token across pools —
+            # int8 pools halve this, which is the point): one scalar per
+            # decode step, aggregated into the SLO report
+            step_bytes = self.num_slots * bucket * self.blocks.token_bytes
+            self.kv_bytes_read += step_bytes
+            if obs.has_sink():
+                obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
+            # blocks_saved() == 0 means no block is shared right now — the
+            # per-slot table walk would only accumulate zeros, so skip it
+            # (the common case for non-templated traffic with the cache on)
+            if self.prefix_cache and self.blocks.blocks_saved() > 0:
+                self.blocks.note_shared_reads(sum(
+                    self.blocks.shared_read_tokens(s.table, s.context_len)
+                    for s in ds))
+        t0 = self._lap(_STAGE)
         with obs.span("serve/decode_step",
                       {"active": len(ds), "gather_bucket": bucket}
                       if obs.has_sink() else None):
@@ -2401,20 +2507,24 @@ class ServeEngine:
                 self.model, self.params, self._pools, tokens, tables,
                 ctx, active, temps, top_ks, top_ps, keys, folds,
                 self._plan, bucket, sampled)
-            # graftlint: allow[R2] the SERIAL loop's per-step fetch: this is the overlap=off reference implementation the dispatch-ahead gates compare against, serial by definition
-            nxt = np.asarray(jax.device_get(nxt))
-        dur = time.perf_counter() - t0
+            self._lap(_DISPATCH)
+            with obs.span("serve/commit_fetch"):
+                # graftlint: allow[R2] the SERIAL loop's per-step fetch: this is the overlap=off reference implementation the dispatch-ahead gates compare against, serial by definition
+                nxt = np.asarray(jax.device_get(nxt))
+        dur = self._lap(_FETCH) - t0
         self.decode_time_s += dur
         self.decode_steps += 1
         self.decode_tokens += len(ds)
         if self.timeline:
             self._iter_decode_s += dur
             self._iter_decode_slots = len(ds)
-        for slot in ds:
-            slot.context_len += 1        # the fed token's K/V landed
-            if self.timeline:
-                self._accrue_decode(slot.request, t0, dur, bucket, 1)
-            self._append(slot, int(nxt[slot.index]))
+        with obs.span("serve/commit"):
+            for slot in ds:
+                slot.context_len += 1    # the fed token's K/V landed
+                if self.timeline:
+                    self._accrue_decode(slot.request, t0, dur, bucket, 1)
+                self._append(slot, int(nxt[slot.index]))
+        self._lap(_COMMIT)
 
     def _dispatch_decode(self) -> Optional[_PendingDecode]:
         """Dispatch-ahead plain decode (ISSUE 12): enqueue iteration N
@@ -2448,62 +2558,63 @@ class ServeEngine:
             ds.append(slot)
         if not ds:
             return None
-        bucket = self._select_bucket(
-            max(s.context_len + self.sched.decode_lookahead
-                for s in ds))
-        S = self.num_slots
-        vals = np.zeros((S,), np.int32)
-        use_dev = np.zeros((S,), bool)
-        tables = np.zeros((S, self.max_blocks_per_seq), np.int32)
-        ctx = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        temps = np.zeros((S,), np.float32)
-        top_ks = np.zeros((S,), np.int32)
-        top_ps = np.zeros((S,), np.float32)
-        keys = np.zeros((S, 2), np.uint32)
-        folds = np.zeros((S,), np.int32)
-        sampled = False
-        for slot in ds:
-            req = slot.request
-            i = slot.index
-            if slot.inflight:
-                use_dev[i] = True
+        with obs.span("serve/stage_decode"):
+            bucket = self._select_bucket(
+                max(s.context_len + self.sched.decode_lookahead
+                    for s in ds))
+            S = self.num_slots
+            vals = np.zeros((S,), np.int32)
+            use_dev = np.zeros((S,), bool)
+            tables = np.zeros((S, self.max_blocks_per_seq), np.int32)
+            ctx = np.zeros((S,), np.int32)
+            active = np.zeros((S,), bool)
+            temps = np.zeros((S,), np.float32)
+            top_ks = np.zeros((S,), np.int32)
+            top_ps = np.zeros((S,), np.float32)
+            keys = np.zeros((S, 2), np.uint32)
+            folds = np.zeros((S,), np.int32)
+            sampled = False
+            for slot in ds:
+                req = slot.request
+                i = slot.index
+                if slot.inflight:
+                    use_dev[i] = True
+                else:
+                    # a DECODE slot always has output resident (prefill
+                    # appends the first token before the state flips) —
+                    # same invariant the serial loop indexes on
+                    vals[i] = req.output[-1]
+                tables[i, :len(slot.table)] = slot.table
+                ctx[i] = slot.context_len
+                active[i] = True
+                if req.sampled:
+                    sampled = True
+                    temps[i] = req.temperature
+                    top_ks[i] = req.top_k
+                    top_ps[i] = req.top_p
+                    keys[i] = self._keys[req.rid]
+                    # the in-flight token counts: token N's fold index is
+                    # its request-global position, exactly the serial value
+                    folds[i] = self._generated(req) + slot.inflight
+            self.blocks.note_gather([s.context_len + 1 for s in ds], bucket)
+            step_bytes = self.num_slots * bucket * self.blocks.token_bytes
+            self.kv_bytes_read += step_bytes
+            if obs.has_sink():
+                obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
+            if self.prefix_cache and self.blocks.blocks_saved() > 0:
+                self.blocks.note_shared_reads(sum(
+                    self.blocks.shared_read_tokens(s.table, s.context_len)
+                    for s in ds))
+            if prev is None or not use_dev.any():
+                tokens = vals
+            elif all(s.inflight for s in ds):
+                # steady pipeline: every active slot rode the in-flight
+                # dispatch, so its token array IS the feed — no select op
+                # on the device chain at all (the common decode-bound case)
+                tokens = prev.nxt
             else:
-                # a DECODE slot always has output resident (prefill
-                # appends the first token before the state flips) —
-                # same invariant the serial loop indexes on
-                vals[i] = req.output[-1]
-            tables[i, :len(slot.table)] = slot.table
-            ctx[i] = slot.context_len
-            active[i] = True
-            if req.sampled:
-                sampled = True
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
-                top_ps[i] = req.top_p
-                keys[i] = self._keys[req.rid]
-                # the in-flight token counts: token N's fold index is
-                # its request-global position, exactly the serial value
-                folds[i] = self._generated(req) + slot.inflight
-        self.blocks.note_gather([s.context_len + 1 for s in ds], bucket)
-        step_bytes = self.num_slots * bucket * self.blocks.token_bytes
-        self.kv_bytes_read += step_bytes
-        if obs.has_sink():
-            obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
-        if self.prefix_cache and self.blocks.blocks_saved() > 0:
-            self.blocks.note_shared_reads(sum(
-                self.blocks.shared_read_tokens(s.table, s.context_len)
-                for s in ds))
-        if prev is None or not use_dev.any():
-            tokens = vals
-        elif all(s.inflight for s in ds):
-            # steady pipeline: every active slot rode the in-flight
-            # dispatch, so its token array IS the feed — no select op
-            # on the device chain at all (the common decode-bound case)
-            tokens = prev.nxt
-        else:
-            tokens = jnp.where(use_dev, prev.nxt, vals)
-        t0 = time.perf_counter()
+                tokens = jnp.where(use_dev, prev.nxt, vals)
+        t0 = self._lap(_STAGE)
         with obs.span("serve/decode_step",
                       {"active": len(ds), "gather_bucket": bucket}
                       if obs.has_sink() else None):
@@ -2511,7 +2622,7 @@ class ServeEngine:
                 self.model, self.params, self._pools, tokens, tables,
                 ctx, active, temps, top_ks, top_ps, keys, folds,
                 self._plan, bucket, sampled)
-        dispatch_s = time.perf_counter() - t0
+        dispatch_s = self._lap(_DISPATCH) - t0
         if self.timeline:
             # the enqueue cost lands in THIS iteration's ledger (the
             # blocked fetch lands in the committing iteration's), so
@@ -2537,10 +2648,11 @@ class ServeEngine:
         output exactly."""
         if prev is None:
             return
-        t0 = time.perf_counter()
-        # graftlint: allow[R2] THE deferred commit fetch (ISSUE 12): deliberately one iteration late, so only the residual past the overlapped host work blocks here
-        nxt = np.asarray(prev.nxt)
-        t_end = time.perf_counter()
+        t0 = self._lap(_STAGE)
+        with obs.span("serve/commit_fetch"):
+            # graftlint: allow[R2] THE deferred commit fetch (ISSUE 12): deliberately one iteration late, so only the residual past the overlapped host work blocks here
+            nxt = np.asarray(prev.nxt)
+        t_end = self._lap(_FETCH)
         fetch_s = t_end - t0
         # the ENGINE's decode-time accounting stays blocked-time only
         # (dispatch enqueue + residual fetch wait): the host work in
@@ -2551,36 +2663,38 @@ class ServeEngine:
         # riders of the CURRENT in-flight dispatch keep their inflight
         # mark (dispatch N ran before this commit of N−1 and re-marked
         # them); everyone else's newest token is host-resident again
-        live = {id(s) for s, _ in (self._pending.riders
-                                   if self._pending is not None else ())}
-        committed = 0
-        for slot, req in prev.riders:
-            if id(slot) not in live:
-                slot.inflight = 0
-            if req.rid in self.finished or slot.request is not req:
-                continue         # wasted row past an EOS: discarded
-            committed += 1
-            self.decode_tokens += 1
-            if self.timeline:
-                # the REQUEST's decode interval is the whole
-                # dispatch→fetch window — the host work inside it ran
-                # concurrently with the device, so it is decode time,
-                # not overhead — clipped to the request's previous
-                # attributed end so intervals stay disjoint (the
-                # checkable-decomposition invariant): back-to-back
-                # overlapped iterations tile the decode-bound stretch
-                # with no overhead gaps, which is the decomposition's
-                # view of the de-overheaded loop
-                start = prev.t_dispatch
-                if req.decode_attr_end is not None:
-                    start = max(start, req.decode_attr_end)
-                self._accrue_decode(req, start, t_end - start,
-                                    prev.bucket, 1)
-                req.decode_attr_end = t_end
-            self._append(slot, int(nxt[slot.index]))
+        with obs.span("serve/commit"):
+            live = {id(s) for s, _ in (self._pending.riders
+                                       if self._pending is not None else ())}
+            committed = 0
+            for slot, req in prev.riders:
+                if id(slot) not in live:
+                    slot.inflight = 0
+                if req.rid in self.finished or slot.request is not req:
+                    continue         # wasted row past an EOS: discarded
+                committed += 1
+                self.decode_tokens += 1
+                if self.timeline:
+                    # the REQUEST's decode interval is the whole
+                    # dispatch→fetch window — the host work inside it ran
+                    # concurrently with the device, so it is decode time,
+                    # not overhead — clipped to the request's previous
+                    # attributed end so intervals stay disjoint (the
+                    # checkable-decomposition invariant): back-to-back
+                    # overlapped iterations tile the decode-bound stretch
+                    # with no overhead gaps, which is the decomposition's
+                    # view of the de-overheaded loop
+                    start = prev.t_dispatch
+                    if req.decode_attr_end is not None:
+                        start = max(start, req.decode_attr_end)
+                    self._accrue_decode(req, start, t_end - start,
+                                        prev.bucket, 1)
+                    req.decode_attr_end = t_end
+                self._append(slot, int(nxt[slot.index]))
         if self.timeline:
             self._iter_decode_s += fetch_s
             self._iter_decode_slots = committed
+        self._lap(_COMMIT)
 
     def _decode_all_spec(self) -> None:
         """One SERIAL speculative iteration: dispatch + immediate
@@ -2601,50 +2715,51 @@ class ServeEngine:
         ds = self.sched.decode_slots()
         if not ds:
             return None
-        k = self.speculate_k
-        bucket = self._select_bucket(self.sched.max_decode_context())
-        S = self.num_slots
-        tokens = np.zeros((S,), np.int32)
-        tables = np.zeros((S, self.max_blocks_per_seq), np.int32)
-        ctx = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        temps = np.zeros((S,), np.float32)
-        top_ks = np.zeros((S,), np.int32)
-        top_ps = np.zeros((S,), np.float32)
-        keys = np.zeros((S, 2), np.uint32)
-        folds = np.zeros((S,), np.int32)
-        sampled = False
-        for slot in ds:
-            req = slot.request
-            i = slot.index
-            # newest committed token: the last generated one, or the
-            # prompt tail when no generation is resident in `output`
-            # (fresh post-preemption resume)
-            tokens[i] = req.output[-1] if req.output else req.prompt[-1]
-            tables[i, :len(slot.table)] = slot.table
-            ctx[i] = slot.context_len
-            active[i] = True
-            if req.sampled:
-                sampled = True
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
-                top_ps[i] = req.top_p
-                keys[i] = self._keys[req.rid]
-                folds[i] = self._generated(req)   # window start index
-        self.blocks.note_gather(
-            [s.context_len + k + 1 for s in ds], bucket)
-        # draft (k+1 steps) + verify each read a bucket-wide assembled
-        # cache: the target-pool read is what the fp-vs-int8 comparison
-        # isolates, so account the verify read (one bucket per slot row)
-        step_bytes = self.num_slots * bucket * self.blocks.token_bytes
-        self.kv_bytes_read += step_bytes
-        if obs.has_sink():
-            obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
-        if self.prefix_cache and self.blocks.blocks_saved() > 0:
-            self.blocks.note_shared_reads(sum(
-                self.blocks.shared_read_tokens(s.table, s.context_len)
-                for s in ds))
-        t0 = time.perf_counter()
+        with obs.span("serve/stage_decode"):
+            k = self.speculate_k
+            bucket = self._select_bucket(self.sched.max_decode_context())
+            S = self.num_slots
+            tokens = np.zeros((S,), np.int32)
+            tables = np.zeros((S, self.max_blocks_per_seq), np.int32)
+            ctx = np.zeros((S,), np.int32)
+            active = np.zeros((S,), bool)
+            temps = np.zeros((S,), np.float32)
+            top_ks = np.zeros((S,), np.int32)
+            top_ps = np.zeros((S,), np.float32)
+            keys = np.zeros((S, 2), np.uint32)
+            folds = np.zeros((S,), np.int32)
+            sampled = False
+            for slot in ds:
+                req = slot.request
+                i = slot.index
+                # newest committed token: the last generated one, or the
+                # prompt tail when no generation is resident in `output`
+                # (fresh post-preemption resume)
+                tokens[i] = req.output[-1] if req.output else req.prompt[-1]
+                tables[i, :len(slot.table)] = slot.table
+                ctx[i] = slot.context_len
+                active[i] = True
+                if req.sampled:
+                    sampled = True
+                    temps[i] = req.temperature
+                    top_ks[i] = req.top_k
+                    top_ps[i] = req.top_p
+                    keys[i] = self._keys[req.rid]
+                    folds[i] = self._generated(req)   # window start index
+            self.blocks.note_gather(
+                [s.context_len + k + 1 for s in ds], bucket)
+            # draft (k+1 steps) + verify each read a bucket-wide assembled
+            # cache: the target-pool read is what the fp-vs-int8 comparison
+            # isolates, so account the verify read (one bucket per slot row)
+            step_bytes = self.num_slots * bucket * self.blocks.token_bytes
+            self.kv_bytes_read += step_bytes
+            if obs.has_sink():
+                obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
+            if self.prefix_cache and self.blocks.blocks_saved() > 0:
+                self.blocks.note_shared_reads(sum(
+                    self.blocks.shared_read_tokens(s.table, s.context_len)
+                    for s in ds))
+        t0 = self._lap(_STAGE)
         with obs.span("serve/spec_decode_step",
                       {"active": len(ds), "gather_bucket": bucket,
                        "speculate_k": k} if obs.has_sink() else None):
@@ -2655,7 +2770,7 @@ class ServeEngine:
                     tokens, tables, ctx, active, temps, top_ks, top_ps,
                     keys, folds, self._plan, self._d_plan, bucket, k,
                     sampled)
-        dispatch_s = time.perf_counter() - t0
+        dispatch_s = self._lap(_DISPATCH) - t0
         if self.timeline:
             # enqueue cost in the dispatching iteration's ledger (the
             # fetch lands in the committing one's) — see the plain
@@ -2678,11 +2793,12 @@ class ServeEngine:
         ds = [slot for slot, _ in pending.riders]
         k = self.speculate_k
         bucket = pending.bucket
-        t0 = time.perf_counter()
-        # graftlint: allow[R2] the speculative window's deferred commit fetch: one fused tuple transfer per window (three reads collapsed), data-dependent acceptance makes it unavoidable
-        drafts, n_acc, bonus = map(np.asarray, jax.device_get(
-            (pending.drafts, pending.n_acc, pending.bonus)))
-        t_end = time.perf_counter()
+        t0 = self._lap(_STAGE)
+        with obs.span("serve/commit_fetch"):
+            # graftlint: allow[R2] the speculative window's deferred commit fetch: one fused tuple transfer per window (three reads collapsed), data-dependent acceptance makes it unavoidable
+            drafts, n_acc, bonus = map(np.asarray, jax.device_get(
+                (pending.drafts, pending.n_acc, pending.bonus)))
+        t_end = self._lap(_FETCH)
         fetch_s = t_end - t0
         self.decode_time_s += pending.dispatch_s + fetch_s
         self.decode_steps += 1
@@ -2690,48 +2806,50 @@ class ServeEngine:
         if self.timeline:
             self._iter_decode_s += fetch_s
             self._iter_decode_slots = len(ds)
-        committed = []
-        for slot in ds:
-            req = slot.request
-            i = slot.index
-            acc = int(n_acc[i])
-            self.draft_proposed += k
-            self.draft_accepted += acc
-            req.spec_proposed += k
-            req.spec_accepted += acc
-            if self.timeline:
-                # committed-token count lands below, one bump per
-                # append (the finish emission inside _append must see
-                # the segment current); the window's attributed
-                # interval is [dispatch, fetch-end] — the concurrent
-                # host work is decode time, not overhead — clipped
-                # against the request's previous interval (a no-op in
-                # serial mode, where commit precedes the next
-                # dispatch)
-                start = pending.t_dispatch
-                if req.decode_attr_end is not None:
-                    start = max(start, req.decode_attr_end)
-                self._accrue_decode(req, start, t_end - start,
-                                    bucket, 0, k, acc)
-                req.decode_attr_end = t_end
-            window = [int(drafts[i, j]) for j in range(acc)]
-            window.append(int(bonus[i]))
-            j = 0
-            for tok in window:
-                j += 1
-                slot.context_len += 1    # this token's K/V is resident
-                self.decode_tokens += 1
+        with obs.span("serve/commit"):
+            committed = []
+            for slot in ds:
+                req = slot.request
+                i = slot.index
+                acc = int(n_acc[i])
+                self.draft_proposed += k
+                self.draft_accepted += acc
+                req.spec_proposed += k
+                req.spec_accepted += acc
                 if self.timeline:
-                    req.segments[-1]["tokens"] += 1
-                self._append(slot, tok)
-                if req.rid in self.finished:
-                    break                # EOS / budget: drop the rest
-            committed.append(j)
-            if req.rid not in self.finished:
-                # rejected-tail blocks (reserved for the verify window,
-                # now holding only stale K/V) go back to the free list
-                self.blocks.trim(slot.table, slot.context_len)
+                    # committed-token count lands below, one bump per
+                    # append (the finish emission inside _append must see
+                    # the segment current); the window's attributed
+                    # interval is [dispatch, fetch-end] — the concurrent
+                    # host work is decode time, not overhead — clipped
+                    # against the request's previous interval (a no-op in
+                    # serial mode, where commit precedes the next
+                    # dispatch)
+                    start = pending.t_dispatch
+                    if req.decode_attr_end is not None:
+                        start = max(start, req.decode_attr_end)
+                    self._accrue_decode(req, start, t_end - start,
+                                        bucket, 0, k, acc)
+                    req.decode_attr_end = t_end
+                window = [int(drafts[i, j]) for j in range(acc)]
+                window.append(int(bonus[i]))
+                j = 0
+                for tok in window:
+                    j += 1
+                    slot.context_len += 1    # this token's K/V is resident
+                    self.decode_tokens += 1
+                    if self.timeline:
+                        req.segments[-1]["tokens"] += 1
+                    self._append(slot, tok)
+                    if req.rid in self.finished:
+                        break                # EOS / budget: drop the rest
+                committed.append(j)
+                if req.rid not in self.finished:
+                    # rejected-tail blocks (reserved for the verify window,
+                    # now holding only stale K/V) go back to the free list
+                    self.blocks.trim(slot.table, slot.context_len)
         self.blocks.note_verify(committed, k + 1)
+        self._lap(_COMMIT)
 
     # -- lifecycle tracing (ISSUE 10) ----------------------------------------
     #
@@ -2963,8 +3081,9 @@ class ServeEngine:
         req.swap_context = slot.context_len
         self.swap_outs += 1
         self.swap_bytes_moved += actual
-        obs.serve("swap_out", request=req.rid, swap_bytes=actual,
-                  **self._replica_kw(), **self._trace_kw(req))
+        if obs.has_sink():
+            obs.serve("swap_out", request=req.rid, swap_bytes=actual,
+                      **self._replica_kw(), **self._trace_kw(req))
         return True
 
     def _apply_restores(self, slot) -> None:
@@ -3022,10 +3141,12 @@ class ServeEngine:
                 self.swap_ins += 1
                 self.swap_bytes_moved += bset.nbytes
                 self.recompute_tokens_avoided += slot.context_len
-                obs.serve("swap_in", request=req.rid,
-                          swap_bytes=bset.nbytes, restore_s=round(dt, 6),
-                          recompute_tokens_avoided=slot.context_len,
-                          **self._replica_kw(), **self._trace_kw(req))
+                if obs.has_sink():
+                    obs.serve("swap_in", request=req.rid,
+                              swap_bytes=bset.nbytes,
+                              restore_s=round(dt, 6),
+                              recompute_tokens_avoided=slot.context_len,
+                              **self._replica_kw(), **self._trace_kw(req))
         if slot.pending_restores:
             t0 = time.perf_counter()
             for b, payload in slot.pending_restores:
@@ -3047,10 +3168,11 @@ class ServeEngine:
         now = time.perf_counter()
         if req.first_token_t is None:
             req.first_token_t = now
-            obs.serve("first_token", request=req.rid,
-                      ttft_s=round(req.ttft_s, 6)
-                      if req.ttft_s is not None else None,
-                      **self._replica_kw(), **self._trace_kw(req))
+            if obs.has_sink():
+                obs.serve("first_token", request=req.rid,
+                          ttft_s=round(req.ttft_s, 6)
+                          if req.ttft_s is not None else None,
+                          **self._replica_kw(), **self._trace_kw(req))
         self.tokens_generated += 1
         if (token == self.eos_token_id
                 or self._generated(req) >= req.max_new_tokens):
@@ -3058,33 +3180,37 @@ class ServeEngine:
             self.sched.finish(slot)
             self.finished[req.rid] = req
             self._keys.pop(req.rid, None)
-            extra = {}
-            if self.speculative:
-                extra = {
-                    "speculate_k": self.speculate_k,
-                    "draft_proposed": req.spec_proposed,
-                    "draft_accepted": req.spec_accepted,
-                    "acceptance_rate": (
-                        round(req.spec_accepted / req.spec_proposed, 4)
-                        if req.spec_proposed else None),
-                }
-            if self.prefix_cache:
-                extra["prefix_cached_tokens"] = req.prefix_cached_tokens
-                extra["cache_hit_rate"] = (
-                    round(req.cache_hit_rate, 4)
-                    if req.cache_hit_rate is not None else None)
-            extra["kernel"] = self.kernel
-            extra["kv_dtype"] = self.kv_cache_dtype
-            extra["tp"] = self.tp
+            # the verdicts are written on the request, sink or no sink
+            verdicts = {}
             if req.has_slo:
-                extra.update(self._slo_verdict(req))
+                verdicts.update(self._slo_verdict(req))
             if req.deadline_s is not None:
-                extra.update(self._deadline_verdict(req))
-            obs.serve("finish", request=req.rid,
-                      tokens=self._generated(req),
-                      preemptions=req.preemptions,
-                      **self._replica_kw(), **self._trace_kw(req),
-                      **extra)
+                verdicts.update(self._deadline_verdict(req))
+            if obs.has_sink():
+                extra = {}
+                if self.speculative:
+                    extra = {
+                        "speculate_k": self.speculate_k,
+                        "draft_proposed": req.spec_proposed,
+                        "draft_accepted": req.spec_accepted,
+                        "acceptance_rate": (
+                            round(req.spec_accepted / req.spec_proposed, 4)
+                            if req.spec_proposed else None),
+                    }
+                if self.prefix_cache:
+                    extra["prefix_cached_tokens"] = \
+                        req.prefix_cached_tokens
+                    extra["cache_hit_rate"] = (
+                        round(req.cache_hit_rate, 4)
+                        if req.cache_hit_rate is not None else None)
+                extra["kernel"] = self.kernel
+                extra["kv_dtype"] = self.kv_cache_dtype
+                extra["tp"] = self.tp
+                obs.serve("finish", request=req.rid,
+                          tokens=self._generated(req),
+                          preemptions=req.preemptions,
+                          **self._replica_kw(), **self._trace_kw(req),
+                          **extra, **verdicts)
             self._emit_timeline(req, "finish")
 
     def _slo_verdict(self, req: Request) -> dict:

@@ -633,6 +633,11 @@ def main() -> None:
         "overlap": engine.overlap,
         "overlap_flushes": (stats.overlap_flushes
                             if engine.overlap else None),
+        # the loop's own account of its wall time (seconds): host work
+        # before/inside/after the dispatches, the wait for the device,
+        # and the caller's time between iterations
+        "host_loop": {k: round(v, 6) for k, v
+                      in engine.host_loop_totals().items()},
         "kernel": stats.kernel,
         "kv_dtype": stats.kv_dtype,
         "tp": stats.tp,

@@ -48,9 +48,11 @@ def test_span_nesting_and_ordering(obs_dir):
         time.sleep(0.01)
         with obs.span("inner"):
             time.sleep(0.01)
+    obs.flush()
     spans = {e["name"]: e for e in _events(obs_dir) if e["type"] == "span"}
     outer, inner = spans["outer"], spans["inner"]
     assert inner["depth"] == outer["depth"] + 1
+    assert inner["parent"] == "outer" and "parent" not in outer
     # containment: inner's [start, end] inside outer's
     assert inner["mono"] >= outer["mono"]
     assert inner["mono"] + inner["dur"] <= outer["mono"] + outer["dur"] + 1e-6
@@ -77,6 +79,7 @@ def test_jsonl_schema_round_trip(obs_dir):
     obs.scalar("train/null_ok", None)
     with obs.span("s", {"k": 1}):
         pass
+    obs.flush()
     count, errors = obs.validate_events_file(str(obs_dir / "events.jsonl"))
     assert errors == []
     assert count >= 3  # run + metric + metric + span
