@@ -204,6 +204,12 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               "gap_s": _NUM,
               "prefill_chunks": (int,),
               "prefill_dispatches": (int,),
+              # prefill's gather bucket (ISSUE 26): Σ over real rows of
+              # start + chunk against Σ over dispatches of rows × bucket
+              # width — the iteration's share on a ledger line, the
+              # run's sums on the report; needed <= attended always
+              "prefill_keys_needed": (int,),
+              "prefill_keys_attended": (int,),
               "decode_slots": (int,),
               "waiting": (int,),
               "kv_used_frac": _NUM,

@@ -15,19 +15,23 @@ speculation; vLLM + Orca + Sarathi + Leviathan lineage):
   ``models/generate.py`` drives), then scatter the newly-written K/V
   back into the pools. No model code changes: paging is an addressing
   layer around the existing cache contract.
-- **Width-bucketed gather** — the decode step is compiled at a small
-  ladder of context-width buckets (``HSTD_SERVE_GATHER_BUCKETS`` /
-  ``gather_buckets``; default quarter-width + full width) and each
-  iteration runs the smallest bucket covering the scheduler's
-  per-iteration max resident context
-  (``Scheduler.max_decode_context``). When most contexts are short the
-  step's KV read traffic (and the attention mask/logits width behind
-  it) shrinks from ``max_model_len`` to the bucket — the read-waste
-  elimination of PagedAttention's motivating analysis. Growth is
-  immediate (correctness), shrinking has hysteresis so bucket churn is
-  bounded; every switch is telemetered (``bucket_switch`` serve event
-  + ``serve/gather_bucket`` series), and each bucket compiles exactly
-  once (the bench asserts steady-state decode compiles ≤ #buckets).
+- **Width-bucketed gather** — the decode step AND the prefill
+  dispatch are compiled at one small ladder of context-width buckets
+  (``HSTD_SERVE_GATHER_BUCKETS`` / ``gather_buckets``; default
+  quarter-width + full width). A decode iteration runs the smallest
+  bucket covering the scheduler's per-iteration max resident context
+  (``Scheduler.max_decode_context``); a prefill dispatch runs the
+  smallest bucket covering ``max(start) + chunk`` over its rows
+  (ISSUE 26). When most contexts are short the step's KV read traffic
+  (and the attention mask/logits width behind it) shrinks from
+  ``max_model_len`` to the bucket — the read-waste elimination of
+  PagedAttention's motivating analysis. Decode growth is immediate
+  (correctness), shrinking has hysteresis so bucket churn is bounded;
+  every decode switch is telemetered (``bucket_switch`` serve event +
+  ``serve/gather_bucket`` series). Prefill keeps no state: each
+  dispatch picks its own bucket, and ``prefill_keys_needed`` /
+  ``prefill_keys_attended`` say how well the ladder fits the traffic.
+  Each (program, bucket) pair compiles exactly once, in ``warmup()``.
 - **Iteration-level scheduling** — a fixed set of ``num_slots`` decode
   slots (static shapes, so after one warmup compile of each step
   function NOTHING retraces); requests admit/evict between decode
@@ -338,7 +342,8 @@ def parse_swap_bytes(spec: Union[str, int, None]) -> Optional[int]:
 
 def parse_gather_buckets(spec: Union[str, Sequence[int], None],
                          max_model_len: int, block_size: int) -> list[int]:
-    """The decode gather-width ladder from a knob value.
+    """The gather-width ladder, shared by decode steps and prefill
+    dispatches, from a knob value.
 
     ``spec`` is the comma-separated ``HSTD_SERVE_GATHER_BUCKETS`` form
     (``"512,2048"``), a sequence of ints, or None/``"auto"`` for the
@@ -608,7 +613,7 @@ def _paged_decode_step(model, params, pools, tokens, block_tables,
 
 def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
                    temps, top_ks, top_ps, keys, folds, plan: CachePlan,
-                   sampled: bool):
+                   sampled: bool, width: Optional[int] = None):
     """One BATCHED prefill dispatch: up to G prefilling slots' chunks as
     G independent rows (static [G, C] shape; unused rows carry pad
     tokens against the null block table). Each row writes its chunk's
@@ -618,11 +623,19 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     rows return a discarded value). Isolation between the packed
     requests is structural: row g's attention reads exactly the KV its
     own block table gathers, so no mask can leak another request's
-    context into it."""
+    context into it.
+
+    ``width`` (a STATIC python int, multiple of the block size; None =
+    the table's full span) is the gather bucket: the chunk attends the
+    first ``width`` logical positions of each row and no more. Keys at
+    ``>= start + C`` are masked at any width, so a narrower bucket
+    drops only terms that are exactly zero. Callers guarantee
+    ``start + C <= width`` for every row; the write-back goes through
+    the full tables either way."""
     G, C = chunks.shape
     bs = pools[0].shape[1]
-    max_ctx = block_tables.shape[1] * bs
-    cache = _assemble_cache(plan, pools, block_tables, start)
+    max_ctx = block_tables.shape[1] * bs if width is None else width
+    cache = _assemble_cache(plan, pools, block_tables, start, width=width)
     # chunk slots are marked valid; the model's step mask
     # (key_slot <= cache_index + q_index) imposes causality within the
     # chunk, and pad-tail keys sit AFTER every real query so they are
@@ -674,7 +687,9 @@ def _decode_step_jit(donate: bool):
 
 @functools.lru_cache(maxsize=2)
 def _prefill_chunk_jit(donate: bool):
-    return jax.jit(_prefill_chunk, static_argnums=(0, 12, 13),
+    """Process-wide jitted prefill dispatch: one compile per (model,
+    plan, sampled, width) and row count."""
+    return jax.jit(_prefill_chunk, static_argnums=(0, 12, 13, 14),
                    donate_argnums=(2,) if donate else ())
 
 
@@ -902,6 +917,11 @@ class EngineStats(NamedTuple):
     decode_steps: int
     prefill_chunks: int
     prefill_dispatches: int
+    # Σ over real prefill rows of start + chunk, and Σ over dispatches
+    # of rows dispatched × bucket width (ISSUE 26): needed ÷ attended
+    # is how full the prefill buckets ran
+    prefill_keys_needed: int
+    prefill_keys_attended: int
     tokens_generated: int
     decode_tokens: int
     decode_time_s: float
@@ -966,9 +986,10 @@ class ServeEngine:
     size it for the expected CONCURRENT context, not
     ``num_slots × max_model_len``.
 
-    ``gather_buckets`` is the decode gather-width ladder (None reads
-    ``HSTD_SERVE_GATHER_BUCKETS``, default quarter + full width; pass
-    ``[max_model_len]`` or ``"full"`` to force full-width gather).
+    ``gather_buckets`` is the gather-width ladder of decode steps and
+    prefill dispatches alike (None reads ``HSTD_SERVE_GATHER_BUCKETS``,
+    default quarter + full width; pass ``[max_model_len]`` or
+    ``"full"`` to force full-width gather).
     ``prefill_batch`` caps how many prefilling slots' chunks one
     prefill dispatch packs (clamped to ``num_slots``).
 
@@ -1262,6 +1283,10 @@ class ServeEngine:
             self.gather_buckets = [b for b in self.gather_buckets
                                    if b >= self.speculate_k + 1]
         self.prefill_batch = max(1, min(int(prefill_batch), self.num_slots))
+        # the buckets a prefill dispatch can run at: a chunk must fit
+        # (max_model_len, a multiple of the chunk, always does)
+        self.prefill_buckets = [b for b in self.gather_buckets
+                                if b >= self.sched.prefill_chunk]
 
         # place every pool heads-sharded over the mesh: the committed
         # shardings ARE the jitted steps' pool in-shardings, and
@@ -1336,6 +1361,8 @@ class ServeEngine:
         self.decode_steps = 0
         self.prefill_chunks = 0
         self.prefill_dispatches = 0
+        self.prefill_keys_needed = 0
+        self.prefill_keys_attended = 0
         self.tokens_generated = 0
         self.decode_tokens = 0
         self.decode_time_s = 0.0
@@ -1649,9 +1676,12 @@ class ServeEngine:
         return self.speculate_k > 0
 
     def warmup(self, sampled: bool = False) -> None:
-        """Compile the prefill step and EVERY bucket's decode (or
-        speculative draft/verify) step on null work so the serving loop
-        itself never traces: the compile-tracker event count stays flat
+        """Compile every prefill program :meth:`_prefill_batch` can
+        dispatch (the batched ``[prefill_batch, C]`` shape at every
+        bucket a chunk fits, the lone-request ``[1, C]`` shape at the
+        first of them only) and EVERY bucket's decode (or speculative
+        draft/verify) step on null work so the serving loop itself
+        never traces: the compile-tracker event count stays flat
         across steady state (the bench asserts decode compiles ≤
         #buckets). With ``sampled=True`` the per-slot-sampling variants
         of every step are ALSO precompiled — without it they compile
@@ -1674,31 +1704,34 @@ class ServeEngine:
             sf = np.zeros((S,), np.float32)
             si = np.zeros((S,), np.int32)
             for mode in modes:
-                # both prefill dispatch shapes: the lone-request [1, C]
-                # variant and the batched [prefill_batch, C] one (the
-                # draft's prefill rides the target's greedy variant
-                # only — drafts never sample at prefill)
+                # the prefill programs _prefill_batch can dispatch: the
+                # batched [prefill_batch, C] shape at every bucket a
+                # chunk fits, the lone-request [1, C] shape at the
+                # first of them only (the draft's prefill rides the
+                # target's greedy variant only — drafts never sample
+                # at prefill)
                 for G in sorted({1, self.prefill_batch}):
-                    with obs.lifecycle_span(f"serve/warmup/prefill_g{G}"):
-                        zf = np.zeros((G,), np.float32)
-                        zi = np.zeros((G,), np.int32)
-                        tok, self._pools = self._prefill_fn(
-                            self.model, self.params, self._pools,
-                            np.zeros((G, C), np.int32),
+                    zf = np.zeros((G,), np.float32)
+                    zi = np.zeros((G,), np.int32)
+                    null = (np.zeros((G, C), np.int32),
                             np.zeros((G, nb), np.int32),
                             zi, np.full((G,), -1, np.int32), zf, zi, zf,
-                            np.zeros((G, 2), np.uint32), zi, self._plan,
-                            mode)
-                        if self.speculative and not mode:
-                            tok, self._d_pools = self._prefill_fn(
-                                self.draft_model, self.draft_params,
-                                self._d_pools,
-                                np.zeros((G, C), np.int32),
-                                np.zeros((G, nb), np.int32),
-                                zi, np.full((G,), -1, np.int32), zf, zi,
-                                zf, np.zeros((G, 2), np.uint32), zi,
-                                self._d_plan, False)
-                        jax.block_until_ready(tok)
+                            np.zeros((G, 2), np.uint32), zi)
+                    with obs.lifecycle_span(f"serve/warmup/prefill_g{G}"):
+                        for width in (self.prefill_buckets
+                                      if G == self.prefill_batch
+                                      else self.prefill_buckets[:1]):
+                            with obs.lifecycle_span(
+                                    f"serve/warmup/prefill_g{G}/w{width}"):
+                                tok, self._pools = self._prefill_fn(
+                                    self.model, self.params, self._pools,
+                                    *null, self._plan, mode, width)
+                                if self.speculative and not mode:
+                                    tok, self._d_pools = self._prefill_fn(
+                                        self.draft_model, self.draft_params,
+                                        self._d_pools, *null, self._d_plan,
+                                        False, width)
+                                jax.block_until_ready(tok)
                 for bucket in self.gather_buckets:
                     with obs.lifecycle_span(
                             f"serve/warmup/decode_b{bucket}"):
@@ -1827,6 +1860,9 @@ class ServeEngine:
             "peak_waiting_depth": self.peak_waiting,
             "bucket_switches": self.bucket_switches,
             "gather_bucket": self._bucket,
+            "prefill_dispatches": self.prefill_dispatches,
+            "prefill_keys_needed": self.prefill_keys_needed,
+            "prefill_keys_attended": self.prefill_keys_attended,
             "gather_read_waste_peak": round(
                 self.blocks.peak_gather_waste, 4),
             "gather_read_waste_mean": round(
@@ -1989,6 +2025,8 @@ class ServeEngine:
             decode_steps=self.decode_steps,
             prefill_chunks=self.prefill_chunks,
             prefill_dispatches=self.prefill_dispatches,
+            prefill_keys_needed=self.prefill_keys_needed,
+            prefill_keys_attended=self.prefill_keys_attended,
             tokens_generated=self.tokens_generated,
             decode_tokens=self.decode_tokens,
             decode_time_s=self.decode_time_s,
@@ -2097,6 +2135,8 @@ class ServeEngine:
         parts[:] = (0.0, 0.0, 0.0, 0.0)
         tokens0 = self.tokens_generated
         chunks0, disp0 = self.prefill_chunks, self.prefill_dispatches
+        needed0 = self.prefill_keys_needed
+        attended0 = self.prefill_keys_attended
         self._iter_prefill_s = 0.0
         self._iter_decode_s = 0.0
         self._iter_decode_slots = 0
@@ -2245,6 +2285,9 @@ class ServeEngine:
                 gather_bucket=self._bucket,
                 prefill_chunks=self.prefill_chunks - chunks0,
                 prefill_dispatches=self.prefill_dispatches - disp0,
+                prefill_keys_needed=self.prefill_keys_needed - needed0,
+                prefill_keys_attended=(self.prefill_keys_attended
+                                       - attended0),
                 decode_slots=self._iter_decode_slots,
                 tokens=self.tokens_generated - tokens0,
                 waiting=waiting,
@@ -2349,12 +2392,20 @@ class ServeEngine:
     def _prefill_batch(self, max_rows: int) -> int:
         """One batched prefill dispatch over up to
         ``min(max_rows, prefill_batch)`` prefilling slots (static
-        [G, C] shape — unused rows ride to the null block). A LONE
-        prefilling request runs the [1, C] variant instead: padding it
+        [G, C] shape — unused rows ride to the null block), at the
+        smallest gather bucket that holds ``max(start) + C`` over its
+        rows: the chunk attends the keys its context needs, not
+        ``max_model_len`` (no hysteresis, no state: each dispatch picks
+        its own bucket). A LONE prefilling request whose context fits
+        the first bucket runs the [1, C] variant instead: padding it
         to the full batch would multiply low-load prefill compute (and
-        TTFT) by ``prefill_batch``. Two compiled shapes total, both
-        warmed. Returns the DISPATCHED row count G — pad rows included,
-        so the caller's token budget charges what the device actually
+        TTFT) by ``prefill_batch``. Past the first bucket a lone row
+        rides the batched shape at its bucket (the [1, C] shape exists
+        at the first bucket only: at full width it lost to the batched
+        one on the v5e, 498 ms against 300 ms for four rows, PERF.md
+        §6). So ``len(ladder) + 1`` compiled shapes, all warmed.
+        Returns the DISPATCHED row count G — pad rows included, so the
+        caller's token budget charges what the device actually
         computed, keeping the decode-stall bound honest at partial
         load (0 = no prefill work)."""
         slots = self.sched.next_prefill_slots(
@@ -2362,8 +2413,11 @@ class ServeEngine:
         if not slots:
             return 0
         with obs.span("serve/stage_prefill"):
-            G = 1 if len(slots) == 1 else self.prefill_batch
             C = self.sched.prefill_chunk
+            need = max(slot.prefill_pos for slot in slots) + C
+            width = next(b for b in self.prefill_buckets if b >= need)
+            G = (1 if len(slots) == 1 and width == self.prefill_buckets[0]
+                 else self.prefill_batch)
             chunks = np.full((G, C), self.pad_token_id, np.int32)
             tables = np.zeros((G, self.max_blocks_per_seq), np.int32)
             start = np.zeros((G,), np.int32)
@@ -2394,11 +2448,12 @@ class ServeEngine:
                         folds[i] = self._generated(req)
         t0 = self._lap(_STAGE)
         with obs.span("serve/prefill_chunk",
-                      {"chunks": len(slots)} if obs.has_sink() else None):
+                      {"chunks": len(slots), "rows": G, "width": width}
+                      if obs.has_sink() else None):
             tok, self._pools = self._prefill_fn(
                 self.model, self.params, self._pools, chunks, tables,
                 start, rel, temps, top_ks, top_ps, keys, folds,
-                self._plan, sampled)
+                self._plan, sampled, width)
             if self.speculative:
                 # the draft's pools must hold the prompt KV too — same
                 # chunks/tables, its own address space; the returned
@@ -2406,7 +2461,7 @@ class ServeEngine:
                 _, self._d_pools = self._prefill_fn(
                     self.draft_model, self.draft_params, self._d_pools,
                     chunks, tables, start, rel, temps, top_ks, top_ps,
-                    keys, folds, self._d_plan, False)
+                    keys, folds, self._d_plan, False, width)
         dur = self._lap(_DISPATCH) - t0
         if self.timeline:
             # dispatch-enqueue wall time (an async backend's device
@@ -2417,8 +2472,10 @@ class ServeEngine:
                 self._accrue_prefill(slot, t0, dur)
         for slot in slots:
             slot.prefill_pos += C
+            self.prefill_keys_needed += slot.prefill_pos
         self.prefill_chunks += len(slots)
         self.prefill_dispatches += 1
+        self.prefill_keys_attended += G * width
         if finals:
             self._lap(_COMMIT)
             # fetch the continuation tokens; also the sync point that

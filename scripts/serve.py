@@ -8,7 +8,7 @@ carrying per-request ``temperature``/``top_k``/``top_p``/``seed``) or a
 synthetic mixed-length trace (default — the zero-egress smoke path).
 ``--temperature/--top_k/--top_p/--sample_seed`` set the default
 sampling configuration (greedy when temperature is 0);
-``--gather_buckets`` overrides the decode gather-width ladder
+``--gather_buckets`` overrides the gather-width ladder (decode and prefill)
 (``HSTD_SERVE_GATHER_BUCKETS``; ``full`` disables bucketing);
 ``--prefix_cache on|off`` (``HSTD_SERVE_PREFIX_CACHE``, default on)
 controls copy-on-write prompt-prefix KV sharing — per-request output
@@ -204,7 +204,7 @@ def main() -> None:
     parser.add_argument("--max_model_len", type=int, default=0,
                         help="0 = model max_position_embeddings")
     parser.add_argument("--gather_buckets", default=None,
-                        help="decode gather-width ladder, e.g. "
+                        help="gather-width ladder (decode and prefill), e.g. "
                              "'64,256' ('full' disables bucketing; "
                              "default: HSTD_SERVE_GATHER_BUCKETS or "
                              "quarter+full width)")
