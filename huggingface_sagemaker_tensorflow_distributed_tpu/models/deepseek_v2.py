@@ -1,0 +1,548 @@
+"""DeepSeek-V2: multi-head LATENT attention (MLA) + shared and routed
+SwiGLU experts with a group-limited gate.
+
+What differs from the Llama layout (``models/llama.py``, whose
+``LlamaRMSNorm`` / ``LlamaMlp`` / ``rope_tables`` this family reuses):
+
+- **Latent attention.** Keys and values of all heads are functions of ONE
+  compressed row per token: ``c = RMSNorm(x W_kva[:, :r])`` (``r`` =
+  ``kv_lora_rank``) and one rotary key ``k_pe = rope(x W_kva[:, r:])``
+  shared by every head. The decode cache holds exactly that row, ``c |
+  k_pe`` (``r + qk_rope_head_dim`` values a token a layer, no heads axis),
+  as the cache variable ``cached_latent`` ``[B, 1, max_len, width]``,
+  ``width`` the row rounded up to the TPU's 128 lanes with zeros
+  (:func:`latent_width`: 640 for the published 576. The compiler stores a
+  576-wide minor dim as 640 lanes anyway, or else lays the pool out with
+  its BLOCK axis minor and re-lays the whole pool out twice a layer a
+  step: ten 400 MB copies in a decode step's program, rehearsal compile
+  for the v5e, PR 28).
+  Two forms of the same function attend it, chosen by the SHAPE of the
+  call (:func:`latent_path`) and by nothing a user sets:
+
+  * *absorbed* (one query a row, the decode step): ``W_kvb``'s key half
+    is folded into the query (``q_nope W_uk^T``) and its value half is
+    applied after the weighted sum, so attention runs in the latent
+    space and the cache is never expanded;
+  * *expanded* (a chunk of queries, prefill and the plain forward): the
+    latent rows are expanded to per-head ``k_nope | v`` through
+    ``W_kvb``, a block of keys at a time under a running softmax, so that
+    neither the expanded keys nor the scores of a long bucket ever exist
+    whole, and the blocks of a bucket past the context are skipped.
+
+- **RoPE** rotates ``q_pe`` and ``k_pe`` only. The published model
+  rotates adjacent pairs ``(2i, 2i+1)``; this code does what HF's port
+  does: it permutes the rotary dims to the half-split order and applies
+  the rotate-half form (``models/llama.py::apply_rope``). The same
+  rotation, and the permutation is the same on ``q_pe`` and ``k_pe``, so
+  every score is the published one. (The benchmark's reference rotates
+  adjacent pairs.) Frequencies are YaRN's (``_scaled_inv_freq``), and
+  the softmax scale carries ``mscale_all_dim``'s temperature squared.
+- **FFN.** The first ``first_k_dense_replace`` layers are a SwiGLU; the
+  others add ``n_shared_experts`` fused shared experts to routed experts
+  under ``models/moe.py::group_limited_gate`` (float32 softmax over all
+  experts, best ``topk_group`` of ``n_group`` groups, top
+  ``num_experts_per_tok``, weights times ``routed_scaling_factor``, no
+  renormalisation) and ``dropless_experts`` (nothing dropped; a token's
+  routing depends on that token alone).
+- **The share held here.** ``experts_held`` / ``expert_rank`` say which
+  routed experts this program holds (experts ``rank * held ..``): the
+  router keeps its published width, the layer computes ``Shared(h)`` plus
+  the held experts' part, and that partial result goes on. With all
+  experts held it is the uncut model. On one chip the layer runs without
+  its exchange; nothing stands in for the absent chips.
+
+Out of scope: the training-only auxiliary losses (``seq_aux``), and
+loading a published checkpoint (``models/convert.py`` has no mapping for
+this family; ROADMAP).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from huggingface_sagemaker_tensorflow_distributed_tpu.models.layers import ACT2FN
+from huggingface_sagemaker_tensorflow_distributed_tpu.models.llama import (
+    LlamaMlp,
+    LlamaRMSNorm,
+    _dense,
+    apply_rope,
+    rope_tables,
+    yarn_mscale,
+)
+from huggingface_sagemaker_tensorflow_distributed_tpu.models.moe import (
+    dropless_experts,
+    group_limited_gate,
+)
+
+NEG_INF = -1e9
+# keys a chunk of queries attends at a time (attend_expanded)
+KEY_BLOCK = 512
+# the collection the routed layers sow their per-expert pair counts into
+MOE_STATS = "moe_stats"
+
+
+def latent_width(cfg) -> int:
+    """Values of a cached latent row as stored: ``kv_lora_rank +
+    qk_rope_head_dim`` rounded up to a multiple of 128 lanes."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def latent_path(q_len: int) -> str:
+    """``absorbed`` | ``expanded``: the form a call with ``q_len`` queries
+    a row attends by. A chunk attends expanded: measured on the v5e at
+    the 8,192 bucket, that form was the faster on every dispatch
+    (PERF.md 6, PR 28)."""
+    return "absorbed" if q_len == 1 else "expanded"
+
+
+@dataclass(frozen=True)
+class DeepseekV2Config:
+    vocab_size: int = 102400
+    hidden_size: int = 5120
+    num_layers: int = 60                   # num_hidden_layers
+    num_heads: int = 128                   # num_attention_heads
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 12288         # the dense layers' SwiGLU
+    moe_intermediate_size: int = 1536      # one expert's
+    n_routed_experts: int = 160            # the router's width
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    n_group: int = 8
+    topk_group: int = 3
+    routed_scaling_factor: float = 16.0
+    first_k_dense_replace: int = 1
+    # the share of the routed experts held here: experts
+    # expert_rank * experts_held .. + experts_held - 1 (None: all)
+    experts_held: Optional[int] = None
+    expert_rank: int = 0
+    max_position_embeddings: int = 163840
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[tuple] = None   # sorted items of the HF dict
+    rms_norm_eps: float = 1e-6
+    hidden_act: str = "silu"
+    initializer_range: float = 0.02
+    tie_word_embeddings: bool = False
+    bos_token_id: int = 100000
+    eos_token_id: int = 100001
+    pad_token_id: int = 100001
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    rms_unit_offset: bool = False          # read by LlamaRMSNorm
+    model_type: str = "deepseek_v2"
+
+    def __post_init__(self):
+        held = self.held
+        if self.tie_word_embeddings:
+            raise ValueError("deepseek_v2 has an untied head; "
+                             "tie_word_embeddings is not implemented")
+        if self.n_routed_experts % self.n_group:
+            raise ValueError(
+                f"n_routed_experts {self.n_routed_experts} is not a "
+                f"multiple of n_group {self.n_group}")
+        if held < 1 or self.n_routed_experts % held:
+            raise ValueError(
+                f"experts_held {held} must divide n_routed_experts "
+                f"{self.n_routed_experts}")
+        if not 0 <= self.expert_rank < self.n_routed_experts // held:
+            raise ValueError(
+                f"expert_rank {self.expert_rank} outside the "
+                f"{self.n_routed_experts // held} shares of {held} experts")
+
+    @property
+    def held(self) -> int:
+        return (self.n_routed_experts if self.experts_held is None
+                else self.experts_held)
+
+    @property
+    def rope_scaling_dict(self) -> Optional[dict]:
+        return dict(self.rope_scaling) if self.rope_scaling else None
+
+    @property
+    def num_moe_layers(self) -> int:
+        return max(self.num_layers - self.first_k_dense_replace, 0)
+
+    @property
+    def softmax_scale(self) -> float:
+        """``(nope + rope)^-0.5 * m^2``, ``m`` YaRN's temperature over
+        all dims (``mscale_all_dim``): 0.11472 as published."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        s = self.rope_scaling_dict
+        if s:
+            m = yarn_mscale(s["factor"], s.get("mscale_all_dim", 0))
+            scale *= m * m
+        return scale
+
+    @property
+    def rope_factor(self) -> float:
+        """What cos and sin are multiplied by: ``mscale(factor, mscale) /
+        mscale(factor, mscale_all_dim)``, 1 as published."""
+        s = self.rope_scaling_dict
+        if not s:
+            return 1.0
+        return (yarn_mscale(s["factor"], s.get("mscale", 1))
+                / yarn_mscale(s["factor"], s.get("mscale_all_dim", 0)))
+
+
+def deepseek_v2_config_from_hf(hf_config: dict, **overrides) -> DeepseekV2Config:
+    """The program's configuration from an HF ``config.json`` mapping.
+    ``experts_held`` / ``expert_rank`` (not HF keys) may ride in the
+    mapping or in ``overrides``. Raises on what the modules do not
+    compute rather than load and diverge."""
+    scaling = hf_config.get("rope_scaling")
+    rope_scaling = None
+    if scaling:
+        rope_type = scaling.get("rope_type", scaling.get("type"))
+        if rope_type != "yarn":
+            raise ValueError(
+                f"rope_scaling type {rope_type!r} is not implemented for "
+                f"deepseek_v2 (yarn only): {scaling!r}")
+        missing = [k for k in ("factor", "original_max_position_embeddings")
+                   if k not in scaling]
+        if missing:
+            raise ValueError(f"yarn rope_scaling is missing {missing}: "
+                             f"{scaling!r}")
+        rope_scaling = tuple(sorted(scaling.items()))
+    unsupported = {
+        "scoring_func": ("softmax", hf_config.get("scoring_func", "softmax")),
+        "topk_method": ("group_limited_greedy",
+                        hf_config.get("topk_method", "group_limited_greedy")),
+        "norm_topk_prob": (False, hf_config.get("norm_topk_prob", False)),
+        "moe_layer_freq": (1, hf_config.get("moe_layer_freq", 1)),
+        "attention_bias": (False, hf_config.get("attention_bias", False)),
+    }
+    for key, (want, got) in unsupported.items():
+        if got != want:
+            raise ValueError(
+                f"deepseek_v2 {key}={got!r} is not implemented (only "
+                f"{want!r}): sigmoid and bias-corrected gates, "
+                "renormalised weights and sparse expert placement are "
+                "other models' (ROADMAP)")
+    if hf_config.get("q_lora_rank") is None:
+        raise ValueError("deepseek_v2 without q_lora_rank (the Lite "
+                         "layout: an uncompressed query) is not implemented")
+    kw = dict(
+        vocab_size=hf_config["vocab_size"],
+        hidden_size=hf_config["hidden_size"],
+        num_layers=hf_config["num_hidden_layers"],
+        num_heads=hf_config["num_attention_heads"],
+        q_lora_rank=hf_config["q_lora_rank"],
+        kv_lora_rank=hf_config["kv_lora_rank"],
+        qk_nope_head_dim=hf_config["qk_nope_head_dim"],
+        qk_rope_head_dim=hf_config["qk_rope_head_dim"],
+        v_head_dim=hf_config["v_head_dim"],
+        intermediate_size=hf_config["intermediate_size"],
+        moe_intermediate_size=hf_config["moe_intermediate_size"],
+        n_routed_experts=hf_config["n_routed_experts"],
+        n_shared_experts=hf_config.get("n_shared_experts", 0),
+        num_experts_per_tok=hf_config["num_experts_per_tok"],
+        n_group=hf_config["n_group"],
+        topk_group=hf_config["topk_group"],
+        routed_scaling_factor=float(hf_config.get("routed_scaling_factor",
+                                                  1.0)),
+        first_k_dense_replace=hf_config.get("first_k_dense_replace", 0),
+        experts_held=hf_config.get("experts_held"),
+        expert_rank=hf_config.get("expert_rank", 0),
+        max_position_embeddings=hf_config.get("max_position_embeddings",
+                                              2048),
+        rope_theta=float(hf_config.get("rope_theta", 10000.0)),
+        rope_scaling=rope_scaling,
+        rms_norm_eps=hf_config.get("rms_norm_eps", 1e-6),
+        hidden_act=hf_config.get("hidden_act", "silu"),
+        initializer_range=hf_config.get("initializer_range", 0.02),
+        tie_word_embeddings=hf_config.get("tie_word_embeddings", False),
+        bos_token_id=hf_config.get("bos_token_id", 100000),
+        eos_token_id=hf_config.get("eos_token_id", 100001),
+        pad_token_id=(hf_config["pad_token_id"]
+                      if hf_config.get("pad_token_id") is not None
+                      else hf_config.get("eos_token_id", 100001)),
+    )
+    kw.update(overrides)
+    kw.pop("use_pooler", None)             # encoder-family knob
+    return DeepseekV2Config(**kw)
+
+
+# -- the two forms of latent attention ---------------------------------------
+
+
+def attend_expanded(q_nope, q_pe, latent, bias, w_kvb, *, rank: int,
+                    scale: float, key_block: int = KEY_BLOCK):
+    """Latent attention, expanded form. ``q_nope`` [B, S, H, nope],
+    ``q_pe`` [B, S, H, rope] (rotated), ``latent`` [B, W, >= rank + rope]
+    (``c | k_pe | zeros``), ``bias`` [B, S, W] float32 additive mask,
+    ``w_kvb`` [rank, H, nope + v]. Per block of keys: ``k_nope | v = c
+    W_kvb``, ``score = (q_nope k_nope + q_pe k_pe) * scale``. Returns
+    [B, S, H, v].
+
+    The keys go ``key_block`` at a time under a running softmax (the
+    flash recurrence: running maximum ``m``, running sum ``l``, rescaled
+    accumulator), so that neither the scores of a long bucket nor the
+    keys and values expanded from its rows ever exist whole: [4 rows,
+    128 heads, 512 queries, 512 keys] of float32 scores is 0.54 GB where
+    8,192 keys at once would be 8.6 GB. (Narrow score rows are also what
+    the v5e's compiler reduces well: one softmax over
+    ``f32[4,16,512,8192]`` took 100 ms, forty of them 4.15 s a dispatch,
+    my chip run, PR 28.) A block no query may see (every key past the
+    longest row's context, in a bucket wider than the context) is
+    skipped: its terms are exactly zero."""
+    B, W = latent.shape[:2]
+    S, H = q_nope.shape[1:3]
+    nope, rot = q_nope.shape[-1], q_pe.shape[-1]
+    kb = key_block if W > key_block and W % key_block == 0 else W
+
+    def step(i, carry):
+        rows = lax.dynamic_slice_in_dim(latent, i * kb, kb, axis=1)
+        bias_k = lax.dynamic_slice_in_dim(bias, i * kb, kb, axis=2)
+
+        def attend(carry):
+            m, l, acc = carry
+            kv = jnp.einsum("bwr,rhd->bwhd", rows[..., :rank], w_kvb)
+            scores = (jnp.einsum("bshd,bwhd->bhsw", q_nope, kv[..., :nope],
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bshd,bwd->bhsw", q_pe,
+                                   rows[..., rank:rank + rot],
+                                   preferred_element_type=jnp.float32))
+            scores = scores * scale + bias_k[:, None]
+            m_new = jnp.maximum(m, scores.max(axis=-1))
+            p = jnp.exp(scores - m_new[..., None])
+            keep = jnp.exp(m - m_new)
+            l = l * keep + p.sum(axis=-1)
+            acc = (acc * keep.transpose(0, 2, 1)[..., None]
+                   + jnp.einsum("bhsw,bwhd->bshd", p.astype(latent.dtype),
+                                kv[..., nope:]).astype(jnp.float32))
+            return m_new, l, acc
+
+        return lax.cond(jnp.any(bias_k > NEG_INF / 2), attend,
+                        lambda c: c, carry)
+
+    m, l, acc = lax.fori_loop(0, W // kb, step, (
+        jnp.full((B, H, S), -1e30, jnp.float32),
+        jnp.zeros((B, H, S), jnp.float32),
+        jnp.zeros((B, S, H, w_kvb.shape[-1] - nope), jnp.float32)))
+    return (acc / l.transpose(0, 2, 1)[..., None]).astype(latent.dtype)
+
+
+def attend_absorbed(q_nope, q_pe, latent, bias, w_kvb, *, rank: int,
+                    scale: float):
+    """Latent attention, absorbed form, for ONE query a row (the decode
+    step): the same function as :func:`attend_expanded` with ``W_kvb``'s
+    key half folded into the query and its value half applied after the
+    weighted sum, so the scores and the sum run against the latent rows
+    themselves: ``score = (q_nope W_uk^T | q_pe) . (c | k_pe) * scale``,
+    ``out = (softmax(score) c) W_uv``. (For a chunk of queries it
+    measured slower than the expanded form on every dispatch: PERF.md
+    6, PR 28.)"""
+    assert q_nope.shape[1] == 1, "absorbed attention takes one query a row"
+    nope = q_nope.shape[-1]
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :nope])
+    q_cat = jnp.concatenate([q_lat.astype(latent.dtype), q_pe], axis=-1)
+    q_cat = jnp.pad(q_cat, [(0, 0)] * 3 + [
+        (0, latent.shape[-1] - q_cat.shape[-1])])       # the row's zeros
+    scores = jnp.einsum("bshc,bwc->bhsw", q_cat, latent,
+                        preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(scores + bias[:, None], axis=-1).astype(latent.dtype)
+    o_lat = jnp.einsum("bhsw,bwr->bshr", p, latent[..., :rank])
+    return jnp.einsum("bshr,rhd->bshd", o_lat.astype(latent.dtype),
+                      w_kvb[..., nope:])
+
+
+def _to_half_split(x):
+    """Rotary dims from the published adjacent-pair order to the
+    half-split order ``apply_rope`` rotates (HF's port does the same)."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+class DeepseekV2Attention(nn.Module):
+    config: DeepseekV2Config
+
+    @nn.compact
+    def __call__(self, hidden, key_valid=None, rope=None,
+                 decode: bool = False):
+        cfg = self.config
+        B, S, _ = hidden.shape
+        H, rank = cfg.num_heads, cfg.kv_lora_rank
+        nope, rot, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim)
+        cq = LlamaRMSNorm(cfg, name="q_a_ln")(
+            _dense(cfg, cfg.q_lora_rank, "q_a_proj")(hidden))
+        q = _dense(cfg, H * (nope + rot), "q_b_proj")(cq)
+        q = q.reshape(B, S, H, nope + rot)
+        q_nope, q_pe = q[..., :nope], q[..., nope:]
+        ckv = _dense(cfg, rank + rot, "kv_a_proj")(hidden)
+        c = LlamaRMSNorm(cfg, name="kv_a_ln")(ckv[..., :rank])
+        # apply_rope takes [B, heads, S, D]; the one k_pe is one "head"
+        q_pe = apply_rope(_to_half_split(q_pe).transpose(0, 2, 1, 3),
+                          rope).transpose(0, 2, 1, 3)
+        k_pe = apply_rope(_to_half_split(ckv[..., rank:])[:, None],
+                          rope)[:, 0]
+        width = latent_width(cfg)
+        latent = jnp.concatenate(
+            [c, k_pe.astype(c.dtype),
+             jnp.zeros((B, S, width - rank - rot), c.dtype)], axis=-1)
+        w_kvb = self.param(
+            "kv_b_proj", nn.initializers.normal(cfg.initializer_range),
+            (rank, H, nope + vd), cfg.param_dtype).astype(cfg.dtype)
+
+        q_slot = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        if decode:
+            is_init = self.has_variable("cache", "cached_latent")
+            cached = self.variable("cache", "cached_latent", jnp.zeros,
+                                   (B, 1, S, width), latent.dtype)
+            cache_index = self.variable(
+                "cache", "cache_index", lambda: jnp.zeros((B,), jnp.int32))
+            if is_init:
+                # the write protocol of models/llama.py::write_kv_cache:
+                # each row's new latent rows at its own write index
+                cur = cache_index.value
+                buf = jax.vmap(
+                    lambda b, new, i: lax.dynamic_update_slice(
+                        b, new, (0, i, 0)))(cached.value, latent[:, None], cur)
+                cached.value = buf
+                cache_index.value = cur + S
+                latent = buf[:, 0]                          # [B, W, width]
+                q_slot = cur[:, None] + q_slot
+        W = latent.shape[1]
+        seen = jnp.arange(W)[None, None, :] <= q_slot[:, :, None]
+        if key_valid is not None:
+            seen = seen & key_valid[:, None, :]
+        bias = jnp.where(seen, 0.0, NEG_INF).astype(jnp.float32)
+        attend = (attend_absorbed if latent_path(S) == "absorbed"
+                  else attend_expanded)
+        ctx = attend(q_nope, q_pe, latent, bias, w_kvb, rank=rank,
+                     scale=cfg.softmax_scale)
+        return _dense(cfg, cfg.hidden_size, "o_proj")(
+            ctx.reshape(B, S, H * vd))
+
+
+class DeepseekV2MoE(nn.Module):
+    """``Shared(h) + sum over the held experts a token chose``; sows the
+    held experts' pair counts into :data:`MOE_STATS`."""
+
+    config: DeepseekV2Config
+
+    @nn.compact
+    def __call__(self, hidden, token_mask=None):
+        cfg = self.config
+        B, S, Hd = hidden.shape
+        E, F = cfg.held, cfg.moe_intermediate_size
+        init = nn.initializers.normal(cfg.initializer_range)
+        router = self.param("router", init, (Hd, cfg.n_routed_experts),
+                            cfg.param_dtype)
+        w_gate = self.param("experts_gate_proj", init, (E, Hd, F),
+                            cfg.param_dtype)
+        w_up = self.param("experts_up_proj", init, (E, Hd, F),
+                          cfg.param_dtype)
+        w_down = self.param("experts_down_proj", init, (E, F, Hd),
+                            cfg.param_dtype)
+        x = hidden.reshape(B * S, Hd)
+        # the gate in float32, as published: on a TPU a float32 matmul
+        # runs in bf16 passes unless told otherwise, and a near-tie
+        # between the sixth and seventh expert would then flip
+        logits = jnp.einsum("th,he->te", x.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        ids, weights = group_limited_gate(
+            jax.nn.softmax(logits, axis=-1), cfg.n_group, cfg.topk_group,
+            cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+        y, counts = dropless_experts(
+            x, ids, weights, w_gate.astype(cfg.dtype),
+            w_up.astype(cfg.dtype), w_down.astype(cfg.dtype),
+            cfg.expert_rank * E, ACT2FN[cfg.hidden_act],
+            token_mask=token_mask)
+        self.sow(MOE_STATS, "expert_counts", counts)
+        self.sow(MOE_STATS, "expert_ids", ids.reshape(B, S, -1))
+        y = y.reshape(B, S, Hd)
+        if cfg.n_shared_experts:
+            shared = dataclasses.replace(
+                cfg, intermediate_size=cfg.n_shared_experts * F)
+            y = y + LlamaMlp(shared, name="shared_experts")(hidden)
+        return y
+
+
+class DeepseekV2Block(nn.Module):
+    config: DeepseekV2Config
+    layer_index: int = 0
+
+    @nn.compact
+    def __call__(self, hidden, key_valid=None, rope=None,
+                 decode: bool = False, token_mask=None):
+        cfg = self.config
+        hidden = hidden + DeepseekV2Attention(cfg, name="self_attn")(
+            LlamaRMSNorm(cfg, name="input_ln")(hidden), key_valid, rope,
+            decode)
+        normed = LlamaRMSNorm(cfg, name="post_attn_ln")(hidden)
+        if self.layer_index < cfg.first_k_dense_replace:
+            return hidden + LlamaMlp(cfg, name="mlp")(normed)
+        return hidden + DeepseekV2MoE(cfg, name="moe")(normed, token_mask)
+
+
+class DeepseekV2Model(nn.Module):
+    config: DeepseekV2Config
+
+    @nn.compact
+    def __call__(self, input_ids, attention_mask=None, position_ids=None,
+                 decode: bool = False, token_mask=None):
+        cfg = self.config
+        B, S = input_ids.shape
+        if position_ids is None:
+            offset = 0
+            if decode:
+                is_init = self.has_variable("cache", "position_index")
+                idx = self.variable("cache", "position_index",
+                                    lambda: jnp.array(0, jnp.int32))
+                if is_init:
+                    offset = idx.value
+                    idx.value = offset + S
+            position_ids = jnp.broadcast_to(
+                offset + jnp.arange(S)[None, :], (B, S))
+        cos, sin = rope_tables(position_ids, cfg.qk_rope_head_dim,
+                               cfg.rope_theta, cfg.rope_scaling_dict)
+        if cfg.rope_factor != 1.0:
+            cos, sin = cos * cfg.rope_factor, sin * cfg.rope_factor
+        key_valid = None if attention_mask is None else attention_mask > 0
+        x = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size,
+            embedding_init=nn.initializers.normal(cfg.initializer_range),
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name="embed_tokens")(input_ids)
+        for i in range(cfg.num_layers):
+            x = DeepseekV2Block(cfg, layer_index=i, name=f"layers_{i}")(
+                x, key_valid, (cos, sin), decode, token_mask)
+        return LlamaRMSNorm(cfg, name="final_ln")(x)
+
+
+class DeepseekV2ForCausalLM(nn.Module):
+    """Same call signature as ``LlamaForCausalLM`` (so ``generate_causal``
+    and the serving engine drive it unchanged), plus ``token_mask``
+    [B, S]: which tokens are real, for the routed layers' counts only
+    (no output depends on it)."""
+
+    config: DeepseekV2Config
+
+    latent_path = staticmethod(latent_path)
+
+    def setup(self):
+        cfg = self.config
+        self.backbone = DeepseekV2Model(cfg)
+        self.lm_head = nn.Dense(
+            cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            kernel_init=nn.initializers.normal(cfg.initializer_range),
+            name="lm_head")
+
+    def __call__(self, input_ids, attention_mask=None, token_type_ids=None,
+                 position_ids=None, deterministic: bool = True,
+                 decode: bool = False, token_mask=None):
+        hidden = self.backbone(input_ids, attention_mask, position_ids,
+                               decode, token_mask)
+        return self.lm_head(hidden).astype(jnp.float32)

@@ -30,6 +30,7 @@ Architecture (HF parity):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -306,7 +307,31 @@ class LlamaRMSNorm(nn.Module):
         return (x32.astype(cfg.dtype) * scale.astype(cfg.dtype))
 
 
-def _scaled_inv_freq(inv_freq, scaling: Optional[dict]):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``
+    (1 without a factor above 1 or with ``mscale`` 0)."""
+    if factor <= 1.0 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(scaling: dict, dim: int, theta: float):
+    """``(low, high)``: the rotary pair indices between which YaRN
+    blends from kept to interpolated frequencies. ``d(n)`` is the index
+    whose wavelength makes ``n`` turns over the original context."""
+    old_len = scaling["original_max_position_embeddings"]
+
+    def d(turns):
+        return (dim * math.log(old_len / (turns * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    low = max(math.floor(d(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(d(scaling.get("beta_slow", 1))), dim - 1)
+    return low, high
+
+
+def _scaled_inv_freq(inv_freq, scaling: Optional[dict],
+                     theta: Optional[float] = None):
     """Apply HF rope_scaling to the base inverse frequencies.
 
     - "linear": inv_freq / factor (position interpolation);
@@ -315,8 +340,15 @@ def _scaled_inv_freq(inv_freq, scaling: Optional[dict]):
       ``factor``, short ones kept, the band between ``low_freq_factor``
       and ``high_freq_factor`` smoothly blended.
 
-    Both types have attention_factor 1.0 in HF, so cos/sin need no
-    post-scaling. Unsupported types are rejected at config build.
+    - "yarn" (needs ``theta``): pairs below ``low`` keep their frequency,
+      pairs above ``high`` are interpolated by ``factor``, a linear ramp
+      between (:func:`yarn_correction_range`). Its attention temperature
+      is the caller's (``yarn_mscale``): the DeepSeek-V2 family puts it
+      into the softmax scale, and its cos/sin factor is
+      ``mscale / mscale_all_dim``.
+
+    "linear" and "llama3" have attention_factor 1.0 in HF, so cos/sin
+    need no post-scaling. Unsupported types are rejected at config build.
     """
     if not scaling:
         return inv_freq
@@ -324,6 +356,12 @@ def _scaled_inv_freq(inv_freq, scaling: Optional[dict]):
     factor = scaling["factor"]
     if rope_type == "linear":
         return inv_freq / factor
+    if rope_type == "yarn":
+        half = inv_freq.shape[0]
+        low, high = yarn_correction_range(scaling, 2 * half, theta)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
     low_f = scaling["low_freq_factor"]
     high_f = scaling["high_freq_factor"]
     old_len = scaling["original_max_position_embeddings"]
@@ -344,7 +382,7 @@ def rope_tables(position_ids, head_dim: int, theta: float,
     rope_scaling mapping (``LlamaConfig.rope_scaling_dict``)."""
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                            dtype=jnp.float32) / head_dim))
-    inv_freq = _scaled_inv_freq(inv_freq, scaling)
+    inv_freq = _scaled_inv_freq(inv_freq, scaling, theta)
     angles = position_ids.astype(jnp.float32)[:, :, None] * inv_freq
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[:, None]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[:, None]

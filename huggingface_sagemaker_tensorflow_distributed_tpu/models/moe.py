@@ -5,8 +5,12 @@ parallelism, SURVEY.md §2 parallelism inventory): a GShard/Switch-style
 token-routed MoE FFN designed TPU-first —
 
 - **Dense dispatch/combine einsums**, no scatter/gather: routing is
-  expressed as one-hot dispatch tensors contracted on the MXU, the only
-  MoE formulation that maps onto XLA's static-shape compilation model.
+  expressed as one-hot dispatch tensors contracted on the MXU, with a
+  static per-expert capacity. It is the formulation the TRAINING families
+  use (encoder MoE, Mixtral); it is not the only one with static shapes:
+  :func:`dropless_experts` below sorts the (token, expert) pairs by
+  expert and runs grouped matmuls over them, drops nothing, and is what
+  the served DeepSeek-V2 family routes with.
 - **Expert parallelism via sharding annotations**: expert weights carry
   ``PartitionSpec("expert", ...)`` (``parallel/sharding.py``) and the
   dispatched activations are constrained expert-major, so XLA inserts
@@ -22,6 +26,14 @@ The router computes in fp32 (softmax over expert logits is precision
 TPU). The Switch load-balance auxiliary loss is sowed into the
 ``losses`` collection; the Trainer adds every sowed value to the task
 loss (``train/trainer.py``).
+
+**Dropless routed experts** (:func:`group_limited_gate`,
+:func:`dropless_experts`): a token's routing depends on that token alone
+(no capacity, no slot competition), so chunked prefill, one-shot prefill
+and decode route alike, which is what lets the serving engine take the
+layer. The layer is told which experts it holds (one chip's share of an
+expert-parallel deployment): it routes over ALL experts and computes the
+part of the result its own experts give.
 """
 
 from __future__ import annotations
@@ -31,6 +43,7 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from huggingface_sagemaker_tensorflow_distributed_tpu.models.layers import ACT2FN, EncoderConfig
 
@@ -263,3 +276,65 @@ class MixtralMoeBlock(nn.Module):
         out = _constrain(out, AXIS_EXPERT, non_expert_axes)
 
         return jnp.einsum("bsec,ebch->bsh", combine.astype(cfg.dtype), out)
+
+
+def group_limited_gate(probs, n_group: int, topk_group: int, top_k: int,
+                       scale: float):
+    """DeepSeek-V2's ``group_limited_greedy`` gate over float32 ``probs``
+    [T, E] (the softmax over ALL experts): the experts are ``n_group``
+    groups of ``E / n_group``, a group scores by its best expert, the
+    best ``topk_group`` groups are kept, the others' probabilities are
+    zeroed, and the ``top_k`` largest of what is left are the token's
+    experts (ties go to the lower index, ``lax.top_k``). Returns
+    ``(ids [T, top_k] int32, weights [T, top_k] float32)`` with
+    ``weights = scale * probs[ids]`` and no renormalisation
+    (``norm_topk_prob`` false, ``routed_scaling_factor``)."""
+    T, E = probs.shape
+    groups = probs.reshape(T, n_group, E // n_group)
+    _, kept = lax.top_k(groups.max(axis=-1), topk_group)       # [T, g]
+    keep = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.int32),
+                   axis=1) > 0                                 # [T, n_group]
+    masked = jnp.where(keep[:, :, None], groups, 0.0).reshape(T, E)
+    weights, ids = lax.top_k(masked, top_k)
+    return ids.astype(jnp.int32), weights * scale
+
+
+def dropless_experts(x, ids, weights, w_gate, w_up, w_down,
+                     first_expert: int, act, token_mask=None):
+    """The held experts' part of a routed SwiGLU layer, nothing dropped.
+
+    ``x`` [T, H]; ``ids``/``weights`` [T, k] from the gate, ids over ALL
+    experts; ``w_gate``/``w_up`` [E, H, F] and ``w_down`` [E, F, H] are
+    the ``E`` experts held here, experts ``first_expert ..
+    first_expert + E - 1`` of the model. The ``T * k`` (token, expert)
+    pairs are sorted by expert, pairs of experts that live elsewhere to a
+    tail that no group covers (static shapes: ``T * k`` rows whatever the
+    routing), and three grouped matmuls (``lax.ragged_dot``, group =
+    expert) run over the sorted rows. Returns ``(y [T, H], counts [E]
+    int32)``: ``y = sum_{k: id held} weight * Expert_id(x)``, and
+    ``counts`` the pairs each held expert got from the tokens that
+    ``token_mask`` [T] marks real (all of them without a mask; the pad
+    rows of a dispatch are computed like any other and counted out)."""
+    T, k = ids.shape
+    E = w_gate.shape[0]
+    local = ids - first_expert
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E).reshape(-1)                # [T*k]
+    order = jnp.argsort(key, stable=True)
+    onehot = key[:, None] == jnp.arange(E, dtype=key.dtype)[None, :]
+    sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)           # [E]
+    xs = x[order // k]                                         # [T*k, H]
+    h = act(lax.ragged_dot(xs, w_gate, sizes)) * lax.ragged_dot(
+        xs, w_up, sizes)
+    ys = lax.ragged_dot(h, w_down, sizes)                      # [T*k, H]
+    # back to (token, choice) order; a row of the tail belongs to an
+    # expert held elsewhere and whatever it holds is left out
+    inverse = jnp.argsort(order)
+    ys = ys[inverse].reshape(T, k, -1)
+    w = jnp.where(held, weights, 0.0)
+    y = jnp.sum(jnp.where(held[:, :, None], ys.astype(jnp.float32), 0.0)
+                * w[:, :, None], axis=1).astype(x.dtype)
+    if token_mask is None:
+        return y, sizes
+    real = jnp.repeat(token_mask.reshape(-1), k)[:, None]
+    return y, jnp.sum(onehot & real, axis=0, dtype=jnp.int32)

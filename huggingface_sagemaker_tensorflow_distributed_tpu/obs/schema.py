@@ -198,6 +198,22 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               # dur_s >= their sum on every ledger line, and the
               # caller's time since the previous iteration returned
               "stage_s": _NUM,
+              # routed experts and the latent cache (ISSUE 28), on the
+              # ledger lines and the report of a model that has them:
+              # (token, expert) pairs of the real tokens of the
+              # dispatches whose counts a fetch has passed, those that
+              # landed on the experts held here (<= moe_pairs on every
+              # line), and per expert layer of the decode step among
+              # them: held experts that got a pair, the busiest and the
+              # mean held expert's pairs
+              "moe_pairs": (int,),
+              "moe_pairs_held": (int,),
+              "moe_decode_pairs": (int,),
+              "moe_decode_pairs_held": (int,),
+              "moe_experts_touched": (list,),
+              "moe_expert_load_max": (list,),
+              "moe_expert_load_mean": (list,),
+              "latent_bytes_per_token": (int,),
               "dispatch_s": _NUM,
               "fetch_wait_s": _NUM,
               "commit_s": _NUM,

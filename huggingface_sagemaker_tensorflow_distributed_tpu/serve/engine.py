@@ -383,7 +383,16 @@ class CachePlan(NamedTuple):
     what it is — ``("kv", pool_index)`` for cached_key/cached_value
     (and, under ``kv_cache_dtype='int8'``, the ``cached_*_scale``
     fp32 scale planes, which ride parallel scale POOLS through the
-    same gather/scatter/COW machinery), ``("index",)`` for the per-row
+    same gather/scatter/COW machinery), ``("latent", pool_index)`` for
+    a latent-attention layer's ``cached_latent`` (ONE pool a layer, of
+    ``(1, rank + rope)`` rows: the compressed row every head's keys and
+    values are functions of; no K/V pair, and NO heads axis: the pool is
+    ``[num_blocks, block_size, rank + rope]``, so that its two minor
+    dims are a block's rows and not a degenerate ``1 x width`` plane the
+    compiler has to re-lay out at every step (found in a rehearsal
+    compile for the v5e, PR 28: two whole-pool copies a layer a step);
+    it rides the same gather/scatter/COW/swap machinery), ``("index",)``
+    for the per-row
     write indices, ``("scalar",)`` for model-level counters (unused
     under explicit position_ids). ``paths`` holds each leaf's key path
     so the PAGED cache (kernel mode) can be built as a nested dict with
@@ -407,6 +416,37 @@ class CachePlan(NamedTuple):
     kinds: tuple
     paths: tuple
     kv_shardings: tuple = ()
+
+
+# the kinds of cache leaf that live in a block pool
+_POOLED = ("kv", "latent")
+
+
+def _moe_counts(mut):
+    """The routed layers' per-expert pair counts ``[expert layers, experts
+    held]`` out of a step's mutated collections, in layer order; ``()``
+    for a model that routes nothing (its step then returns exactly what
+    it always did)."""
+    stats = mut.get("moe_stats")
+    if not stats:
+        return ()
+    flat = [(path, leaf) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(stats)[0]
+            if any(getattr(p, "key", None) == "expert_counts" for p in path)]
+
+    def layer(path):
+        name = next(str(getattr(p, "key", p)) for p in path
+                    if str(getattr(p, "key", p)).startswith("layers_"))
+        return int(name.rsplit("_", 1)[1])
+
+    return (jnp.stack([leaf for _, leaf in
+                       sorted(flat, key=lambda pl: layer(pl[0]))]),)
+
+
+def _routes(model) -> bool:
+    """True for a model with DROPLESS routed experts (it takes a
+    ``token_mask`` and sows ``moe_stats``); static under jit."""
+    return bool(getattr(model.config, "n_routed_experts", 0))
 
 
 def _constrain_pools(pools, plan: CachePlan):
@@ -468,6 +508,13 @@ def build_cache_plan(model, params, max_ctx: int,
                     "(e.g. T5 encoder-decoder) are not serveable here")
             kinds.append(("kv", len(pool_shapes)))
             pool_shapes.append((h, d, leaf.dtype))
+        elif name == "cached_latent":
+            b, h, s, d = leaf.shape            # h == 1: no heads axis
+            if s != max_ctx:
+                raise ValueError(
+                    f"cache leaf {name} has width {s}, expected {max_ctx}")
+            kinds.append(("latent", len(pool_shapes)))
+            pool_shapes.append((h, d, leaf.dtype))
         elif name == "cache_index":
             kinds.append(("index",))
         elif name == "position_index":
@@ -475,14 +522,25 @@ def build_cache_plan(model, params, max_ctx: int,
         else:
             raise ValueError(
                 f"unsupported cache leaf {name!r}: the serve engine "
-                "speaks the cached_key/cached_value (+ int8 scale) "
-                "protocol only")
+                "speaks the cached_key/cached_value (+ int8 scale) and "
+                "cached_latent protocols only")
         paths.append(names)
     kv_shardings: tuple = ()
     if mesh is not None and mesh.shape.get("tensor", 1) > 1:
         from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.sharding import (
             kv_pool_sharding,
         )
+
+        if any(k[0] == "latent" for k in kinds):
+            # a latent row has no heads axis to shard: every device
+            # would need all of it (a replicated pool, and attention
+            # sharded over the QUERY heads against it). Not wired:
+            # refuse rather than shard the one "head" into nothing
+            raise ValueError(
+                "a latent-attention cache (cached_latent) cannot be "
+                "served under a tensor-parallel mesh: its rows have no "
+                "heads axis to shard, and a replicated pool is not "
+                "wired (ROADMAP)")
 
         kv_shardings = tuple(kv_pool_sharding(mesh, h)
                              for h, _d, _dt in pool_shapes)
@@ -492,6 +550,29 @@ def build_cache_plan(model, params, max_ctx: int,
     return result
 
 
+def pool_dims(plan: CachePlan, pool_shapes, num_blocks: int,
+              block_size: int) -> list:
+    """The shape of every block pool of ``plan``: ``[num_blocks,
+    block_size, heads, head_dim]`` for a K/V (or scale) pool,
+    ``[num_blocks, block_size, width]`` for a latent pool."""
+    latent = {k[1] for k in plan.kinds if k[0] == "latent"}
+    return [(num_blocks, block_size, d) if i in latent
+            else (num_blocks, block_size, h, d)
+            for i, (h, d, _dt) in enumerate(pool_shapes)]
+
+
+def _pool_view(pool):
+    """A pool as ``[num_blocks, block_size, heads, dim]``: a latent pool
+    gains a heads axis of one (a reshape, no copy)."""
+    return pool[:, :, None, :] if pool.ndim == 3 else pool
+
+
+def _pool_rows(pool, rows):
+    """``rows`` ``[n, heads, dim]`` as the pool stores them: a latent
+    pool's rows have no heads axis."""
+    return rows[:, 0, :] if pool.ndim == 3 else rows
+
+
 def _assemble_cache(plan: CachePlan, pools, block_tables, context_lens,
                     width: Optional[int] = None):
     """The model-facing cache pytree: contiguous per-slot KV gathered
@@ -499,9 +580,9 @@ def _assemble_cache(plan: CachePlan, pools, block_tables, context_lens,
     given), write indices set to each slot's context length."""
     leaves = []
     for kind in plan.kinds:
-        if kind[0] == "kv":
-            leaves.append(gather_paged_kv(pools[kind[1]], block_tables,
-                                          width=width))
+        if kind[0] in _POOLED:
+            leaves.append(gather_paged_kv(_pool_view(pools[kind[1]]),
+                                          block_tables, width=width))
         elif kind[0] == "index":
             leaves.append(context_lens.astype(jnp.int32))
         else:
@@ -519,17 +600,22 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
     token per slot — greedy argmax, or the per-slot seeded sample for
     rows with ``temperature > 0`` when the (static) ``sampled`` mode is
     on. Callers guarantee ``context_len + 1 <= width`` for every active
-    slot."""
+    slot. Returns ``(next_tok, pools)``; a model with routed experts adds
+    a third, ``[expert layers, experts held]`` int32: the pairs each held
+    expert got from the ACTIVE slots."""
     cache = _assemble_cache(plan, pools, block_tables, context_lens,
                             width=width)
     # kv-buffer validity includes the slot being written this step —
     # exactly generate_causal's decode-step mask, at bucket width
     valid = (jnp.arange(width)[None, :]
              <= context_lens[:, None]).astype(jnp.int32)
+    routes = _routes(model)
     logits, mut = model.apply(
         {"params": params, "cache": cache}, tokens[:, None], valid,
         position_ids=context_lens[:, None], decode=True,
-        deterministic=True, mutable=["cache"])
+        deterministic=True,
+        mutable=["cache", "moe_stats"] if routes else ["cache"],
+        **({"token_mask": active[:, None]} if routes else {}))
     last = logits[:, -1, :].astype(jnp.float32)
     if sampled:
         next_tok = sample_per_slot(last, temps, top_ks, top_ps, keys, folds)
@@ -542,13 +628,15 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
     mut_leaves = jax.tree_util.tree_leaves(mut["cache"])
     new_pools = list(pools)
     for leaf, kind in zip(mut_leaves, plan.kinds):
-        if kind[0] != "kv":
+        if kind[0] not in _POOLED:
             continue
         written = jnp.take_along_axis(
             leaf, pos[:, None, None, None], axis=2)[:, :, 0, :]  # [S, H, D]
         new_pools[kind[1]] = scatter_paged_kv(
-            new_pools[kind[1]], safe_tables, pos, written)
-    return next_tok, _constrain_pools(new_pools, plan)
+            new_pools[kind[1]], safe_tables, pos,
+            _pool_rows(new_pools[kind[1]], written))
+    return (next_tok, _constrain_pools(new_pools, plan),
+            *_moe_counts(mut))
 
 
 def _paged_cache(plan: CachePlan, pools, block_tables, context_lens):
@@ -631,7 +719,9 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     ``>= start + C`` are masked at any width, so a narrower bucket
     drops only terms that are exactly zero. Callers guarantee
     ``start + C <= width`` for every row; the write-back goes through
-    the full tables either way."""
+    the full tables either way. Returns ``(next_tok, pools)``, and for a
+    model with routed experts a third, the REAL tokens' pair counts per
+    held expert (as :func:`_decode_step`)."""
     G, C = chunks.shape
     bs = pools[0].shape[1]
     max_ctx = block_tables.shape[1] * bs if width is None else width
@@ -644,10 +734,18 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     valid = (jnp.arange(max_ctx)[None, :]
              < start[:, None] + C).astype(jnp.int32)
     pos_ids = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    routes = _routes(model)
+    extra = {}
+    if routes:
+        # the real tokens of the dispatch, for the routed layers' counts:
+        # a pad row rides the null block table, a final chunk is real up
+        # to ``rel``, any other chunk is real whole
+        n_real = jnp.where(rel >= 0, rel + 1, C) * (block_tables[:, 0] != 0)
+        extra["token_mask"] = jnp.arange(C)[None, :] < n_real[:, None]
     logits, mut = model.apply(
         {"params": params, "cache": cache}, chunks, valid,
         position_ids=pos_ids, decode=True, deterministic=True,
-        mutable=["cache"])
+        mutable=["cache", "moe_stats"] if routes else ["cache"], **extra)
     sel = jnp.take_along_axis(
         logits.astype(jnp.float32),
         jnp.clip(rel, 0, C - 1)[:, None, None], axis=1)[:, 0]  # [G, V]
@@ -661,7 +759,7 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     mut_leaves = jax.tree_util.tree_leaves(mut["cache"])
     new_pools = list(pools)
     for leaf, kind in zip(mut_leaves, plan.kinds):
-        if kind[0] != "kv":
+        if kind[0] not in _POOLED:
             continue
         h, d = leaf.shape[1], leaf.shape[3]
         written = jax.vmap(
@@ -669,8 +767,10 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
         )(leaf, start)                                      # [G, H, C, D]
         written = written.transpose(0, 2, 1, 3).reshape(G * C, h, d)
         new_pools[kind[1]] = scatter_paged_kv(
-            new_pools[kind[1]], tables_tok, positions, written)
-    return next_tok, _constrain_pools(new_pools, plan)
+            new_pools[kind[1]], tables_tok, positions,
+            _pool_rows(new_pools[kind[1]], written))
+    return (next_tok, _constrain_pools(new_pools, plan),
+            *_moe_counts(mut))
 
 
 @functools.lru_cache(maxsize=2)
@@ -736,13 +836,18 @@ class _PendingDecode(NamedTuple):
     may be reassigned by the time a wasted token is discarded), the
     bucket it ran at, and the dispatch-enqueue cost/stamp. The fetch is
     deferred to the NEXT engine iteration: everything the host does in
-    between runs concurrently with this dispatch's device compute."""
+    between runs concurrently with this dispatch's device compute.
+    ``moe_seq``: how many routed-count entries the run had made once
+    this dispatch had added its own (``_moe_mark``: an absolute count,
+    which entries resolved meanwhile do not shift): its fetch shows them
+    all computed."""
 
     nxt: Any
     riders: tuple
     bucket: int
     dispatch_s: float
     t_dispatch: float
+    moe_seq: int = 0
 
 
 class _PendingSpec(NamedTuple):
@@ -778,7 +883,7 @@ def _scatter_window(pools, plan: CachePlan, cache_leaves, block_tables,
     tables_tok = jnp.repeat(safe_tables, k + 1, axis=0)   # [S*(k+1), nb]
     new_pools = list(pools)
     for leaf, kind in zip(cache_leaves, plan.kinds):
-        if kind[0] != "kv":
+        if kind[0] not in _POOLED:
             continue
         h, d = leaf.shape[1], leaf.shape[3]
         written = jax.vmap(
@@ -975,6 +1080,13 @@ class EngineStats(NamedTuple):
     migrations_in: int = 0
     migrations_out: int = 0
     migration_bytes: int = 0
+    # latent-attention cache and routed experts (ISSUE 28): bytes a
+    # token's latent rows cost over all layers (None: a K/V cache), and
+    # the routed pairs counted under a telemetry sink (0 without one:
+    # an untraced run fetches no count)
+    latent_bytes_per_token: Optional[int] = None
+    moe_pairs: int = 0
+    moe_pairs_held: int = 0
 
 
 class ServeEngine:
@@ -1147,10 +1259,13 @@ class ServeEngine:
         cfg = model.config
         if getattr(cfg, "num_experts", 0):
             raise ValueError(
-                "ServeEngine does not support MoE models: expert "
-                "capacity depends on the apply's sequence length, so "
-                "chunked prefill could drop token->expert assignments "
-                "the one-shot path never drops")
+                "ServeEngine does not support capacity-slot MoE models "
+                "(models/moe.py::topk_dispatch): expert capacity depends "
+                "on the apply's sequence length, so chunked prefill could "
+                "drop token->expert assignments the one-shot path never "
+                "drops. Dropless routed experts (models/moe.py::"
+                "dropless_experts), whose routing depends on the token "
+                "alone, are served")
         if getattr(cfg, "pipeline_stages", 0):
             raise ValueError("ServeEngine needs the dense stack "
                              "(pipeline_stages=0)")
@@ -1236,6 +1351,24 @@ class ServeEngine:
                                              self.max_model_len,
                                              mesh=self.mesh)
         self._plan = plan
+        # a latent-attention model (one `cached_latent` pool a layer):
+        # which form each dispatch attends by is the model's own rule on
+        # the dispatch's shape, named in the step spans' arguments
+        self._latent = any(k[0] == "latent" for k in plan.kinds)
+        self._routes = _routes(model)
+        # (token, expert) pairs one real token makes over the model
+        self._moe_fanout = (int(cfg.num_experts_per_tok)
+                            * int(cfg.num_moe_layers)) if self._routes else 0
+        if self._latent or self._routes:
+            if self.kernel == "pallas":
+                raise ValueError(
+                    "kernel='pallas' (the fused paged K/V kernel) has no "
+                    "latent-attention form: serve this model with the "
+                    "xla gather path")
+            if self.speculate_k:
+                raise ValueError(
+                    "speculative decoding is not wired for latent-"
+                    "attention or routed-expert models")
         # bytes one resident token costs across every pool (int8 KV +
         # its fp32 scale plane included) — the figure that sizes a
         # byte-budgeted pool and denominates kv_bytes_read telemetry.
@@ -1372,6 +1505,16 @@ class ServeEngine:
         self.draft_proposed = 0
         self.draft_accepted = 0
         self.kv_bytes_read = 0      # pool bytes decode dispatches read
+        # routed experts (a model with dropless experts, under a
+        # telemetry sink only: the counts are device arrays that ride out
+        # of the steps and are fetched once a later fetch has shown them
+        # computed): (token, expert) pairs of the real tokens dispatched,
+        # and those that landed on the experts held here
+        self.moe_pairs = 0
+        self.moe_pairs_held = 0
+        self._moe_flight: list = []   # (pairs, device counts, is decode)
+        self._moe_resolved = 0        # entries taken off its head so far
+        self._moe_landed = 0          # entries of the run a fetch has passed
         self.spec_windows = 0       # active (slot, iteration) pairs
         self.peak_resident = 0      # max concurrently-occupied slots
         # open-loop SLO accounting (ISSUE 16): attainment counters over
@@ -1491,14 +1634,13 @@ class ServeEngine:
         ``jnp.zeros`` would transiently allocate the WHOLE pool on the
         default device first, OOMing init in precisely the
         bigger-than-a-chip regime TP serves."""
+        dims = pool_dims(plan, pool_shapes, num_blocks, block_size)
         if not plan.kv_shardings:
-            return [jnp.zeros((num_blocks, block_size, h, d), dtype)
-                    for h, d, dtype in pool_shapes]
-        return [jax.device_put(
-                    np.zeros((num_blocks, block_size, h, d),
-                             np.dtype(dtype)), s)
-                for (h, d, dtype), s in zip(pool_shapes,
-                                            plan.kv_shardings)]
+            return [jnp.zeros(shape, dtype)
+                    for shape, (_h, _d, dtype) in zip(dims, pool_shapes)]
+        return [jax.device_put(np.zeros(shape, np.dtype(dtype)), s)
+                for shape, (_h, _d, dtype), s in zip(
+                    dims, pool_shapes, plan.kv_shardings)]
 
     # -- public API ----------------------------------------------------------
 
@@ -1723,11 +1865,11 @@ class ServeEngine:
                                       else self.prefill_buckets[:1]):
                             with obs.lifecycle_span(
                                     f"serve/warmup/prefill_g{G}/w{width}"):
-                                tok, self._pools = self._prefill_fn(
+                                tok, self._pools, *_ = self._prefill_fn(
                                     self.model, self.params, self._pools,
                                     *null, self._plan, mode, width)
                                 if self.speculative and not mode:
-                                    tok, self._d_pools = self._prefill_fn(
+                                    tok, self._d_pools, *_ = self._prefill_fn(
                                         self.draft_model, self.draft_params,
                                         self._d_pools, *null, self._d_plan,
                                         False, width)
@@ -1755,7 +1897,7 @@ class ServeEngine:
                                     np.zeros((S, 2), np.uint32), si,
                                     self._plan, bucket, mode)
 
-                            tok, self._pools = decode(si)
+                            tok, self._pools, *_ = decode(si)
                             if self.overlap:
                                 # the dispatch-ahead loop feeds the
                                 # previous step's device-resident tokens
@@ -1765,7 +1907,7 @@ class ServeEngine:
                                 # a second compile (found on four chips:
                                 # two 5 s compiles mid-serve); on one
                                 # device it is a cache hit
-                                tok, self._pools = decode(tok)
+                                tok, self._pools, *_ = decode(tok)
                         jax.block_until_ready(tok)
             if (self.overlap and not self.speculative
                     and not self._warmed_modes):
@@ -1876,6 +2018,13 @@ class ServeEngine:
                 self.decode_tokens / self.decode_time_s, 1)
         out["kernel"] = self.kernel
         out["kv_dtype"] = self.kv_cache_dtype
+        # latent cache / routed experts: absent for any other model
+        if self._latent:
+            out["latent_bytes_per_token"] = self.blocks.token_bytes
+        if self._routes:
+            self._moe_resolve(everything=True)
+            out["moe_pairs"] = self.moe_pairs
+            out["moe_pairs_held"] = self.moe_pairs_held
         # multi-replica serving (ISSUE 14): a router-owned replica's
         # report names itself so the merged cross-host report (and
         # `obsctl slo`'s per-replica grouping) can attribute it; absent
@@ -2021,7 +2170,12 @@ class ServeEngine:
         return out
 
     def stats(self) -> EngineStats:
+        self._moe_resolve(everything=True)
         return EngineStats(
+            latent_bytes_per_token=(self.blocks.token_bytes
+                                    if self._latent else None),
+            moe_pairs=self.moe_pairs,
+            moe_pairs_held=self.moe_pairs_held,
             decode_steps=self.decode_steps,
             prefill_chunks=self.prefill_chunks,
             prefill_dispatches=self.prefill_dispatches,
@@ -2266,6 +2420,7 @@ class ServeEngine:
         totals[_COMMIT] += parts[_COMMIT]
         totals[_DUR] += dur_s
         totals[_GAP] += gap_s
+        moe_kw = self._moe_resolve() if sink else {}
         if sink and self.timeline:
             # the engine ledger: one line per iteration with the phase
             # mix (prefill vs decode dispatch seconds inside the
@@ -2293,7 +2448,7 @@ class ServeEngine:
                 waiting=waiting,
                 preemptions=self.sched.n_preemptions,
                 kv_used_frac=round(self.blocks.utilization(), 4),
-                **arrival_kw, **self._replica_kw())
+                **moe_kw, **arrival_kw, **self._replica_kw())
         elif sink:
             # timeline off: the per-iteration gauges as series, which
             # `obsctl tail` falls back to (with it on, the ledger line
@@ -2307,6 +2462,63 @@ class ServeEngine:
                        self.iterations)
         self.iterations += 1
 
+
+    def _latent_kw(self, q_len: int) -> dict:
+        """``{"latent_path": "absorbed" | "expanded"}`` for a dispatch
+        of ``q_len`` queries a row of a latent-attention model (the
+        model's own rule on the shape), ``{}`` for any other model."""
+        if not self._latent:
+            return {}
+        return {"latent_path": self.model.latent_path(q_len)}
+
+    def _moe_dispatched(self, moe: list, tokens: int, decode: bool) -> None:
+        """Keep a dispatch's routed counts (a device array, NOT fetched
+        here) with the pairs its ``tokens`` real tokens made, when there
+        is a sink to report them to; an untraced run drops them."""
+        if moe and obs.has_sink():
+            self._moe_flight.append(
+                (tokens * self._moe_fanout, moe[0], decode))
+
+    def _moe_mark(self) -> int:
+        """How many routed-count entries the run has made so far: the
+        device runs its dispatches in order, so a fetch of the newest
+        one's result shows all of them computed."""
+        return self._moe_resolved + len(self._moe_flight)
+
+    def _moe_resolve(self, everything: bool = False) -> dict:
+        """Fetch the routed counts of the dispatches a later fetch has
+        shown computed (all that are left with ``everything``: the run
+        is over), add them to the running sums and return the ledger's
+        fields: ``moe_pairs`` / ``moe_pairs_held`` of those dispatches,
+        the decode steps' part of both (``moe_decode_pairs`` /
+        ``moe_decode_pairs_held``) and, per expert layer, of the last
+        decode step among them, the held experts that got a pair and the
+        busiest and the mean held expert's pairs. ``{}`` for a model
+        that routes nothing."""
+        if not self._routes:
+            return {}
+        n = (len(self._moe_flight) if everything
+             else self._moe_landed - self._moe_resolved)
+        landed, self._moe_flight = self._moe_flight[:n], self._moe_flight[n:]
+        self._moe_resolved += n
+        self._moe_landed = max(self._moe_landed, self._moe_resolved)
+        out = {"moe_pairs": 0, "moe_pairs_held": 0,
+               "moe_decode_pairs": 0, "moe_decode_pairs_held": 0}
+        for pairs, counts, decode in landed:
+            counts = np.asarray(counts)          # computed: no wait
+            held = int(counts.sum())
+            out["moe_pairs"] += pairs
+            out["moe_pairs_held"] += held
+            if decode:
+                out["moe_decode_pairs"] += pairs
+                out["moe_decode_pairs_held"] += held
+                out["moe_experts_touched"] = (counts > 0).sum(1).tolist()
+                out["moe_expert_load_max"] = counts.max(1).tolist()
+                out["moe_expert_load_mean"] = [
+                    round(float(m), 4) for m in counts.mean(1)]
+        self.moe_pairs += out["moe_pairs"]
+        self.moe_pairs_held += out["moe_pairs_held"]
+        return out
 
     def _capacity_phase(self) -> None:
         """Decode-side block capacity for the next dispatch, preempting
@@ -2448,17 +2660,21 @@ class ServeEngine:
                         folds[i] = self._generated(req)
         t0 = self._lap(_STAGE)
         with obs.span("serve/prefill_chunk",
-                      {"chunks": len(slots), "rows": G, "width": width}
+                      {"chunks": len(slots), "rows": G, "width": width,
+                       **self._latent_kw(C)}
                       if obs.has_sink() else None):
-            tok, self._pools = self._prefill_fn(
+            tok, self._pools, *moe = self._prefill_fn(
                 self.model, self.params, self._pools, chunks, tables,
                 start, rel, temps, top_ks, top_ps, keys, folds,
                 self._plan, sampled, width)
+            self._moe_dispatched(moe, sum(
+                min(C, len(s.request.prompt) - s.prefill_pos)
+                for s in slots), False)
             if self.speculative:
                 # the draft's pools must hold the prompt KV too — same
                 # chunks/tables, its own address space; the returned
                 # token is discarded (the draft never emits)
-                _, self._d_pools = self._prefill_fn(
+                _, self._d_pools, *_ = self._prefill_fn(
                     self.draft_model, self.draft_params, self._d_pools,
                     chunks, tables, start, rel, temps, top_ks, top_ps,
                     keys, folds, self._d_plan, False, width)
@@ -2483,6 +2699,7 @@ class ServeEngine:
             with obs.span("serve/first_token_fetch"):
                 # graftlint: allow[R2] first-token fetch at prompt completion: the value gates the slot's prefill->decode flip and is the sync that keeps TTFT an honest wall time
                 tok_host = np.asarray(jax.device_get(tok))
+            self._moe_landed = self._moe_mark()
             self._lap(_FETCH)
             with obs.span("serve/commit"):
                 for i, slot in finals:
@@ -2558,16 +2775,19 @@ class ServeEngine:
                     for s in ds))
         t0 = self._lap(_STAGE)
         with obs.span("serve/decode_step",
-                      {"active": len(ds), "gather_bucket": bucket}
+                      {"active": len(ds), "gather_bucket": bucket,
+                       **self._latent_kw(1)}
                       if obs.has_sink() else None):
-            nxt, self._pools = self._decode_fn(
+            nxt, self._pools, *moe = self._decode_fn(
                 self.model, self.params, self._pools, tokens, tables,
                 ctx, active, temps, top_ks, top_ps, keys, folds,
                 self._plan, bucket, sampled)
+            self._moe_dispatched(moe, len(ds), True)
             self._lap(_DISPATCH)
             with obs.span("serve/commit_fetch"):
                 # graftlint: allow[R2] the SERIAL loop's per-step fetch: this is the overlap=off reference implementation the dispatch-ahead gates compare against, serial by definition
                 nxt = np.asarray(jax.device_get(nxt))
+            self._moe_landed = self._moe_mark()
         dur = self._lap(_FETCH) - t0
         self.decode_time_s += dur
         self.decode_steps += 1
@@ -2673,12 +2893,14 @@ class ServeEngine:
                 tokens = jnp.where(use_dev, prev.nxt, vals)
         t0 = self._lap(_STAGE)
         with obs.span("serve/decode_step",
-                      {"active": len(ds), "gather_bucket": bucket}
+                      {"active": len(ds), "gather_bucket": bucket,
+                       **self._latent_kw(1)}
                       if obs.has_sink() else None):
-            nxt, self._pools = self._decode_fn(
+            nxt, self._pools, *moe = self._decode_fn(
                 self.model, self.params, self._pools, tokens, tables,
                 ctx, active, temps, top_ks, top_ps, keys, folds,
                 self._plan, bucket, sampled)
+            self._moe_dispatched(moe, len(ds), True)
         dispatch_s = self._lap(_DISPATCH) - t0
         if self.timeline:
             # the enqueue cost lands in THIS iteration's ledger (the
@@ -2689,7 +2911,7 @@ class ServeEngine:
             slot.context_len += 1        # the fed token's K/V lands
             slot.inflight = 1
         return _PendingDecode(nxt, tuple((s, s.request) for s in ds),
-                              bucket, dispatch_s, t0)
+                              bucket, dispatch_s, t0, self._moe_mark())
 
     def _commit_decode(self, prev: Optional[_PendingDecode]) -> None:
         """Land one in-flight plain decode iteration: the deferred
@@ -2709,6 +2931,7 @@ class ServeEngine:
         with obs.span("serve/commit_fetch"):
             # graftlint: allow[R2] THE deferred commit fetch (ISSUE 12): deliberately one iteration late, so only the residual past the overlapped host work blocks here
             nxt = np.asarray(prev.nxt)
+        self._moe_landed = max(self._moe_landed, prev.moe_seq)
         t_end = self._lap(_FETCH)
         fetch_s = t_end - t0
         # the ENGINE's decode-time accounting stays blocked-time only
