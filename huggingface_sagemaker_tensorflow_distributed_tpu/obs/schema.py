@@ -127,6 +127,9 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               # mean pool bytes one decode dispatch reads — the figure
               # int8 pools halve)
               "kernel": (str,),
+              # which way decode steps attend (ISSUE 29): the fused
+              # paged kernel or the gathered copy of the cache
+              "decode_path": (str,),
               "kv_dtype": (str,),
               "kv_bytes_read": (int,),
               "kv_bytes_read_per_step": _NUM,

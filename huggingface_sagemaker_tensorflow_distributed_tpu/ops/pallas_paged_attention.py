@@ -2,207 +2,348 @@
 attention that walks per-slot block tables DIRECTLY, with optional
 in-kernel int8 KV dequantization.
 
-The serving engine's decode hot path was two HBM round-trips:
-``ops.attention.gather_paged_kv`` materializes a dense
-``[slots, H, width, D]`` view of each slot's paged KV, then the model
-attends over it — at long context the step is bound by KV bytes moved,
-not FLOPs (the read-amplification PagedAttention's motivating analysis
-names; Kwon et al. 2023 pay a single fused read here). This kernel
-folds the gather into the attention read:
+The gather path (``ops.attention.paged_attention(impl="xla")``)
+materializes a dense ``[slots, H, width, D]`` view of each slot's paged
+KV, rewrites it with the step's K/V, repeats it to the query heads and
+attends over it: five passes over a bucket-wide copy of the cache,
+whatever the contexts (PERF.md §5, PR 29: 79% of a decode step of
+``qwen2.5-3b-chat-sat``). This kernel reads each page that holds keys
+once, and nothing else (Kwon et al. 2023 pay a single fused read here):
 
-- **grid** ``(slot, context_block)`` with the context-block axis
-  innermost, so the online-softmax state (running max / sum / output
-  accumulator, Dao et al. 2022 — the same recurrence
-  ``ops/pallas_attention.py`` blocks over) lives in VMEM scratch across
-  one slot's context walk. One tile holds ALL kv heads of one pool
-  block, ``(1, block_size, H_kv, D)``: Mosaic requires a block's last
-  two dimensions to equal the array's or be multiples of (8, 128), and
-  a one-head slice of a ``[blocks, block_size, H_kv, D]`` pool is
-  neither (the shape this kernel had until it first met the compiler);
-- **block-table indirection in the BlockSpec index maps**: the tables
-  (and per-slot context lengths) ride scalar prefetch
-  (``pltpu.PrefetchScalarGridSpec``), so tile ``i`` of slot ``s`` DMAs
-  pool block ``tables[s, i]`` straight from the paged pool — no dense
-  intermediate ever exists in HBM;
-- **vector-unit arithmetic, heads on sublanes**: a decode query is one
-  row per head, so QKᵀ and PV are a lane reduction and a block-axis
-  reduction over the ``[block_size, H_kv, D]`` tile instead of
-  M=1 matmuls — every head advances in the same instruction and no
-  per-head slice or relayout of the tile is needed;
-- **context masking in-kernel**: keys at logical positions ≥
-  ``context_lens[s]`` (stale block tails, null-block junk) are masked
-  to −1e30 in-tile, and whole tiles past the context skip compute via
-  ``pl.when`` (the dynamic analogue of ``pallas_attention._tile_runs``
-  — the grid is static per width bucket, the work is not);
-- **GQA query grouping**: the ``H // H_kv`` query heads of one KV head
-  attend against the same resident tile (a static loop over the group),
-  so grouped-query models read each KV block exactly once — the repeat
-  the XLA path materializes never happens;
-- **sliding-window banding**: with ``window`` set, tiles entirely
-  BELOW the band (newest key ≤ ``ctx − 1 − window``) skip compute too
-  — the banded-tile inequality of ``_tile_runs``, driven by the
-  dynamic per-slot context — and in-band tiles mask per position;
-- **in-tile int8 dequant**: with scale pools given, K/V tiles load as
-  int8 (+ the fp32 per-(position, head) scale rows riding the same
-  block-table index maps) and dequantize in VMEM — int8 pools halve
-  the KV bytes per decode step END TO END, not just in storage.
+- **no grid, one walk**: the kernel is one program that loops over the
+  slots and, for each, over COMPUTE BLOCKS of several pages: 512 keys,
+  or as many as 1 MiB of a pool holds where that is fewer (many or wide
+  KV heads: :func:`block_pages`), so the kernel's fast memory does not
+  grow with the model's heads. A slot's walk runs from the band's first page
+  (0 without a window) to ``ceil(context / block_size)``: pages past the
+  context are never fetched, a slot at context 0 costs no page, and the
+  time follows the contexts, not the bucket ``width`` (which only bounds
+  the block-table columns a walk may touch);
+- **block-table indirection in the DMAs**: tables and context lengths
+  live in SMEM, the pools stay in HBM (``memory_space=pl.ANY``) and each
+  page is one ``make_async_copy`` from ``pool[tables[s, i]]`` into its
+  row range of a VMEM buffer; a block's copies are issued together, and
+  double-buffered against the arithmetic of the block before it, across
+  slot boundaries too;
+- **pages as rows**: a page ``[block_size, H_kv, D]`` is read as
+  ``[block_size * H_kv, D]``, one row a (key, kv head) pair. For the
+  pool's layout on the chip that view is a bitcast (rehearsal compile
+  for the v5e, PR 29: bf16 ``[N, 16, 2, 128]`` is tiled ``(2, 128)``
+  with the two heads packed in one 32-bit word, byte for byte what
+  ``[N, 32, 128]`` tiled ``(8, 128)`` is), so the layout that prefill,
+  copy-on-write, swap and the prefix index share stays as it is;
+- **QKᵀ and PV on the MXU**: ``[H, D] x [D, rows]`` for all query heads
+  against all rows of the block, the (query head, row) pairs whose kv
+  heads differ masked out with the keys past the context, float32
+  logits and softmax statistics (Dao et al. 2022's running max / sum),
+  the weights cast to the compute dtype for ``[H, rows] x [rows, D]``.
+  Operands are the query's dtype (bf16 in serving; float32 operands take
+  ``Precision.HIGHEST``), accumulation is float32: no lower than the
+  gather path, whose logits are a bf16 einsum's output. Splitting a
+  block by kv head instead would relayout every byte (the heads
+  interleave at sublane granularity); the masked product costs ``H_kv``
+  times the MXU work, which is not what bounds a decode step;
+- **sliding-window banding**: with ``window`` set the walk starts at the
+  page holding position ``context - window`` and in-band keys mask per
+  position (key kept iff ``0 <= q_pos - k_pos < window``);
+- **in-tile int8 dequant**: with scale pools given, K/V pages load as
+  int8 and the fp32 per-(position, head) scales are applied where they
+  are one multiply a logit: ``q . (k s) = (q . k) s`` scales a COLUMN of
+  the logits, ``sum p (v s) = sum (p s) v`` a column of the weights. The
+  scales themselves (4 bytes a row against the row's ``D``) are
+  gathered by the block tables outside the kernel into ``[slots, 1,
+  width * H_kv]`` row vectors: a scale pool's minor dimension is 1, and
+  a page of it cannot be sliced for a DMA.
+- **head sizes off the lane width**: a pool whose ``D`` is no multiple
+  of 128 is padded to one before the call, a copy of the pool a call.
+  Such a pool is not page-contiguous on the chip to begin with (the
+  v5e's compiler lays bf16 ``[N, 16, 12, 64]`` out block-minor:
+  rehearsal compile, PR 29), so the result is right and not fast, and
+  the serving engine's own choice never takes the kernel there.
 
-Numerics match the XLA gather path (``ops.attention.paged_attention``):
-fp32 logits and softmax statistics, fp32 PV accumulation, output cast
-to the query dtype. Inactive rows (``context_len == 0``) return ZEROS
-(the XLA path returns a softmax over fully-masked junk instead —
-callers discard those rows either way).
+Inactive rows (``context_len == 0``) return ZEROS (the XLA path returns
+a softmax over fully-masked junk instead — callers discard those rows
+either way).
 
 Correctness is testable without TPU hardware via
 ``pallas_call(interpret=True)`` — ``tests/test_paged_kernel.py`` pins
 kernel-vs-XLA parity across width buckets, GQA groupings, int8/fp
-pools, and sliding-window bands, and ``tests/test_serve.py`` pins
-engine-level token-exactness vs ``generate_causal`` with the kernel
-engaged.
+pools, sliding-window bands and compute-block boundaries, and
+``tests/test_serve.py`` pins engine-level token-exactness vs
+``generate_causal`` with the kernel engaged. On the chip:
+``benchmarks/tpu_kernel_parity.py`` (against float64) and
+``tools/paged_decode_microbench.py`` (time against the gather path).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+# keys one compute block holds: a few hundred, so that a layer-call of
+# some thousands of resident tokens is tens of blocks (16, 32 and 64
+# pages of 16 keys timed on the chip at chat-sat's shape, PR 29: 32)
+_BLOCK_KEYS = 512
+# ... and the bytes of one pool it may hold. Everything the kernel keeps
+# in VMEM is a multiple of this (four such buffers, the loaded block,
+# query heads x rows of float32 logits and bias), whatever ``H_kv``, so
+# 32 KV heads compile where two do (the v5e gives a kernel 16 MiB)
+_BLOCK_BYTES = 1 << 20
 
 
-def _paged_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
-                  window, groups):
-    """One (slot, context_block) tile over all kv heads.
-    ``tbl_ref``/``ctx_ref`` are the scalar-prefetched block tables /
-    context lengths (also consumed by the BlockSpec index maps — the
-    gather indirection); ``ks_ref``/``vs_ref`` are None on fp pools.
-    ``q_ref``/``o_ref`` are ``[1, G, H_kv, D]`` and the scratch
-    ``[G, H_kv, ·]``: group-major, so one group's heads are a leading
-    index away."""
-    s_idx = pl.program_id(0)
-    i = pl.program_id(1)
-    num_blocks = pl.num_programs(1)
+def block_pages(block_size: int, kv_heads: int, head_dim: int, itemsize: int,
+                pool_blocks: int, lane_rows: bool = False) -> int:
+    """Pages of one compute block: ``_BLOCK_KEYS`` keys' worth, no more
+    than ``_BLOCK_BYTES`` of a pool and no more than the pool has. With
+    ``lane_rows`` (int8 pools on the chip: a block's scales are sliced
+    along lanes at its first row) whole lane tiles of rows only."""
+    rows = block_size * kv_heads
+    pages = max(1, min(_BLOCK_KEYS // block_size,
+                       _BLOCK_BYTES // (rows * head_dim * itemsize),
+                       pool_blocks))
+    if lane_rows:
+        align = 128 // math.gcd(rows, 128)
+        if pages < align:
+            raise ValueError(
+                f"int8 pools: a compute block of {pages} pages x {rows} "
+                f"(key, head) rows is no multiple of 128 rows, and {align} "
+                f"pages do not fit the pool ({pool_blocks} blocks) or "
+                f"{_BLOCK_BYTES} bytes")
+        pages -= pages % align
+    return pages
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ctx = ctx_ref[s_idx]
-    start = i * block_size
-    # tiles fully past the context hold no valid key; with a sliding
-    # window, tiles fully BELOW the band (newest key ≤ ctx-1-window)
-    # hold none either — the dynamic form of _tile_runs' band check
-    run = start < ctx
-    if window is not None:
-        run = jnp.logical_and(run, start + block_size > ctx - window)
+def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
+                  block_size, kv_heads, pages, table_width, window, int8):
+    """The whole call: every slot's walk over its pages.
 
-    @pl.when(run)
-    def _step():
-        k = k_ref[0].astype(jnp.float32)                  # [bs, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
-        if ks_ref is not None:
-            # in-tile dequant: int8 block × fp32 per-(pos, head) scale
-            k = k * ks_ref[0]                             # [bs, Hkv, 1]
-            v = v * vs_ref[0]
-        pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (k.shape[0], k.shape[1], 1), 0)
-        keep = pos < ctx
-        if window is not None:
-            # the decode query sits at position ctx-1: Mistral's band
-            # keeps key j iff 0 <= (ctx-1) - j < window
-            keep = jnp.logical_and(keep, pos > ctx - 1 - window)
-        for g in range(groups):
-            q = q_ref[0, g].astype(jnp.float32)           # [Hkv, D]
-            s_log = jnp.sum(k * q[None], axis=-1,
-                            keepdims=True) * scale        # [bs, Hkv, 1]
-            s_log = jnp.where(keep, s_log, _NEG_INF)
-            m_prev = m_ref[g][:, :1][None]                # [1, Hkv, 1]
-            l_prev = l_ref[g][:, :1][None]
-            m_new = jnp.maximum(
-                m_prev, jnp.max(s_log, axis=0, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s_log - m_new)                    # [bs, Hkv, 1]
-            l_new = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
-            m_ref[g] = jnp.broadcast_to(m_new[0], m_ref.shape[1:])
-            l_ref[g] = jnp.broadcast_to(l_new[0], l_ref.shape[1:])
-            acc_ref[g] = acc_ref[g] * alpha[0] + jnp.sum(p * v, axis=0)
+    ``tbl_ref`` (SMEM, ``[slots * table_width]``) and ``ctx_ref`` (SMEM,
+    ``[slots]``) drive the DMAs; ``q_ref`` / ``o_ref`` are ``[slots, H,
+    D]`` in VMEM; ``head_bias_ref`` ``[H, pages * rows]`` is 0 where a
+    block's row belongs to the query head's kv head and -1e30 elsewhere.
+    The pools (``[N, rows, D]``, rows = block_size * kv_heads) stay in
+    HBM; ``bufs`` are their double buffers ``[2, pages, rows, D]``. With
+    ``int8`` two more VMEM inputs follow the bias: the gathered K and V
+    scales, ``[slots, 1, (table_width + pages) * rows]``."""
+    scales, refs = (refs[:2], refs[2:]) if int8 else ((), refs)
+    pools, o_ref, bufs, sem = refs[:2], refs[2], refs[3:5], refs[5]
+    num_slots, num_heads, _ = q_ref.shape
+    rows = block_size * kv_heads
+    P = pages
+    # a scale vector is sliced along lanes at a block's first row: keep
+    # that a multiple of the lane width by starting a banded walk on a
+    # page that is one
+    align = 128 // math.gcd(rows, 128) if int8 else 1
 
-    @pl.when(i == num_blocks - 1)
-    def _finish():
-        for g in range(groups):
-            l = l_ref[g][:, :1]
-            # a context-0 (inactive) row runs no tile: l == 0, output 0
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, g] = (acc_ref[g] / safe_l).astype(o_ref.dtype)
+    def copies(s, page, buf_slot, p):
+        """Page ``p`` of a block starting at table column ``page``."""
+        block = tbl_ref[s * table_width + page + p]
+        return [pltpu.make_async_copy(pool.at[block], buf.at[buf_slot, p],
+                                      sem.at[buf_slot, i])
+                for i, (pool, buf) in enumerate(zip(pools, bufs))]
+
+    def each_page(n, fn):
+        def body(p, carry):
+            fn(p)
+            return carry
+        lax.fori_loop(0, n, body, 0)
+
+    def start(s, page, n, buf_slot):
+        """Issue the DMAs of the ``n <= P`` pages of a block that hold
+        keys: unrolled where the block is full (a fifth faster at long
+        contexts than the loop: chip run, PR 29)."""
+        def go(p):
+            for c in copies(s, page, buf_slot, p):
+                c.start()
+
+        @pl.when(n == P)
+        def _full():
+            for p in range(P):
+                go(p)
+
+        @pl.when(n < P)
+        def _ragged():
+            each_page(n, go)
+
+    def wait(s, page, n, buf_slot):
+        """A DMA semaphore counts bytes: a full block's pages are ONE
+        wait for the whole buffer's worth (a tenth of a call's time
+        against a wait a page: chip run, PR 29)."""
+        @pl.when(n == P)
+        def _full():
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                pltpu.make_async_copy(pool.at[pl.ds(0, P)], buf.at[buf_slot],
+                                      sem.at[buf_slot, i]).wait()
+
+        @pl.when(n < P)
+        def _ragged():
+            each_page(n, lambda p: [c.wait()
+                                    for c in copies(s, page, buf_slot, p)])
+
+    def walk(s):
+        """(context, first page, end page) of slot ``s``."""
+        ctx = ctx_ref[s]
+        end = jnp.minimum(
+            lax.div(ctx + (block_size - 1), jnp.int32(block_size)),
+            table_width)
+        if window is None:
+            return ctx, jnp.int32(0), end
+        first = lax.div(jnp.maximum(ctx - window, 0),
+                        jnp.int32(block_size * align)) * align
+        return ctx, first, end
+
+    # a value row no DMA has written yet must not hold a NaN: its weight
+    # is an exact 0, and 0 * NaN is not. Stale rows of an earlier block
+    # are finite (real K/V); the keys' junk goes through a select
+    bufs[1][...] = jnp.zeros_like(bufs[1])
+
+    col = lax.broadcasted_iota(jnp.int32, (num_heads, P * rows), 1)
+    compute = q_ref.dtype
+    precision = (lax.Precision.HIGHEST if compute == jnp.float32
+                 else lax.Precision.DEFAULT)
+
+    def load(i, buf_slot):
+        """Stream ``i`` (0 keys, 1 values) of a landed block as
+        ``[P * rows, D]`` in the compute dtype (int8 values are exact
+        in it; their scales go on the logits' side)."""
+        return bufs[i][buf_slot].reshape(P * rows, -1).astype(compute)
+
+    def scale_row(i, s, page):
+        """The ``[1, P * rows]`` scales of the block at ``page``."""
+        return scales[i][s, :, pl.ds(pl.multiple_of(page * rows, 128),
+                                     P * rows)]
+
+    def slot_body(s, carry):
+        done, prefetched = carry     # blocks so far; is my first in flight
+        ctx, first, end = walk(s)
+        nblk = lax.div(end - first + (P - 1), jnp.int32(P))
+        nxt = jnp.minimum(s + 1, num_slots - 1)
+        _, first_n, end_n = walk(nxt)
+        next_walks = jnp.logical_and(s + 1 < num_slots, end_n > first_n)
+
+        @pl.when(jnp.logical_and(nblk > 0, prefetched == 0))
+        def _first():
+            start(s, first, jnp.minimum(P, end - first), done % 2)
+
+        def block_body(b, state):
+            m, l, acc = state
+            buf_slot = (done + b) % 2
+            page = first + b * P
+            n = jnp.minimum(P, end - page)
+
+            # the next block's pages fly while this one is attended: this
+            # slot's next block, or the next slot's first
+            @pl.when(b + 1 < nblk)
+            def _ahead():
+                start(s, page + P, jnp.minimum(P, end - page - P),
+                      1 - buf_slot)
+
+            @pl.when(jnp.logical_and(b + 1 == nblk, next_walks))
+            def _ahead_slot():
+                start(nxt, first_n, jnp.minimum(P, end_n - first_n),
+                      1 - buf_slot)
+
+            wait(s, page, n, buf_slot)
+            k = load(0, buf_slot)
+            logits = lax.dot_general(
+                q_ref[s], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=precision) * scale          # [H, P * rows]
+            if int8:
+                logits = logits * scale_row(0, s, page)
+            # row c of the block is key (page * block_size + c // kv_heads)
+            # of kv head c % kv_heads: the head through the bias, the
+            # position without a division
+            base = page * block_size
+            keep = col < (ctx - base) * kv_heads
+            if window is not None:
+                keep = jnp.logical_and(
+                    keep, col >= (ctx - window - base) * kv_heads)
+            logits = jnp.where(keep, logits + head_bias_ref[...], _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(logits - m_new)
+            l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            if int8:
+                # a scale past the context may be junk: 0 * NaN again
+                p = jnp.where(keep, p * scale_row(1, s, page), 0.0)
+            acc_new = alpha * acc + lax.dot_general(
+                p.astype(compute), load(1, buf_slot),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision)
+            return m_new, l_new, acc_new
+
+        m, l, acc = lax.fori_loop(
+            0, nblk, block_body,
+            (jnp.full((num_heads, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((num_heads, 1), jnp.float32),
+             jnp.zeros(o_ref.shape[1:], jnp.float32)))
+        # a context-0 (inactive) row walks no page: l == 0, output 0
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        return (done + nblk,
+                jnp.logical_and(nblk > 0, next_walks).astype(jnp.int32))
+
+    lax.fori_loop(0, num_slots, slot_body, (jnp.int32(0), jnp.int32(0)))
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "window", "interpret", "int8"))
+    static_argnames=("scale", "window", "interpret", "pages"))
 def _paged_call(q, k_pool, v_pool, block_tables, context_lens,
                 k_scale_pool, v_scale_pool, scale, window, interpret,
-                int8):
-    S, Hq, D = q.shape
-    _, bs, Hkv, _ = k_pool.shape
-    G = Hq // Hkv
+                pages):
+    S, Hq, head_dim = q.shape
+    N, bs, Hkv, _ = k_pool.shape
+    rows = bs * Hkv
     nb = block_tables.shape[1]
-    qg = q.reshape(S, Hkv, G, D).transpose(0, 2, 1, 3)    # [S, G, Hkv, D]
+    int8 = k_scale_pool is not None
+    lane_pad = 0 if interpret else -head_dim % 128
+    if lane_pad:
+        # zeros add nothing to a dot product; the output's are cut off
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, lane_pad)))
+        k_pool, v_pool = (jnp.pad(p, ((0, 0),) * 3 + ((0, lane_pad),))
+                          for p in (k_pool, v_pool))
+    D = head_dim + lane_pad
+    # pages as rows: one row a (key, kv head) pair (a bitcast on the chip)
+    pools = [k_pool.reshape(N, rows, D), v_pool.reshape(N, rows, D)]
+    # the scales of each slot's table span as one row vector, with a
+    # block of zeros behind it for the last block's slice to end in
+    scales = [jnp.pad(sp[block_tables].reshape(S, 1, nb * rows),
+                      ((0, 0), (0, 0), (0, pages * rows)))
+              for sp in ((k_scale_pool, v_scale_pool) if int8 else ())]
+    # query head j attends kv head j // G; row c of a block holds kv head
+    # c % Hkv
+    head_bias = jnp.where(
+        (jnp.arange(Hq) // (Hq // Hkv))[:, None]
+        == (jnp.arange(pages * rows) % Hkv)[None, :],
+        0.0, _NEG_INF).astype(jnp.float32)
 
-    # index maps receive the scalar-prefetch refs after the grid ids:
-    # the kv maps read the BLOCK TABLE to pick the pool block each tile
-    # DMAs — the gather, folded into the attention read
-    def q_map(s, i, tbl, ctx):
-        return (s, 0, 0, 0)
-
-    def kv_map(s, i, tbl, ctx):
-        return (tbl[s, i], 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, G, Hkv, D), q_map),
-        pl.BlockSpec((1, bs, Hkv, D), kv_map),
-        pl.BlockSpec((1, bs, Hkv, D), kv_map),
-    ]
-    args = [qg, k_pool, v_pool]
-    if int8:
-        in_specs += [pl.BlockSpec((1, bs, Hkv, 1), kv_map),
-                     pl.BlockSpec((1, bs, Hkv, 1), kv_map)]
-        args += [k_scale_pool, v_scale_pool]
-
-    def kernel(*refs):
-        if int8:
-            tbl, ctx, q_, k_, v_, ks_, vs_, o_, acc_, m_, l_ = refs
-        else:
-            tbl, ctx, q_, k_, v_, o_, acc_, m_, l_ = refs
-            ks_ = vs_ = None
-        _paged_kernel(tbl, ctx, q_, k_, v_, ks_, vs_, o_, acc_, m_, l_,
-                      scale=scale, block_size=bs, window=window, groups=G)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, Hkv, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G, Hkv, D), jnp.float32),     # output accumulator
-            pltpu.VMEM((G, Hkv, 128), jnp.float32),   # running max (lanes)
-            pltpu.VMEM((G, Hkv, 128), jnp.float32),   # running sum (lanes)
-        ],
-    )
+    kernel = functools.partial(
+        _paged_kernel, scale=scale, block_size=bs, kv_heads=Hkv,
+        pages=pages, table_width=nb, window=window, int8=int8)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, G, Hkv, D), q.dtype),
+        in_specs=[smem, smem, vmem, vmem] + [vmem] * len(scales) + [hbm] * 2,
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct((S, Hq, D), q.dtype),
+        scratch_shapes=(
+            [pltpu.VMEM((2, pages, rows, D), p.dtype) for p in pools]
+            + [pltpu.SemaphoreType.DMA((2, 2))]),
         interpret=interpret,
         name="paged_decode",
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      *args)
-    return out.transpose(0, 2, 1, 3).reshape(S, Hq, D)
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      context_lens.astype(jnp.int32), q, head_bias, *scales, *pools)
+    return out[..., :head_dim] if lane_pad else out
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
@@ -222,15 +363,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
     counts valid tokens per slot (the query's own K/V included — the
     query position is ``context_lens - 1``). ``width`` (static, block
     multiple) restricts the walk to a context bucket exactly like
-    :func:`~.attention.gather_paged_kv`; ``window`` applies Mistral's
-    sliding band (key kept iff ``0 <= q_pos - k_pos < window``) with
-    below-band tiles skipped entirely. GQA is native: query heads must
-    be a multiple of pool kv heads. Returns [slots, heads, head_dim];
-    context-0 rows return zeros."""
+    :func:`~.attention.gather_paged_kv`: callers guarantee
+    ``context_lens <= width``, and the time follows the contexts, not
+    the bucket. ``window`` applies Mistral's sliding band (key kept iff
+    ``0 <= q_pos - k_pos < window``) with the pages below the band not
+    read. GQA is native: query heads must be a multiple of pool kv
+    heads. Returns [slots, heads, head_dim]; context-0 rows return
+    zeros."""
     if (k_scale_pool is None) != (v_scale_pool is None):
         raise ValueError("int8 pools need BOTH k_scale_pool and "
                          "v_scale_pool (or neither)")
-    int8 = k_scale_pool is not None
     if q.shape[1] % k_pool.shape[2]:
         raise ValueError(
             f"query heads {q.shape[1]} must be a multiple of pool kv "
@@ -250,6 +392,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
     scale = scale if scale is not None else head_dim ** -0.5
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
+    # how many pages are fetched together and attended as one matmul (a
+    # full block's one wait is described by the pool's first pages)
+    pages = block_pages(bs, k_pool.shape[2], head_dim + -head_dim % 128,
+                        k_pool.dtype.itemsize, k_pool.shape[0],
+                        lane_rows=k_scale_pool is not None and not interpret)
     return _paged_call(q, k_pool, v_pool, block_tables, context_lens,
                        k_scale_pool, v_scale_pool, float(scale),
-                       window, interpret, int8)
+                       window, interpret, pages)

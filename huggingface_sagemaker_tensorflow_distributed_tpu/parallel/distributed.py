@@ -19,6 +19,7 @@ On GCP TPU VMs all three are auto-detected by JAX and may be omitted.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 
@@ -32,9 +33,9 @@ _INITIALIZED = False
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 # <checkout>/.jax_cache (git-ignored): the path is part of the cache
 # key's environment, so it is fixed, never per process or per job
-_CHECKOUT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+_CHECKOUT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 
 def compilation_cache_dir() -> str:
@@ -50,9 +51,24 @@ def enable_compilation_cache() -> str:
     :func:`compilation_cache_dir`. With ``JAX_COMPILATION_CACHE_DIR``
     set, jax's own reading of it is the only setting and nothing is
     configured here. ``JAX_ENABLE_COMPILATION_CACHE=false`` (the test
-    suite) keeps jax from reading or writing the directory at all."""
+    suite) keeps jax from reading or writing the directory at all. File
+    names in source locations lose the checkout's path, so that a
+    program's key is the same from any checkout (unless
+    ``JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX`` already says what to
+    strip)."""
     if not os.environ.get(ENV_CACHE_DIR):
         jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    if not jax.config.jax_hlo_source_file_canonicalization_regex:
+        # a Mosaic kernel rides in its program as the bytes of its
+        # module, source locations included, and the cache's key is over
+        # those bytes (an ordinary operation's locations are left out of
+        # it). With the checkout's path in the file names a copy of the
+        # checkout elsewhere compiles every program that holds a kernel
+        # anew (the decode steps: 23 s of set-up, chip run of PR 29;
+        # PERF.md §6). Names relative to the checkout are the same
+        # everywhere
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(_CHECKOUT + os.sep))
     return compilation_cache_dir()
 
 

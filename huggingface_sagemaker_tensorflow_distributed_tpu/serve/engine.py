@@ -58,15 +58,20 @@ speculation; vLLM + Orca + Sarathi + Leviathan lineage):
   them. Writes into still-shared blocks privatize first via a
   device-side block copy (COW) — output stays token-exact vs cold
   start.
-- **Fused paged-attention kernel** (``kernel='pallas'``) — the decode
-  step's gather→dense-attend HBM round trip collapses into ONE fused
-  read: the model's paged decode branch scatters each slot's new K/V
-  straight into the pools and attends via the Pallas kernel
+- **Fused paged-attention kernel** (``decode_path='paged_kernel'``) —
+  the decode step's gather→dense-attend HBM round trip collapses into
+  ONE fused read: the model's paged decode branch scatters each slot's
+  new K/V straight into the pools and attends via the Pallas kernel
   (``ops/pallas_paged_attention.py``), which walks the block tables
-  inside the attention read — no ``[S, H, width, D]`` intermediate.
-  Rides the same bucket ladder (one compile per bucket); interpret
-  mode off-TPU, so CPU runs are correct but slow (tests), and the
-  default stays ``kernel='xla'`` (the gather reference path).
+  inside the attention read — no ``[S, H, width, D]`` intermediate, and
+  no page past a slot's context is read. Rides the same bucket ladder
+  (one compile per bucket). It IS the decode path where
+  :func:`resolve_decode_path` finds it measured to win: on a TPU, for
+  K/V pools of 128-wide heads stored in a floating type, without a
+  mesh. Everywhere else the gather path decodes: on a CPU (the kernel
+  is interpret mode there: correct but slow, and the tests' reference
+  is the gather), for latent pools, under a tensor-parallel mesh, and
+  in the speculative step. ``kernel='xla' | 'pallas'`` forces either.
 - **int8 KV pools** (``kv_cache_dtype='int8'``) — pools store K/V as
   symmetric per-(position, head) int8 with fp32 scales riding parallel
   scale pools (written by the model's own ``kv_quantize`` protocol at
@@ -227,18 +232,73 @@ def parse_tp(spec) -> int:
     return tp
 
 
-def parse_kernel(spec: Union[str, None]) -> str:
-    """The decode-kernel knob: ``xla`` (gather + dense attention — the
-    reference path, CPU-native) or ``pallas`` (the fused paged-decode
-    kernel, ``ops/pallas_paged_attention.py`` — interpret-mode off
-    TPU). None reads ``HSTD_SERVE_KERNEL``, default ``xla``."""
+def parse_kernel(spec: Union[str, None]) -> Optional[str]:
+    """The decode-kernel knob: ``xla`` forces the gather path (gather +
+    dense attention — the reference, CPU-native), ``pallas`` the fused
+    paged-decode kernel (``ops/pallas_paged_attention.py`` —
+    interpret-mode off TPU). None reads ``HSTD_SERVE_KERNEL``; with that
+    unset too the answer is None: the engine chooses
+    (:func:`resolve_decode_path`)."""
     if spec is None:
-        spec = os.environ.get(ENV_KERNEL, "xla")
-    s = str(spec).strip().lower() or "xla"
+        spec = os.environ.get(ENV_KERNEL)
+    if spec is None or not str(spec).strip():
+        return None
+    s = str(spec).strip().lower()
     if s not in ("xla", "pallas"):
         raise ValueError(f"unparseable {ENV_KERNEL} value {spec!r}: "
                          "expected xla | pallas")
     return s
+
+
+# head sizes at which the fused kernel was measured to beat the gather
+# path on the chip (tools/paged_decode_microbench.py; PERF.md §6, PR 29:
+# at 1, 2, 4, 8 and 32 KV heads of 128, bf16 and float32; the kernel
+# bounds its compute block in bytes, so the number of KV heads is no
+# input here). 64 is not among them: such a pool is laid out block-minor
+# on the v5e, no page of it is contiguous, and the kernel's call copies
+# it whole
+_KERNEL_HEAD_DIMS = (128,)
+
+
+def resolve_decode_path(kernel: Optional[str], *, platform: str,
+                        pool_kinds: Sequence[str], routed: bool,
+                        mesh: bool, head_dim: int, kv_dtype: str) -> str:
+    """Which way a decode step attends: ``paged_kernel`` (the fused
+    kernel walks the block tables; ``_paged_decode_step``) or ``gather``
+    (a bucket-wide copy of the cache; ``_decode_step``). A pure function
+    of what the engine can see: the backend's ``platform``, the kinds of
+    the plan's pooled leaves (``kv`` | ``latent``), whether the model
+    routes tokens to experts, whether a tensor-parallel ``mesh`` is on,
+    the pools' ``head_dim`` and storage ``kv_dtype`` (``fp`` | ``int8``),
+    and the explicit ``kernel`` (``xla`` | ``pallas`` | None = choose).
+
+    An explicit value wins, and ``pallas`` raises where the kernel has
+    no form: latent pools (and routed experts, whose step it does not
+    count), a mesh. Left to choose, the kernel is taken only where it was
+    measured to win: on a TPU, K/V pools, no mesh, a head size of
+    ``_KERNEL_HEAD_DIMS``, pools in a floating type (an int8 pool of two
+    KV heads is stored head-major within a page on the v5e and would be
+    copied whole for every call: rehearsal compile, PR 29)."""
+    fits = all(k == "kv" for k in pool_kinds) and not routed
+    if kernel == "pallas":
+        if mesh:
+            raise ValueError(
+                "kernel='pallas' does not compose with a tensor-parallel "
+                "mesh: the fused paged kernel reads whole pools and "
+                "would need a shard_map port — serve TP with the xla "
+                "gather path (the kernel is a per-chip bandwidth "
+                "optimization; TP is a capacity one)")
+        if not fits:
+            raise ValueError(
+                "kernel='pallas' (the fused paged K/V kernel) has no "
+                "latent-attention form: serve this model with the "
+                "xla gather path")
+        return "paged_kernel"
+    if kernel == "xla":
+        return "gather"
+    chosen = (platform == "tpu" and fits and not mesh
+              and head_dim in _KERNEL_HEAD_DIMS and kv_dtype == "fp")
+    return "paged_kernel" if chosen else "gather"
 
 
 def parse_kv_dtype(spec: Union[str, None], model_default: str) -> str:
@@ -1054,6 +1114,10 @@ class EngineStats(NamedTuple):
     peak_resident_requests: int = 0
     # paged-attention kernel + int8 pools (ISSUE 9)
     kernel: str = "xla"
+    # which way decode steps attended, and how many went each way (a
+    # speculative engine's windows are gather steps whatever ``kernel``)
+    decode_path: str = "gather"
+    decode_steps_by_path: Optional[dict] = None
     kv_dtype: str = "fp"
     kv_bytes_read: int = 0
     kv_token_bytes: int = 0
@@ -1138,11 +1202,16 @@ class ServeEngine:
     byte-for-byte the refcount-free engine's behavior — same tokens,
     same compile count.
 
-    ``kernel`` (None reads ``HSTD_SERVE_KERNEL``, default ``xla``)
-    selects the decode-attention path: ``xla`` gathers a dense view
-    then attends (reference, CPU-native), ``pallas`` runs the fused
+    ``kernel`` (None reads ``HSTD_SERVE_KERNEL``; unset, the engine
+    chooses) selects the decode-attention path: ``xla`` gathers a dense
+    view then attends (reference, CPU-native), ``pallas`` runs the fused
     paged-decode kernel — gather folded into the attention read, int8
-    dequant in-tile, sliding-window band tiles skipped. Speculative
+    dequant in-tile, no page outside the context or the sliding band
+    read. Left to choose (:func:`resolve_decode_path`), the engine takes
+    the kernel on a TPU for K/V pools of 128-wide heads in a floating
+    type without a mesh, and the gather path anywhere else; the path
+    taken is ``decode_path`` in ``stats()``, the ``report`` event and
+    the ``serve/decode_step`` span's arguments. Speculative
     engines keep draft/verify on the assembled path either way (the
     kernel is single-token). ``kv_cache_dtype`` (None reads
     ``HSTD_SERVE_KV_DTYPE``, default = the model config's own value)
@@ -1269,7 +1338,7 @@ class ServeEngine:
         if getattr(cfg, "pipeline_stages", 0):
             raise ValueError("ServeEngine needs the dense stack "
                              "(pipeline_stages=0)")
-        self.kernel = parse_kernel(kernel)
+        kernel = parse_kernel(kernel)
         # tensor-parallel mesh resolution (ISSUE 13): an explicit Mesh,
         # an int degree, or the HSTD_SERVE_TP env default
         from jax.sharding import Mesh as _Mesh
@@ -1292,13 +1361,6 @@ class ServeEngine:
                 self.mesh = tensor_parallel_mesh(self.tp)
             else:
                 self.mesh = None
-        if self.mesh is not None and self.kernel == "pallas":
-            raise ValueError(
-                "kernel='pallas' does not compose with a tensor-parallel "
-                "mesh: the fused paged kernel reads whole pools and "
-                "would need a shard_map port — serve TP with the xla "
-                "gather path (the kernel is a per-chip bandwidth "
-                "optimization; TP is a capacity one)")
         self.kv_cache_dtype = parse_kv_dtype(
             kv_cache_dtype, getattr(cfg, "kv_cache_dtype", "fp"))
         if self.kv_cache_dtype != getattr(cfg, "kv_cache_dtype", "fp"):
@@ -1359,16 +1421,23 @@ class ServeEngine:
         # (token, expert) pairs one real token makes over the model
         self._moe_fanout = (int(cfg.num_experts_per_tok)
                             * int(cfg.num_moe_layers)) if self._routes else 0
-        if self._latent or self._routes:
-            if self.kernel == "pallas":
-                raise ValueError(
-                    "kernel='pallas' (the fused paged K/V kernel) has no "
-                    "latent-attention form: serve this model with the "
-                    "xla gather path")
-            if self.speculate_k:
-                raise ValueError(
-                    "speculative decoding is not wired for latent-"
-                    "attention or routed-expert models")
+        # which way a decode step attends: forced by ``kernel``, else
+        # chosen from the platform, the plan's kinds, the mesh and the
+        # pools' head size and storage; ``self.kernel`` names the result
+        path = resolve_decode_path(
+            kernel, platform=jax.default_backend(),
+            pool_kinds=[k[0] for k in plan.kinds if k[0] in _POOLED],
+            routed=self._routes, mesh=self.mesh is not None,
+            head_dim=max(d for _h, d, _dt in pool_shapes),
+            kv_dtype=self.kv_cache_dtype)
+        self.kernel = "pallas" if path == "paged_kernel" else "xla"
+        # a speculative window attends a gathered cache (``_spec_fn``)
+        # whatever the plain step would have done
+        self.decode_path = "gather" if self.speculate_k else path
+        if (self._latent or self._routes) and self.speculate_k:
+            raise ValueError(
+                "speculative decoding is not wired for latent-"
+                "attention or routed-expert models")
         # bytes one resident token costs across every pool (int8 KV +
         # its fp32 scale plane included) — the figure that sizes a
         # byte-budgeted pool and denominates kv_bytes_read telemetry.
@@ -1484,7 +1553,7 @@ class ServeEngine:
         # to the telemetry stream — the byte-identity contract.
         self.replica: Optional[int] = None
         self._decode_fn = (_paged_decode_step_jit(donate)
-                           if self.kernel == "pallas"
+                           if self.decode_path == "paged_kernel"
                            else _decode_step_jit(donate))
         self._prefill_fn = _prefill_chunk_jit(donate)
         self._spec_fn = _spec_step_jit(donate)
@@ -2017,6 +2086,7 @@ class ServeEngine:
             out["decode_tokens_per_sec"] = round(
                 self.decode_tokens / self.decode_time_s, 1)
         out["kernel"] = self.kernel
+        out["decode_path"] = self.decode_path
         out["kv_dtype"] = self.kv_cache_dtype
         # latent cache / routed experts: absent for any other model
         if self._latent:
@@ -2209,6 +2279,11 @@ class ServeEngine:
             shared_read_frac=self.blocks.shared_read_frac(),
             peak_resident_requests=self.peak_resident,
             kernel=self.kernel,
+            decode_path=self.decode_path,
+            # an engine decodes one way for life
+            decode_steps_by_path={
+                p: self.decode_steps if p == self.decode_path else 0
+                for p in ("paged_kernel", "gather")},
             kv_dtype=self.kv_cache_dtype,
             kv_bytes_read=self.kv_bytes_read,
             kv_token_bytes=self.blocks.token_bytes,
@@ -2776,6 +2851,7 @@ class ServeEngine:
         t0 = self._lap(_STAGE)
         with obs.span("serve/decode_step",
                       {"active": len(ds), "gather_bucket": bucket,
+                       "decode_path": self.decode_path,
                        **self._latent_kw(1)}
                       if obs.has_sink() else None):
             nxt, self._pools, *moe = self._decode_fn(
@@ -2894,6 +2970,7 @@ class ServeEngine:
         t0 = self._lap(_STAGE)
         with obs.span("serve/decode_step",
                       {"active": len(ds), "gather_bucket": bucket,
+                       "decode_path": self.decode_path,
                        **self._latent_kw(1)}
                       if obs.has_sink() else None):
             nxt, self._pools, *moe = self._decode_fn(
@@ -3042,6 +3119,7 @@ class ServeEngine:
         t0 = self._lap(_STAGE)
         with obs.span("serve/spec_decode_step",
                       {"active": len(ds), "gather_bucket": bucket,
+                       "decode_path": self.decode_path,
                        "speculate_k": k} if obs.has_sink() else None):
             drafts, n_acc, bonus, self._pools, self._d_pools = \
                 self._spec_fn(

@@ -223,10 +223,13 @@ def main() -> None:
                              "HSTD_SERVE_PREFIX_CACHE or on)")
     parser.add_argument("--kernel", default=None,
                         choices=("xla", "pallas"),
-                        help="decode attention path: xla = gather + "
-                             "dense (reference), pallas = fused paged "
-                             "kernel (interpret mode off-TPU; default: "
-                             "HSTD_SERVE_KERNEL or xla)")
+                        help="force the decode attention path: xla = "
+                             "gather + dense (reference), pallas = fused "
+                             "paged kernel (interpret mode off-TPU). "
+                             "Default: HSTD_SERVE_KERNEL, else the engine "
+                             "chooses: the kernel on a TPU for K/V pools "
+                             "of 128-wide heads in a floating type "
+                             "without --tp, the gather anywhere else")
     parser.add_argument("--kv_cache_dtype", default=None,
                         choices=("fp", "int8"),
                         help="KV pool storage; int8 halves pool bytes "

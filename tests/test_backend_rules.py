@@ -42,7 +42,8 @@ def test_env_set_means_no_code_path_writes_the_cache_dir(
         monkeypatch, config_updates, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert enable_compilation_cache() == str(tmp_path)
-    assert config_updates == []
+    assert [k for k, _ in config_updates
+            if k == "jax_compilation_cache_dir"] == []
     assert chip_smoke.cache_dir() == str(tmp_path)
 
 
@@ -51,10 +52,34 @@ def test_env_unset_means_one_fixed_directory_in_the_checkout(
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     fixed = os.path.join(ROOT, ".jax_cache")
     assert enable_compilation_cache() == fixed
-    assert config_updates == [("jax_compilation_cache_dir", fixed)]
+    assert config_updates[0] == ("jax_compilation_cache_dir", fixed)
     assert compilation_cache_dir() == chip_smoke.cache_dir() == fixed
     with open(os.path.join(ROOT, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+def test_source_locations_lose_the_checkouts_path(monkeypatch,
+                                                    config_updates):
+    """A Mosaic kernel's module rides in its program with its source
+    locations, and the compile cache's key is over those bytes: file
+    names are made relative to the checkout, so the key is the same from
+    a copy at another path (PR 29). A regex the user has set stays."""
+    import re
+
+    enable_compilation_cache()
+    (regex,) = [v for k, v in config_updates
+                if k == "jax_hlo_source_file_canonicalization_regex"]
+    here = os.path.join(ROOT, "huggingface_sagemaker_tensorflow_distributed"
+                        "_tpu", "ops", "pallas_paged_attention.py")
+    assert re.sub(regex, "", here) == os.path.relpath(here, ROOT)
+    assert re.sub(regex, "", "/elsewhere" + here) == "/elsewhere" + here
+    config_updates.clear()
+    monkeypatch.setattr(
+        type(jax.config), "jax_hlo_source_file_canonicalization_regex",
+        property(lambda self: "^/mine/"), raising=False)
+    enable_compilation_cache()
+    assert not [k for k, _ in config_updates
+                if k == "jax_hlo_source_file_canonicalization_regex"]
 
 
 def test_every_entry_point_goes_through_the_one_rule():

@@ -51,6 +51,18 @@ def _kernel(q, pk, pv, tables, ctx, width=None, window=None, ks=None,
                                   v_scale_pool=vs)
 
 
+@pytest.fixture
+def two_page_blocks(monkeypatch):
+    """The kernel's one derived compute block, shrunk to TWO of the
+    cell's pages (32 keys) so a 128-key table holds several."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+        pallas_paged_attention,
+    )
+
+    monkeypatch.setattr(pallas_paged_attention, "_BLOCK_KEYS",
+                        2 * _CELL["bs"])
+
+
 def _assert_close(got, want, ctx):
     """Active rows match to tolerance; the kernel's context-0 rows are
     exact zeros (the XLA path emits masked-junk softmax there — both
@@ -61,21 +73,157 @@ def _assert_close(got, want, ctx):
     assert np.all(np.asarray(got)[~act] == 0.0)
 
 
-def test_paged_kernel_smoke_matches_xla():
-    """Tier-1 smoke: one small fp GQA case through the kernel (tiny
-    width, one bucket) — the full matrix runs under the slow tier."""
+# chat-sat's geometry cut small (PR 29): 2 KV heads x 8 query heads a
+# group, heads of 128, blocks of 16, a compute block of TWO pages (32
+# keys). One batch holds every edge of the walk: an empty slot, one key,
+# exactly one page, exactly one compute block, one key past a compute
+# block, and the bucket's full width.
+_CELL = dict(Hkv=2, G=8, D=128, bs=16)
+
+
+def _cell_contexts(width):
+    return np.array([0, 1, 16, 32, 33, width], np.int32)
+
+
+def _cell_case(rng, width, int8):
+    """(q, pools as stored, pools as their values, scales, tables, ctx)
+    at the cell's geometry for the bucket ``width``; the block table
+    spans 128 keys whatever the bucket."""
     import jax.numpy as jnp
 
-    rng = np.random.RandomState(0)
-    S, Hq, Hkv, D, bs, nb = 3, 4, 2, 8, 4, 4
-    pk, pv = _pools(rng, 1 + S * nb, bs, Hkv, D)
-    tables = jnp.asarray(rng.permutation(np.arange(1, 1 + S * nb))
+    Hkv, G, D, bs = (_CELL[k] for k in ("Hkv", "G", "D", "bs"))
+    ctx = _cell_contexts(width)
+    S, nb = len(ctx), 128 // bs
+    N = 1 + S * nb
+    pk, pv = _pools(rng, N, bs, Hkv, D)
+    tables = jnp.asarray(rng.permutation(np.arange(1, N))
                          .reshape(S, nb).astype(np.int32))
-    q = jnp.asarray(rng.randn(S, Hq, D).astype(np.float32))
-    ctx = jnp.asarray(np.array([5, 16, 0], np.int32))
-    got = _kernel(q, pk, pv, tables, ctx, width=16)
-    want = _xla_ref(q, pk, pv, tables, ctx, width=16)
+    q = jnp.asarray(rng.randn(S, Hkv * G, D).astype(np.float32) * 0.3)
+    if not int8:
+        return q, (pk, pv), (pk, pv), {}, tables, jnp.asarray(ctx)
+    qk, ks, dk = _quantized(rng, pk)
+    qv, vs, dv = _quantized(rng, pv)
+    return (q, (qk, qv), (dk, dv), dict(ks=ks, vs=vs), tables,
+            jnp.asarray(ctx))
+
+
+@pytest.mark.parametrize("case", [
+    "smoke",
+    "cell-b64-fp", "cell-b64-fp-window", "cell-b64-int8",
+    "cell-b64-int8-window",
+    "cell-b128-fp", "cell-b128-fp-window", "cell-b128-int8",
+    "cell-b128-int8-window",
+])
+def test_paged_kernel_smoke_matches_xla(case, two_page_blocks):
+    """Tier-1 parity (interpret mode against the gather path): one small
+    fp GQA case, and the cell's geometry cut small at two buckets, fp and
+    int8 pools, with and without a window whose band starts inside a
+    compute block — the full matrix runs under the slow tier."""
+    import jax.numpy as jnp
+
+    if case == "smoke":
+        rng = np.random.RandomState(0)
+        S, Hq, Hkv, D, bs, nb = 3, 4, 2, 8, 4, 4
+        pk, pv = _pools(rng, 1 + S * nb, bs, Hkv, D)
+        tables = jnp.asarray(rng.permutation(np.arange(1, 1 + S * nb))
+                             .reshape(S, nb).astype(np.int32))
+        q = jnp.asarray(rng.randn(S, Hq, D).astype(np.float32))
+        ctx = jnp.asarray(np.array([5, 16, 0], np.int32))
+        got = _kernel(q, pk, pv, tables, ctx, width=16)
+        want = _xla_ref(q, pk, pv, tables, ctx, width=16)
+        _assert_close(got, want, ctx)
+        return
+    _, bucket, kind, *rest = case.split("-")
+    width, window = int(bucket[1:]), (24 if rest else None)
+    q, stored, values, scales, tables, ctx = _cell_case(
+        np.random.RandomState(7), width, kind == "int8")
+    got = _kernel(q, *stored, tables, ctx, width=width, window=window,
+                  **scales)
+    want = _xla_ref(q, *values, tables, ctx, width=width, window=window)
     _assert_close(got, want, ctx)
+
+
+@pytest.mark.parametrize("kind", ["fp", "int8"])
+def test_pages_past_the_context_are_not_read(kind, two_page_blocks):
+    """Junk (NaN) in every pool block a slot's context does not reach
+    must not change its output: the walk ends at the context's last
+    page, an empty slot fetches nothing, and the null block is never
+    touched. (int8 values cannot hold a NaN: their scales do, over
+    values of 127.)"""
+    import jax.numpy as jnp
+
+    width = 128
+    q, stored, values, scales, tables, ctx = _cell_case(
+        np.random.RandomState(8), width, kind == "int8")
+    want = _xla_ref(q, *values, tables, ctx, width=width)
+    bs = _CELL["bs"]
+    reached = np.zeros((stored[0].shape[0],), bool)
+    for row, n in zip(np.asarray(tables), np.asarray(ctx)):
+        reached[row[:-(-int(n) // bs)]] = True
+    junk = jnp.asarray(~reached)[:, None, None, None]
+    if kind == "int8":
+        stored = tuple(jnp.where(junk, jnp.int8(127), p) for p in stored)
+        scales = {k: jnp.where(junk, jnp.nan, v) for k, v in scales.items()}
+    else:
+        stored = tuple(jnp.where(junk, jnp.nan, p) for p in stored)
+    got = _kernel(q, *stored, tables, ctx, width=width, **scales)
+    assert np.isfinite(np.asarray(got)).all()
+    _assert_close(got, want, ctx)
+
+
+@pytest.mark.parametrize("shape, pages", [
+    # (block size, KV heads, head size, item bytes, pool blocks, int8 on chip)
+    ((16, 2, 128, 2, 7629, False), 32),     # chat-sat's: 512 keys
+    ((16, 8, 128, 2, 4000, False), 32),     # Llama-3-8B's: 1 MiB exactly
+    ((16, 32, 128, 2, 4000, False), 8),     # Llama-2-7B's: 1 MiB is 128 keys
+    ((16, 32, 128, 4, 4000, False), 4),
+    ((16, 8, 128, 4, 4000, False), 16),
+    ((16, 2, 128, 1, 7629, True), 32),
+    ((24, 1, 128, 1, 4000, True), 16),      # 21 pages cut to whole lane tiles
+    ((16, 2, 128, 2, 5, False), 5),         # no more than the pool has
+    ((16, 128, 256, 4, 4000, False), 1),    # a page over the budget: one
+])
+def test_compute_block_is_bounded_in_keys_and_bytes(shape, pages):
+    """The compute block is derived, not set: 512 keys, at most 1 MiB of
+    a pool (so VMEM does not grow with the KV heads: 32 of them compile
+    where 2 do), at most the pool; int8 pools on the chip take whole
+    lane tiles of rows or raise (REVIEW of PR 29)."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_attention import (
+        _BLOCK_BYTES,
+        block_pages,
+    )
+
+    got = block_pages(*shape)
+    assert got == pages
+    bs, Hkv, D, itemsize, _, lanes = shape
+    assert got == 1 or got * bs * Hkv * D * itemsize <= _BLOCK_BYTES
+    if lanes:
+        assert got * bs * Hkv % 128 == 0
+    with pytest.raises(ValueError, match="multiple of 128 rows"):
+        block_pages(16, 1, 128, 1, 4, True)
+
+
+def test_many_kv_heads_match_xla_with_the_block_cut_by_bytes():
+    """Llama-2-7B's heads (32 KV heads of 128, no grouping): a page of
+    float32 is 256 KiB, so a compute block is 4 pages by the byte bound
+    and a 128-key context is two of them."""
+    import jax.numpy as jnp
+
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_attention import (
+        block_pages,
+    )
+
+    rng = np.random.RandomState(9)
+    S, Hkv, D, bs, nb = 3, 32, 128, 16, 8
+    N = 1 + S * nb
+    assert block_pages(bs, Hkv, D, 4, N) == 4
+    pk, pv = _pools(rng, N, bs, Hkv, D)
+    tables = jnp.asarray(rng.permutation(np.arange(1, N))
+                         .reshape(S, nb).astype(np.int32))
+    q = jnp.asarray(rng.randn(S, Hkv, D).astype(np.float32) * 0.3)
+    ctx = jnp.asarray(np.array([65, 128, 0], np.int32))
+    _assert_close(_kernel(q, pk, pv, tables, ctx, width=128),
+                  _xla_ref(q, pk, pv, tables, ctx, width=128), ctx)
 
 
 @pytest.mark.parametrize("group", [1, 4])

@@ -1326,6 +1326,115 @@ def test_kv_pool_bytes_doubles_int8_admission(gpt2_setup):
             >= 2 * fp_eng.stats().peak_resident_requests)
 
 
+_SEEN = dict(platform="tpu", pool_kinds=("kv", "kv"), routed=False,
+             mesh=False, head_dim=128, kv_dtype="fp")
+
+
+@pytest.mark.parametrize("kernel,seen,want", [
+    # left to choose: the kernel where it was measured to win ...
+    (None, {}, "paged_kernel"),
+    # ... and the gather path for anything else the engine can see
+    (None, {"platform": "cpu"}, "gather"),
+    (None, {"platform": "gpu"}, "gather"),
+    (None, {"pool_kinds": ("latent",)}, "gather"),
+    (None, {"pool_kinds": ("kv", "latent")}, "gather"),
+    (None, {"routed": True}, "gather"),
+    (None, {"mesh": True}, "gather"),
+    (None, {"head_dim": 64}, "gather"),
+    (None, {"kv_dtype": "int8"}, "gather"),
+    # an explicit value wins, on any platform
+    ("xla", {}, "gather"),
+    ("pallas", {"platform": "cpu"}, "paged_kernel"),
+    ("pallas", {"platform": "cpu", "head_dim": 64, "kv_dtype": "int8"},
+     "paged_kernel"),
+    # ... except where the kernel has no form
+    ("pallas", {"pool_kinds": ("latent",)}, "no latent-attention form"),
+    ("pallas", {"routed": True}, "no latent-attention form"),
+    ("pallas", {"mesh": True}, "tensor-parallel"),
+])
+def test_decode_path_is_a_function_of_what_the_engine_sees(kernel, seen,
+                                                           want):
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
+        resolve_decode_path,
+    )
+
+    if want in ("paged_kernel", "gather"):
+        assert resolve_decode_path(kernel, **{**_SEEN, **seen}) == want
+    else:
+        with pytest.raises(ValueError, match=want):
+            resolve_decode_path(kernel, **{**_SEEN, **seen})
+
+
+def test_llama_default_heads_take_the_kernel_inside_its_byte_bound():
+    """The chooser does not ask how many KV heads a pool has: the
+    kernel's compute block is bounded in bytes, so ``LlamaConfig``'s own
+    default (Llama-2-7B's 32 KV heads of 128, no grouping), which the
+    first form of PR 29's kernel could not compile for the v5e (16.6 MiB
+    of VMEM), takes the kernel with a block of 128 keys, 1 MiB a pool."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.llama import (
+        LlamaConfig,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_attention import (
+        block_pages,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
+        resolve_decode_path,
+    )
+
+    cfg = LlamaConfig()
+    kv_heads, head_dim = cfg.num_kv_heads, cfg.hidden_size // cfg.num_heads
+    assert (kv_heads, head_dim) == (32, 128)
+    assert resolve_decode_path(
+        None, **{**_SEEN, "head_dim": head_dim}) == "paged_kernel"
+    pages = block_pages(16, kv_heads, head_dim, 2, 4000)
+    assert pages == 8 and pages * 16 * kv_heads * head_dim * 2 == 1 << 20
+
+
+@pytest.mark.parametrize("kernel,path", [(None, "gather"),
+                                         ("pallas", "paged_kernel")])
+def test_decode_path_is_on_the_span_and_in_the_stats(gpt2_setup, tmp_path,
+                                                     kernel, path):
+    """The path a decode step took is one line of a traced run away: the
+    ``serve/decode_step`` span's arguments, ``stats()`` with the steps
+    counted by path, ``slo_summary()`` and the ``report`` event. Left to
+    choose on a CPU the engine keeps the gather path."""
+    import json
+
+    from huggingface_sagemaker_tensorflow_distributed_tpu import obs
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
+        ServeEngine,
+    )
+
+    cfg, model, params = gpt2_setup
+    rng = np.random.RandomState(13)
+    obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
+    try:
+        eng = ServeEngine(model, params, num_slots=2, block_size=4,
+                          num_blocks=40, prefill_chunk=8, max_model_len=32,
+                          kernel=kernel)
+        for n in (5, 9):
+            eng.submit(rng.randint(1, 120, (n,)).astype(np.int32), 4)
+        eng.run()
+        st = eng.stats()
+        obs.flush()
+        with open(tmp_path / "telemetry" / "events.jsonl") as f:
+            events = [json.loads(line) for line in f]
+    finally:
+        obs.reset(enabled=False)
+    other = ({"paged_kernel", "gather"} - {path}).pop()
+    assert eng.decode_path == st.decode_path == path
+    assert st.decode_steps_by_path == {path: st.decode_steps, other: 0}
+    assert st.decode_steps > 0
+    assert eng.slo_summary()["decode_path"] == path
+    spans = [e for e in events if e.get("type") == "span"
+             and e["name"] == "serve/decode_step"]
+    assert spans and all(e["args"]["decode_path"] == path for e in spans)
+    report = [e for e in events if e.get("event") == "report"][-1]
+    assert report["decode_path"] == path
+    assert report["kernel"] == ("pallas" if path == "paged_kernel"
+                                else "xla")
+
+
 def test_parse_kernel_and_kv_dtype_knobs(monkeypatch):
     from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
         ENV_KERNEL,
@@ -1334,7 +1443,9 @@ def test_parse_kernel_and_kv_dtype_knobs(monkeypatch):
         parse_kv_dtype,
     )
 
-    assert parse_kernel(None) == "xla"
+    monkeypatch.delenv(ENV_KERNEL, raising=False)
+    assert parse_kernel(None) is None          # unset: the engine chooses
+    assert parse_kernel("XLA") == "xla"
     assert parse_kernel("PALLAS") == "pallas"
     monkeypatch.setenv(ENV_KERNEL, "pallas")
     assert parse_kernel(None) == "pallas"
