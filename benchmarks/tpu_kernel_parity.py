@@ -43,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 
 FAILED = []
-SUBSET = False  # --subset: ~2-min spot-check embedded in bench.py headline
 
 
 def check(name: str, got, want, atol: float, rtol: float = 1e-3) -> None:
@@ -96,8 +95,6 @@ def flash_parity() -> None:
              ("causal", 8, 12, 12, 512, 64, True, None, 1e-2),
              ("windowed", 8, 12, 12, 512, 64, True, 128, 1e-2),
              ("gqa", 2, 16, 4, 1024, 128, True, None, 5e-2))
-    if SUBSET:
-        cases = cases[1:2]
     for tag, B, H, Hkv, S, D, causal, window, ceiling in cases:
         rep = H // Hkv
         scale = D ** -0.5
@@ -242,8 +239,6 @@ def vocab_ce_parity() -> None:
 
     shapes = (("gpt2-vocab", (2048, 768, 50257)),
               ("mlm-bias-aug", (2048, 896, 30522)))
-    if SUBSET:
-        shapes = shapes[:1]
     for label, (n_tok, h_dim, vocab) in shapes:
         rng = np.random.RandomState(1)
         hidden = jnp.asarray(rng.randn(n_tok, h_dim), jnp.float32) * 0.1
@@ -278,8 +273,6 @@ def vocab_ce_parity() -> None:
         for name, a, b in zip(("dh", "dw"), gf, gx):
             check(f"vocab-ce {name} ({label})", a, b, atol=1e-5)
 
-        if SUBSET:
-            continue
         # smoothed variant (eps=0.1): the running logit-sum + smoothed
         # target paths in the kernel, vs the explicit decomposition
         eps = 0.1
@@ -308,8 +301,6 @@ def vocab_ce_parity() -> None:
 
 
 def main() -> None:
-    global SUBSET
-    SUBSET = "--subset" in sys.argv[1:]
     from huggingface_sagemaker_tensorflow_distributed_tpu.parallel import (
         enable_compilation_cache,
         require_accelerator,
@@ -325,8 +316,7 @@ def main() -> None:
               "off-TPU, so these checks prove nothing about Mosaic")
     flash_parity()
     vocab_ce_parity()
-    if not SUBSET:
-        paged_parity()
+    paged_parity()
     if FAILED:
         print(f"FAILED: {FAILED}")
         sys.exit(1)

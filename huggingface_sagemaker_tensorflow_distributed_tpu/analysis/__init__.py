@@ -2,7 +2,7 @@
 
 A stdlib-only, jax-less ``ast``-based lint pass that enforces the
 engine's hardest-won invariants *in the diff* instead of minutes later
-in a bench gate: compile flatness (jit static-key hygiene), the
+in a test: compile flatness (jit static-key hygiene), the
 dispatch-ahead hot path's no-new-host-sync contract, the jax-free
 tooling zones (``obs``/``obsctl``/this package itself), the typed
 telemetry schema, the README env-knob registry, and BlockManager

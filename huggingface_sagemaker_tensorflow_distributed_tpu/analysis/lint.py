@@ -38,7 +38,7 @@ from typing import Iterable, Optional, Sequence
 PACKAGE = "huggingface_sagemaker_tensorflow_distributed_tpu"
 
 #: repo-root entries linted alongside the package
-DEFAULT_EXTRAS = ("scripts", "bench.py", "launch.py")
+DEFAULT_EXTRAS = ("scripts", "launch.py")
 
 #: rule id for pragma-hygiene findings (not suppressible — a pragma
 #: cannot vouch for another pragma)
@@ -278,8 +278,8 @@ def _make_source(rel: str, text: str,
 
 
 def _module_name(rel: str) -> Optional[str]:
-    """Dotted module name for package files; repo scripts and bench.py
-    get a ``scripts.x`` / top-level name so intra-scripts imports
+    """Dotted module name for package files; repo scripts get a
+    ``scripts.x`` / top-level name so intra-scripts imports
     resolve too."""
     if not rel.endswith(".py"):
         return None

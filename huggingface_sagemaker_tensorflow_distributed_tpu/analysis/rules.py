@@ -1,5 +1,5 @@
 """graftlint rules R1–R7: the repo-specific invariants, each grounded
-in a property a bench gate or poison test already hunts dynamically —
+in a property a test already hunts dynamically —
 the rule catches the regression in the diff instead.
 
 Every rule is a pure function ``Project -> list[Finding]`` registered
@@ -431,7 +431,7 @@ R7_ROOT = f"{PACKAGE}/serve/policy.py"
 def check_r7(project: Project) -> list[Finding]:
     """Same reachability walk as R1, rooted at the admission-policy
     module. The policy layer's contract is that admission ordering is
-    pure host arithmetic — the bench's compile-flatness gate (ZERO new
+    pure host arithmetic — the compile-flatness test (ZERO new
     compiled variants under ``policy=slo``) rests on no jax reaching
     the module at import time, and the router's rate limiter must keep
     importing on jax-less driver boxes."""
@@ -502,7 +502,7 @@ RULES: dict[str, Rule] = {
         "R7", "policy-jax-free",
         "serve/policy.py and everything it imports stay jax-free — "
         "admission ordering is host arithmetic, which is what makes "
-        "the policy bench's zero-new-compiles gate and jax-less "
+        "the zero-new-compiles test under policy=slo and jax-less "
         "driver-box imports hold.",
         check_r7),
 }

@@ -445,8 +445,8 @@ class TrainConfig:
     def bucket_sizes(self, max_len: int) -> Optional[list[int]]:
         """The length-bucket width schedule ``bucket_multiple`` implies:
         multiples of it up to ``max_len`` (validated sp-divisible in
-        ``__post_init__``). None when bucketing is off. Shared by
-        ``scripts/train.py`` and ``bench.py --buckets``."""
+        ``__post_init__``). None when bucketing is off. Read by
+        ``scripts/train.py``."""
         if not self.bucket_multiple:
             return None
         return list(range(self.bucket_multiple, max_len + 1,

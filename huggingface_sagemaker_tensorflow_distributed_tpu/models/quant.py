@@ -13,7 +13,7 @@ through HBM.
 The reference has no quantization story at all (its serving path is
 ``save_pretrained`` and whatever the downstream endpoint does,
 reference ``scripts/train.py:182-183``); this is in-repo and targeted
-at the decode bench (``bench.py --generate``).
+at decoding, where the weights' bytes bound the step.
 
 Scope: the dense kernels of the generating families — GPT-2
 (qkv / attn_out / fc_in / fc_out), T5 (query/key/value/attention_out,

@@ -1,8 +1,8 @@
 """Analytic model-FLOPs accounting + per-chip peak table → MFU.
 
-One convention, used by the trainer's per-window MFU series and every
-bench line: matmul FLOPs only, training = 3× forward (fwd + dX + dW),
-remat recompute excluded, embedding lookups / layernorms / softmax
+One convention, used by the trainer's per-window MFU series: matmul
+FLOPs only, training = 3× forward (fwd + dX + dW), remat recompute
+excluded, embedding lookups / layernorms / softmax
 excluded (~2% at the shapes we ship). "Model FLOPs" counts USEFUL work:
 multiply by REAL token counts (attention-mask sums — which is what makes
 the figure packing-aware), not padded widths; padded tokens burn
@@ -12,7 +12,7 @@ they should.
 Peak FLOP/s comes from a device_kind substring table (public bf16
 spec-sheet numbers) with an ``HSTD_PEAK_TFLOPS`` env override for chips
 the table doesn't know — including CPU runs, where the override is the
-only way to get a meaningful MFU at all (the bench acceptance uses it).
+only way to get an MFU at all.
 
 Stdlib-only by construction: ``obs`` (and the report tooling built on
 it) must import without jax. Callers pass ``device_kind`` as a string.
@@ -91,9 +91,7 @@ def _layer_fwd_flops_per_token(hidden: int, intermediate: int, kv_len: int,
 def _moe_extra_fwd(cfg, args: dict, layers: int) -> float:
     """Routed-MoE forward surcharge per token: every ``moe_every``-th
     layer runs ``expert_top_k`` expert MLPs instead of one dense MLP —
-    (top_k − 1) extra MLP units on ``layers // moe_every`` layers (the
-    same convention as ``benchmarks/mixtral_train_bench.py``, reused so
-    the trainer's MFU and the bench line cannot drift)."""
+    (top_k − 1) extra MLP units on ``layers // moe_every`` layers."""
     experts = int(getattr(cfg, "num_experts", 0) or 0)
     if not experts:
         return 0.0

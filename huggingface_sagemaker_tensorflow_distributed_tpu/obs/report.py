@@ -126,12 +126,6 @@ def _host_section(events: list[dict]) -> dict:
         "alerts": sum(1 for e in events if e["type"] == "alert"),
         "anomalies": sum(1 for e in events if e["type"] == "anomaly"),
     }
-    # graftlint static-analysis count (`bench.py --lint` mirrors its
-    # stdout line into this series when a sink is configured): the
-    # LAST sample is the run's figure — a lint pass reruns supersede
-    lint_series = _metric_series(events, "lint/findings")
-    if lint_series:
-        section["lint_findings"] = int(lint_series[-1])
     return section
 
 
@@ -321,12 +315,6 @@ DIFF_METRICS: dict[str, tuple[int, str]] = {
     "compile_cum_s": (+1, "ratio"),
     "compile_count": (+1, "count"),
     "anomalies": (+1, "count"),
-    # graftlint unsuppressed-finding count (`bench.py --lint`): the
-    # healthy tree holds this at ZERO, so the shared count rule (any
-    # increase regresses, worse UP) makes a new unannotated invariant
-    # violation a CI regression even when nobody reran the linter's
-    # own test tier
-    "lint_findings": (+1, "count"),
     "serve_ttft_p50_s": (+1, "ratio"),
     "serve_ttft_p99_s": (+1, "ratio"),
     "serve_e2e_p50_s": (+1, "ratio"),
@@ -475,9 +463,6 @@ def _report_scalars(report: dict) -> dict:
             for h in hosts), 6) if hosts else None,
         "anomalies": len(report.get("anomaly_index", [])),
     }
-    lint_vals = [h["lint_findings"] for h in hosts
-                 if isinstance(h.get("lint_findings"), int)]
-    out["lint_findings"] = sum(lint_vals) if lint_vals else None
     for key in ("ttft_p50_s", "ttft_p99_s", "e2e_p50_s", "e2e_p99_s",
                 "decode_tokens_per_sec", "preemptions",
                 "acceptance_rate", "cache_hit_rate",

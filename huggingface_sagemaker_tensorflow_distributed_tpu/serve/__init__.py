@@ -9,7 +9,7 @@ per-slot seeded sampling).
   prefill budget.
 - :mod:`~.engine` — the jitted prefill/decode step functions (compiled
   per gather bucket) and the driving loop (``scripts/serve.py`` is the
-  CLI; ``bench.py --serve`` the measurement).
+  CLI; ``chipbench/`` the measurement).
 - :mod:`~.router` — N engine replicas behind one facade (ISSUE 14):
   round-robin / least-loaded / prefix-affinity / length-aware
   placement, replica drain/restart with requeue-to-siblings and live

@@ -1893,8 +1893,8 @@ class ServeEngine:
         first of them only) and EVERY bucket's decode (or speculative
         draft/verify) step on null work so the serving loop itself
         never traces: the compile-tracker event count stays flat
-        across steady state (the bench asserts decode compiles ≤
-        #buckets). With ``sampled=True`` the per-slot-sampling variants
+        across steady state (``tests/test_serve_gates.py`` holds it at 0
+        per mechanism). With ``sampled=True`` the per-slot-sampling variants
         of every step are ALSO precompiled — without it they compile
         lazily on the first sampled batch (one mid-serve stall per
         bucket), which latency-sensitive sampled traffic should not

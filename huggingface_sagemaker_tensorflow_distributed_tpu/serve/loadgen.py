@@ -26,7 +26,7 @@ Three layers, in the house determinism style:
   iterations on a deterministic virtual clock (``tick_s`` of virtual
   time per fleet step): token streams, backlog integers, and the
   driver's own attainment/miss-attribution accounting are exact across
-  reruns — what the tier-1 gates and bench line run on a shared CPU.
+  reruns — what the tier-1 tests run on a shared CPU.
   ``wall`` honors arrival times with real sleeps and threads
   ``arrival_s``/``slo`` into :meth:`~.engine.ServeEngine.submit`, so
   the engine stamps real verdicts into the telemetry stream — the mode

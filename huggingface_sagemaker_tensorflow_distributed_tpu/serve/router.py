@@ -569,8 +569,7 @@ class Router:
         """Warm every replica. Replicas share the module-level jitted
         step families (identical static keys), so replica 0 compiles
         the ladder and the rest reuse it — N replicas cost one bucket
-        ladder of compiles, not N (the per-replica compile-flatness
-        gate the bench enforces)."""
+        ladder of compiles, not N."""
         for eng in self.engines:
             eng.warmup(sampled=sampled)
 

@@ -4,7 +4,7 @@ compile-flatness, host-sync, and contract invariants.
 
 Usage::
 
-    # lint the whole tree (package + scripts/ + bench.py + launch.py)
+    # lint the whole tree (package + scripts/ + launch.py)
     python scripts/graftlint.py
     # specific files, machine-readable output
     python scripts/graftlint.py huggingface_sagemaker_tensorflow_distributed_tpu/serve/engine.py --format json

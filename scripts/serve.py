@@ -173,14 +173,22 @@ def load_trace(args, vocab: int):
                               int(row.get("max_new_tokens",
                                           args.max_new_tokens)), kw))
         return trace
-    from benchmarks.serve_bench import make_trace
+    return [(p, m, dict(defaults)) for p, m in _synthetic_trace(args, vocab)]
 
+
+def _synthetic_trace(args, vocab: int):
+    """[(prompt_ids, max_new_tokens)] drawn from ``--seed``: every
+    fourth request wants a long continuation, the rest a short one."""
     rng = np.random.RandomState(args.seed)
-    base = make_trace(rng, args.requests, vocab, args.prompt_min,
-                      args.prompt_max, (4, max(4, args.max_new_tokens // 4)),
-                      (args.max_new_tokens // 2, args.max_new_tokens),
-                      long_every=4)
-    return [(p, m, dict(defaults)) for p, m in base]
+    short_new = (4, max(4, args.max_new_tokens // 4))
+    long_new = (args.max_new_tokens // 2, args.max_new_tokens)
+    trace = []
+    for i in range(args.requests):
+        p = int(rng.randint(args.prompt_min, args.prompt_max + 1))
+        lo, hi = long_new if i % 4 == 3 else short_new
+        prompt = rng.randint(1, vocab, (p,)).astype(np.int32)
+        trace.append((prompt, int(rng.randint(lo, hi + 1))))
+    return trace
 
 
 def main() -> None:

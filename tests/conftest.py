@@ -55,6 +55,29 @@ def rng():
     return np.random.RandomState(0)
 
 
+@pytest.fixture(scope="module")
+def gpt2_setup():
+    """``(cfg, model, params)`` of the tiny GPT-2 (2 layers, 2 heads of
+    16, vocabulary 128, float32) the serving tests share."""
+    import jax.numpy as jnp
+
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.auto import (
+        init_params,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.gpt2 import (
+        Gpt2Config,
+        Gpt2LMHeadModel,
+    )
+
+    cfg = Gpt2Config(vocab_size=128, hidden_size=32, num_layers=2,
+                     num_heads=2, intermediate_size=64,
+                     max_position_embeddings=128, hidden_dropout=0.0,
+                     embd_dropout=0.0, attention_dropout=0.0,
+                     eos_token_id=127, pad_token_id=0, dtype=jnp.float32)
+    model = Gpt2LMHeadModel(cfg)
+    return cfg, model, init_params(model, cfg, seed=0)
+
+
 # --- fast/full tiering -------------------------------------------------------
 # The full suite needs ~11-14 min on a 1-core box; time-budgeted gates
 # run `pytest -m "not slow"` (<2 min). Every test measured >=4s on the
@@ -118,7 +141,6 @@ _SLOW_TESTS = {
     "test_t5.py::test_forward_shapes_finite",
     "test_deberta.py::test_deberta_training_learns",
     "test_deberta.py::test_deberta_v3_style_seq_cls_parity",
-    "test_mesh_bench.py::test_profile_breakdown_finds_collectives",
     "test_pallas_attention.py::test_flash_causal_matches_xla_fwd_and_bwd",
     "test_pallas_attention.py::test_flash_qkv_grads_match_xla",
     "test_rtd.py::test_rtd_training_learns",
@@ -216,23 +238,18 @@ _SLOW_TESTS = {
     # flush) stay tier-1 per the same precedent
     "test_serve.py::test_overlap_sampled_bitwise_and_spec_rejection_storm",
     # ISSUE 13 offset: the TP exactness gates (bucket boundary +
-    # forced preemption, ~16s of SPMD compiles) and the bench smoke's
-    # deterministic TP capacity line join tier-1, paid for by moving
-    # (a) the TP byte-budget unit test — its 2x-admission claim is
-    # tier-1-gated by the bench smoke's admission-depth assert — and
-    # (b) the 18s sampled-SPECULATIVE seed-determinism composition
-    # (the sampled-plain and speculative-greedy determinism gates
-    # each stay tier-1; only their composition moves)
-    "test_serve.py::test_tp_engine_kv_pool_bytes_budget_doubles_admission",
+    # forced preemption, ~16s of SPMD compiles) join tier-1, paid for
+    # by moving the 18s sampled-SPECULATIVE seed-determinism
+    # composition (the sampled-plain and speculative-greedy
+    # determinism gates each stay tier-1; only their composition moves)
     "test_serve.py::test_sampled_speculative_serve_seed_deterministic_across_preemption",
     # ISSUE 14 budget: the heaviest router composition (affinity x
     # speculative x prefix-cache across replicas, 7s) is slow-marked
     # per the PR 10/12 precedent, and the sampled-bitwise x placement
-    # composition (2.6s) moves with it as the offset for the smoke
-    # bench's new router line — the core router gates (token identity
-    # per policy, drain-mid-trace identity + conservation, the
-    # randomized drain/restart schedule, the replicas=1 byte-identity
-    # allowlist) stay tier-1
+    # composition (2.6s) moves with it — the core router gates (token
+    # identity per policy, drain-mid-trace identity + conservation,
+    # the randomized drain/restart schedule, the replicas=1
+    # byte-identity allowlist) stay tier-1
     "test_router.py::test_router_affinity_speculative_prefix_composition",
     "test_router.py::test_router_sampled_streams_bitwise_identical_across_placement",
     # ISSUE 15: the retained runtime no-jax SUBPROCESS smokes — the

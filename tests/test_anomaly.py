@@ -3,7 +3,7 @@ loss and a forced step-time spike each produce EXACTLY ONE rate-limited
 ``anomaly`` event, a flight-recorder dump, and (when enabled) a
 profiler trace directory; healthy runs produce ZERO anomaly events.
 Plus the straggler-alert satellite, the flight ring's bound, the
-FLOPs/peak table, and the bench NaN-exit contract.
+FLOPs/peak table.
 """
 
 import json
@@ -190,7 +190,7 @@ def test_train_flops_per_token_families():
     mlm = flops.train_flops_per_token(enc, "mlm", 512)
     assert mlm < full
     # sparse MoE: routed surcharge applies to layers//moe_every layers
-    # only — the mixtral bench convention (top_k-1 extra MLPs each)
+    # only (top_k-1 extra MLPs each)
     moe = LlamaConfig(vocab_size=1000, hidden_size=64, num_layers=4,
                       num_heads=4, num_kv_heads=2, intermediate_size=128,
                       num_experts=8, expert_top_k=2, moe_every=2)
@@ -311,32 +311,3 @@ def test_nan_loss_fit_triggers_anomaly_and_flight_dump(obs_dir, tmp_path):
     assert [f for f in os.listdir(obs_dir) if f.startswith("flight_")]
     for a in anoms:
         assert obs.validate_event(a) == []
-
-
-# -- bench divergence exit ---------------------------------------------------
-
-def test_bench_child_exits_nonzero_on_nan_loss(obs_dir):
-    import bench
-
-    det = obs.anomalies()
-    bench._check_divergence_exit()          # healthy: no exit
-    det.observe_loss(0, float("nan"))
-    with pytest.raises(SystemExit) as exc:
-        bench._check_divergence_exit()
-    assert exc.value.code == bench.ANOMALY_RC
-
-
-def test_bench_emit_carries_mfu_and_anomalies(obs_dir, monkeypatch, capsys):
-    import bench
-
-    monkeypatch.setenv(flops.ENV_PEAK, "100.0")
-    bench.emit("m", 10.0, 1.0, flops_per_sample=1e9)
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["mfu"] == pytest.approx(10.0 * 1e9 / 1e12 / 100.0)
-    assert 0 < line["mfu"] <= 1.0
-    assert line["anomalies"] == 0
-    obs.anomalies().observe_loss(0, float("nan"))
-    bench.emit("m", 10.0, 1.0)
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["anomalies"] == 1 and line["anomaly_kinds"] == {
-        "nan_loss": 1}

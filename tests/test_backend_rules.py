@@ -16,7 +16,7 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.parallel import (
 
 ROOT = chip_smoke.ROOT
 ENTRY_POINTS = ("scripts/train.py", "scripts/serve.py", "scripts/predict.py",
-                "bench.py", "chip_smoke.py")
+                "chip_smoke.py")
 
 
 def _tracked_python():

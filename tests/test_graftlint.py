@@ -620,15 +620,3 @@ def test_linter_itself_runs_without_jax():
                           stderr=subprocess.STDOUT, text=True)
     assert proc.returncode == 0, proc.stdout
     assert json.loads(proc.stdout)["total"] == 0
-
-def test_bench_lint_stage_emits_zero_count_line():
-    """`bench.py --lint` emits the lint_findings count line obsctl
-    diff gates (zero-baseline count metric, worse UP)."""
-    proc = subprocess.run([sys.executable, "bench.py", "--lint"],
-                          stdout=subprocess.PIPE,
-                          stderr=subprocess.PIPE, text=True, cwd=_REPO)
-    assert proc.returncode == 0, proc.stderr
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["metric"] == "lint_findings"
-    assert line["value"] == 0
-    assert line["worse_direction"] == "up"

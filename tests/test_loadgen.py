@@ -1,8 +1,8 @@
 """Open-loop load generation + SLO contract (ISSUE 16,
 ``serve/loadgen.py`` + the engine's deadline fields): the arrival
 generators are pure functions of their seeds, the virtual-clock driver
-is byte-replayable (the property the bench's determinism gates rest
-on — including Router ``replicas=1`` vs the bare engine), overload is
+is byte-replayable (including Router ``replicas=1`` vs the bare
+engine), overload is
 queue-attributed, and every new telemetry field stays ABSENT on a
 closed-loop run (the byte-identity contract for pre-16 streams)."""
 
@@ -134,27 +134,6 @@ def test_parse_slo_specs_and_env(monkeypatch):
 
 # -- the virtual-clock driver on the real engine -----------------------------
 
-@pytest.fixture(scope="module")
-def gpt2_setup():
-    import jax.numpy as jnp
-
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.auto import (
-        init_params,
-    )
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.gpt2 import (
-        Gpt2Config,
-        Gpt2LMHeadModel,
-    )
-
-    cfg = Gpt2Config(vocab_size=128, hidden_size=32, num_layers=2,
-                     num_heads=2, intermediate_size=64,
-                     max_position_embeddings=128, hidden_dropout=0.0,
-                     embd_dropout=0.0, attention_dropout=0.0,
-                     eos_token_id=127, pad_token_id=0, dtype=jnp.float32)
-    model = Gpt2LMHeadModel(cfg)
-    return cfg, model, init_params(model, cfg, seed=0)
-
-
 _ENGINE_KW = dict(num_slots=2, block_size=8, num_blocks=17,
                   prefill_chunk=8, max_model_len=64, timeline="off")
 
@@ -261,7 +240,8 @@ def test_virtual_overload_is_queue_dominant(gpt2_setup):
     """At a rate far past fleet capacity the driver's verdict must be
     the open-loop signature: attainment strictly below 1 with QUEUE the
     dominant miss phase, and the engine's deterministic backlog peak
-    above zero."""
+    above the underload run's, with fewer deadline-meeting tokens than
+    that run (the same requests at 50 a second under the same SLO)."""
     from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
         ServeEngine,
     )
@@ -277,7 +257,14 @@ def test_virtual_overload_is_queue_dominant(gpt2_setup):
     assert s["dominant_miss_phase"] == "queue"
     assert s["miss_phases"]["queue"] == s["slo_missed"]
     assert set(s["group_slo_attainment"]) == {"a", "b"}
-    assert eng.slo_summary()["arrival_backlog_peak"] > 0
+    calm_eng = ServeEngine(model, params, **_ENGINE_KW)
+    calm = OpenLoopDriver(calm_eng, _schedule(), clock="virtual",
+                          tick_s=0.001, slo=SloSpec(ttft_s=0.003),
+                          process="poisson", rate=50.0)
+    calm.run()
+    assert (eng.slo_summary()["arrival_backlog_peak"]
+            > calm_eng.slo_summary()["arrival_backlog_peak"])
+    assert 0 < s["goodput_tokens"] < calm.summary()["goodput_tokens"]
 
 
 def test_driver_is_one_shot(gpt2_setup):

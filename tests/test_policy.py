@@ -411,27 +411,6 @@ def test_schema_types_policy_riders_and_rejects_mistypes():
 # -- engine + router end-to-end (jax) ----------------------------------------
 
 
-@pytest.fixture(scope="module")
-def gpt2_setup():
-    import jax.numpy as jnp
-
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.auto import (
-        init_params,
-    )
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.gpt2 import (
-        Gpt2Config,
-        Gpt2LMHeadModel,
-    )
-
-    cfg = Gpt2Config(vocab_size=128, hidden_size=32, num_layers=2,
-                     num_heads=2, intermediate_size=64,
-                     max_position_embeddings=128, hidden_dropout=0.0,
-                     embd_dropout=0.0, attention_dropout=0.0,
-                     eos_token_id=127, pad_token_id=0, dtype=jnp.float32)
-    model = Gpt2LMHeadModel(cfg)
-    return cfg, model, init_params(model, cfg, seed=0)
-
-
 _ENGINE_KW = dict(num_slots=2, block_size=4, num_blocks=20,
                   prefill_chunk=8, max_model_len=64)
 

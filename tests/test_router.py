@@ -18,27 +18,6 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.serve.router import (
 )
 
 
-@pytest.fixture(scope="module")
-def gpt2_setup():
-    import jax.numpy as jnp
-
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.auto import (
-        init_params,
-    )
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.gpt2 import (
-        Gpt2Config,
-        Gpt2LMHeadModel,
-    )
-
-    cfg = Gpt2Config(vocab_size=128, hidden_size=32, num_layers=2,
-                     num_heads=2, intermediate_size=64,
-                     max_position_embeddings=128, hidden_dropout=0.0,
-                     embd_dropout=0.0, attention_dropout=0.0,
-                     eos_token_id=127, pad_token_id=0, dtype=jnp.float32)
-    model = Gpt2LMHeadModel(cfg)
-    return cfg, model, init_params(model, cfg, seed=0)
-
-
 _KW = dict(num_slots=2, block_size=4, num_blocks=40, prefill_chunk=8,
            max_model_len=64)
 
@@ -84,6 +63,8 @@ def test_router_output_token_identical_to_single_engine(gpt2_setup,
     assert slo["replicas"] == 2 and slo["placement"] == placement
     assert slo["requests"] == len(trace)
     assert slo["replica_load_imbalance"] >= 1.0
+    if placement == "least_loaded":
+        assert slo["replica_load_imbalance"] <= 1.5
 
 
 def test_router_sampled_streams_bitwise_identical_across_placement(
@@ -228,6 +209,35 @@ def test_router_affinity_keeps_families_sticky_and_aged(gpt2_setup):
     assert len(tiny._affinity) <= 2
     assert ([list(tiny.output_ids(q)) for q in treqs]
             == [list(router.output_ids(q)) for q in reqs])
+
+
+def test_router_affinity_hit_rate_at_least_round_robins(gpt2_setup):
+    """Three templated families interleaved, each template primed:
+    round-robin splits every family over both replicas and each pays
+    its own cold miss; affinity keeps a family where it was primed.
+    Same tokens, and a higher prefix hit rate."""
+    _cfg, model, params = gpt2_setup
+    rng = np.random.RandomState(8)
+    prefixes = [rng.randint(1, 120, (16,)).astype(np.int32)
+                for _ in range(3)]
+    trace = [(np.concatenate(
+        [prefixes[f], rng.randint(1, 120, (int(rng.randint(2, 6)),))
+         .astype(np.int32)]), int(rng.randint(3, 6)))
+        for _ in range(3) for f in range(3)]
+    outs, hit = {}, {}
+    for placement in ("round_robin", "affinity"):
+        router = Router(model, params, replicas=2, placement=placement,
+                        prefix_cache=True, **_KW)
+        for p in prefixes:
+            router.submit(p, 1)
+        router.run()
+        reqs = [router.submit(p, m) for p, m in trace]
+        router.run()
+        outs[placement] = [list(router.output_ids(q)) for q in reqs]
+        hit[placement] = (sum(q.prefix_cached_tokens for q in reqs)
+                          / sum(q.prefix_prompt_tokens for q in reqs))
+    assert outs["affinity"] == outs["round_robin"]
+    assert hit["affinity"] > hit["round_robin"] > 0
 
 
 def test_router_affinity_imbalance_bound_falls_back_to_load(gpt2_setup):

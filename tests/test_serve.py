@@ -194,27 +194,6 @@ def test_paged_attention_matches_contiguous():
 
 # -- engine exactness (the gate) ---------------------------------------------
 
-@pytest.fixture(scope="module")
-def gpt2_setup():
-    import jax.numpy as jnp
-
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.auto import (
-        init_params,
-    )
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.gpt2 import (
-        Gpt2Config,
-        Gpt2LMHeadModel,
-    )
-
-    cfg = Gpt2Config(vocab_size=128, hidden_size=32, num_layers=2,
-                     num_heads=2, intermediate_size=64,
-                     max_position_embeddings=128, hidden_dropout=0.0,
-                     embd_dropout=0.0, attention_dropout=0.0,
-                     eos_token_id=127, pad_token_id=0, dtype=jnp.float32)
-    model = Gpt2LMHeadModel(cfg)
-    return cfg, model, init_params(model, cfg, seed=0)
-
-
 def _reference(model, params, prompt, max_new, eos):
     """Per-request generate_causal greedy, trimmed EOS-inclusive."""
     import jax.numpy as jnp
@@ -1706,37 +1685,6 @@ def test_tp_engine_token_exact_across_bucket_boundary(gpt2_setup,
     assert slo["kv_pool_bytes_per_device"] == eng.blocks.pool_bytes
 
 
-def test_tp_warmup_covers_the_device_token_feed(gpt2_setup, devices8,
-                                                tmp_path):
-    """Under a mesh the dispatch-ahead loop's feed — the previous
-    step's device-resident tokens — is an executable of its own (a
-    committed array's sharding is part of the key; first seen as two
-    5 s compiles mid-serve on four chips). Warm-up compiles it: the
-    run, across both gather buckets, compiles nothing."""
-    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
-        ServeEngine,
-    )
-
-    cfg, model, params = gpt2_setup
-    obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
-    try:
-        tracker = obs.compile_tracker()
-        eng = ServeEngine(model, params, mesh=2, num_slots=3, block_size=4,
-                          num_blocks=40, prefill_chunk=8, max_model_len=32,
-                          gather_buckets=[16, 32])
-        eng.warmup()
-        count0 = tracker.count
-        rng = np.random.RandomState(31)
-        for p, m in [(5, 9), (15, 6), (12, 8)]:
-            eng.submit(rng.randint(1, 120, (p,)).astype(np.int32), m)
-        eng.run()
-        assert eng.bucket_switches > 0
-        assert tracker.count == count0, \
-            "TP serving compiled after warm-up"
-    finally:
-        obs.reset()
-
-
 def test_tp_engine_token_exact_under_forced_preemption(gpt2_setup,
                                                        devices8):
     """The ISSUE 13 tier-1 exactness gate, half 2: recompute
@@ -1762,41 +1710,6 @@ def test_tp_engine_token_exact_under_forced_preemption(gpt2_setup,
                        num_blocks=10, prefill_chunk=8, max_model_len=32)
     assert eng.blocks.token_bytes * 2 == lone.blocks.token_bytes
     assert eng.blocks.pool_bytes * 2 == lone.blocks.pool_bytes
-
-
-def test_tp_engine_kv_pool_bytes_budget_doubles_admission(gpt2_setup,
-                                                          devices8):
-    """The capacity story the bench line gates, as a unit test: on the
-    SAME per-device ``kv_pool_bytes`` budget a TP=2 engine holds ~2x
-    the blocks and keeps ~2x the requests concurrently resident
-    (uniform block need: prompts pad to one chunk, continuations fit
-    the padded span)."""
-    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.engine import (
-        ServeEngine,
-    )
-
-    cfg, model, params = gpt2_setup
-    rng = np.random.RandomState(32)
-    trace = [(rng.randint(1, 120, (6,)).astype(np.int32), 2)
-             for _ in range(8)]
-    lone = ServeEngine(model, params, num_slots=1, block_size=4,
-                       num_blocks=4, prefill_chunk=8, max_model_len=32)
-    budget = 4 * 4 * lone.blocks.token_bytes     # 4 blocks single-device
-    kw = dict(num_slots=6, block_size=4, num_blocks=999, prefill_chunk=8,
-              max_model_len=32, kv_pool_bytes=budget)
-    engs = {}
-    for mesh in (None, 2):
-        eng = ServeEngine(model, params, mesh=mesh, **kw)
-        reqs = [eng.submit(p, m) for p, m in trace]
-        eng.run()
-        engs[mesh] = (eng, [[int(t) for t in eng.output_ids(r)]
-                            for r in reqs])
-    base, tp = engs[None][0], engs[2][0]
-    assert engs[2][1] == engs[None][1]
-    assert base.blocks.num_blocks == 5 and tp.blocks.num_blocks == 9
-    assert tp.peak_resident >= 2 * base.peak_resident
-    # same per-device budget — the pools cost each chip the same bytes
-    assert tp.blocks.pool_bytes <= budget + tp.blocks.block_bytes
 
 
 @pytest.mark.slow

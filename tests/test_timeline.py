@@ -1,9 +1,9 @@
 """Request-lifecycle tracing (ISSUE 10): the tier-1 decomposition gate
 — a REAL engine run's ``request_timeline`` events must decompose each
 request's e2e into queue + prefill + decode + preempted + overhead
-within tolerance, with the accounting entirely host-side (the serve
-bench's compile-flatness gates run with the timeline on, so zero new
-compiled variants is enforced there) — plus the jax-less
+within tolerance, with the accounting entirely host-side
+(``test_serve_gates.py::test_no_compile_after_warmup`` runs with the
+timeline on, so zero new compiled variants is enforced there) — plus the jax-less
 ``obs/timeline.py`` tooling: sliding-window percentile estimator,
 incremental tail follower (never re-reads the prefix), deterministic
 ``obsctl timeline|slo`` output, and the poisoned-jax import contract

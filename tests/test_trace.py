@@ -306,27 +306,6 @@ def test_schema_rejects_mistyped_trace_context_fields():
 
 # -- the real thing: forced mid-decode migration ------------------------------
 
-@pytest.fixture(scope="module")
-def gpt2_setup():
-    import jax.numpy as jnp
-
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.auto import (
-        init_params,
-    )
-    from huggingface_sagemaker_tensorflow_distributed_tpu.models.gpt2 import (
-        Gpt2Config,
-        Gpt2LMHeadModel,
-    )
-
-    cfg = Gpt2Config(vocab_size=128, hidden_size=32, num_layers=2,
-                     num_heads=2, intermediate_size=64,
-                     max_position_embeddings=128, hidden_dropout=0.0,
-                     embd_dropout=0.0, attention_dropout=0.0,
-                     eos_token_id=127, pad_token_id=0, dtype=jnp.float32)
-    model = Gpt2LMHeadModel(cfg)
-    return cfg, model, init_params(model, cfg, seed=0)
-
-
 def test_engine_mid_decode_migration_stitches_complete(gpt2_setup,
                                                        tmp_path):
     """End to end on real engines: a request migrated MID-DECODE
@@ -389,6 +368,60 @@ def test_engine_mid_decode_migration_stitches_complete(gpt2_setup,
     assert mig["from_replica"] == 0 and mig["to_replica"] == 1
     # the stitched ttft matches the engine's own stamp to the rounding
     assert tr["ttft_s"] == pytest.approx(req.ttft_s, abs=1e-6)
+
+
+def test_disaggregated_fleet_stitches_every_request_and_reconciles(
+        gpt2_setup, tmp_path):
+    """A traced prefill:1,decode:1 fleet under an open-loop schedule:
+    every request stitches into ONE complete trace with its migration
+    in it, every trace passes the cross-hop decomposition check, and the
+    stitcher's prefill-side TTFT percentiles equal the router's own
+    per-role report to the digit (same nearest-rank percentile, same
+    rounding: any daylight is an attribution bug)."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu import obs
+    from huggingface_sagemaker_tensorflow_distributed_tpu.obs.timeline import (
+        load_events,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.loadgen import (
+        OpenLoopDriver,
+        SloSpec,
+        make_schedule,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve.router import (
+        Router,
+    )
+
+    cfg, model, params = gpt2_setup
+    n_req, rate = 8, 300.0
+    schedule = make_schedule(
+        n_req, 120, process="poisson", rate=rate, seed=11, prompt_lo=4,
+        prompt_hi=16, new_lo=3, new_hi=6, eos_token_id=cfg.eos_token_id)
+    out = tmp_path / "fleet"
+    obs.reset(out_dir=str(out), enabled=True)
+    try:
+        router = Router(model, params, roles={"prefill": 1, "decode": 1},
+                        num_slots=2, block_size=4, num_blocks=40,
+                        prefill_chunk=8, max_model_len=64,
+                        gather_buckets=[16, 32], prefix_cache=False,
+                        timeline="on", trace="on")
+        finished = OpenLoopDriver(
+            router, schedule, clock="virtual", tick_s=0.001,
+            slo=SloSpec(ttft_s=0.02), process="poisson", rate=rate).run()
+        obs.flush()
+    finally:
+        obs.reset()
+    assert len(finished) == n_req
+    events, errors = load_events([str(out)])
+    assert not errors
+    traces = collect_traces(events)
+    fleet = fleet_summary(traces)
+    assert len(traces) == fleet["complete_traces"] == n_req
+    assert fleet["trace_stitch_failures"] == 0
+    assert all(len(t["migrates"]) >= 1 for t in traces)
+    assert [p for t in traces for p in check_trace(t)] == []
+    by_router = router.slo_summary()["per_role"]["prefill"]
+    for key in ("ttft_p50_s", "ttft_p95_s", "ttft_p99_s"):
+        assert fleet["per_role"]["prefill"][key] == by_router[key] > 0
 
 
 def test_engine_untraced_stream_carries_no_trace_fields(gpt2_setup,
