@@ -27,7 +27,21 @@ What differs from the Llama layout (``models/llama.py``, whose
     latent rows are expanded to per-head ``k_nope | v`` through
     ``W_kvb``, a block of keys at a time under a running softmax, so that
     neither the expanded keys nor the scores of a long bucket ever exist
-    whole, and the blocks of a bucket past the context are skipped.
+    whole, and the blocks of a bucket past the context are skipped. That
+    walk has two forms of its own (:func:`expanded_form`, again a pure
+    function of what the code sees): on a TPU, outside a mesh, at shapes
+    that tile (the serving engine's three prefill programs) ONE fused
+    kernel in which a block's scores never leave the chip
+    (``ops/pallas_latent_attention.py``, :func:`attend_expanded_kernel`);
+    everywhere else (a CPU, ``model.init``'s 8 tokens, a ragged plain
+    forward, the Trainer's mesh) the XLA loop (:func:`attend_expanded`),
+    which is also the kernel's reference in the tests. A
+    four-row layer-call of 512 queries at start 4,096 is 17.1 ms by the
+    kernel (41% of the MXU's peak) against 46.7 ms by the loop (15%:
+    each block's ``f32[4,128,512,512]`` scores go to HBM and back), at
+    start 7,680 26.7 against 81.9, and where the rows' contexts differ
+    (512 to 7,680) 15.3 against 81.9, the kernel skipping a row at a
+    time (my chip runs, PR 32; PERF.md 6).
 
 - **RoPE** rotates ``q_pe`` and ``k_pe`` only. The published model
   rotates adjacent pairs ``(2i, 2i+1)``; this code does what HF's port
@@ -59,6 +73,7 @@ this family; ROADMAP).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -80,9 +95,16 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.models.moe import (
     dropless_experts,
     group_limited_gate,
 )
+from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+    pallas_latent_attention,
+)
+from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
+    maybe_current_mesh,
+)
 
 NEG_INF = -1e9
-# keys a chunk of queries attends at a time (attend_expanded)
+# keys a chunk of queries attends at a time: the XLA loop's block
+# (attend_expanded) and the fused kernel's query and key block
 KEY_BLOCK = 512
 # the collection the routed layers sow their per-expert pair counts into
 MOE_STATS = "moe_stats"
@@ -98,8 +120,39 @@ def latent_path(q_len: int) -> str:
     """``absorbed`` | ``expanded``: the form a call with ``q_len`` queries
     a row attends by. A chunk attends expanded: measured on the v5e at
     the 8,192 bucket, that form was the faster on every dispatch
-    (PERF.md 6, PR 28)."""
+    (PERF.md 6, PR 28). HOW an expanded call walks its keys is
+    :func:`expanded_form`'s answer."""
     return "absorbed" if q_len == 1 else "expanded"
+
+
+EXPANDED_FORMS = ("kernel", "xla_loop")
+
+
+def expanded_form(cfg, q_len: int, width: int, *, platform: str,
+                  mesh: bool) -> str:
+    """``kernel`` | ``xla_loop``: how an expanded call of ``q_len``
+    queries a row over ``width`` keys attends. A pure function of what
+    the code can see, and nothing a user sets: the fused kernel
+    (``ops/pallas_latent_attention.py``) on a TPU, outside a mesh (a
+    Mosaic kernel is not partitioned by GSPMD, and the Trainer's steps
+    always trace under one), for shapes it has blocks for (queries and
+    keys whole multiples of ``KEY_BLOCK``, the widths whole lane tiles,
+    bf16 or float32: ``pallas_latent_attention.takes``); the XLA loop
+    (:func:`attend_expanded`) for everything else: a CPU, the 8-token
+    ``model.init``, a ragged plain forward."""
+    fits = pallas_latent_attention.takes(
+        q_len=q_len, width=width, block=KEY_BLOCK, rank=cfg.kv_lora_rank,
+        nope=cfg.qk_nope_head_dim, v_dim=cfg.v_head_dim,
+        row=latent_width(cfg), dtype=cfg.dtype)
+    return "kernel" if platform == "tpu" and not mesh and fits \
+        else "xla_loop"
+
+
+def _seen_form(cfg, q_len: int, width: int) -> str:
+    """:func:`expanded_form` of what this process sees: the default
+    backend's platform and whether an ambient mesh is set."""
+    return expanded_form(cfg, q_len, width, platform=jax.default_backend(),
+                         mesh=maybe_current_mesh() is not None)
 
 
 @dataclass(frozen=True)
@@ -294,7 +347,13 @@ def attend_expanded(q_nope, q_pe, latent, bias, w_kvb, *, rank: int,
     ``f32[4,16,512,8192]`` took 100 ms, forty of them 4.15 s a dispatch,
     my chip run, PR 28.) A block no query may see (every key past the
     longest row's context, in a bucket wider than the context) is
-    skipped: its terms are exactly zero."""
+    skipped: its terms are exactly zero.
+
+    This is the XLA form: what runs on a CPU, under a mesh and at shapes
+    the fused kernel has no blocks for (:func:`expanded_form`), and the
+    reference :func:`attend_expanded_kernel` is held to. On the v5e a
+    block's scores cost it five passes over HBM, 47 ms a four-row
+    layer-call at start 4,096 where the kernel takes 17 (PR 32)."""
     B, W = latent.shape[:2]
     S, H = q_nope.shape[1:3]
     nope, rot = q_nope.shape[-1], q_pe.shape[-1]
@@ -330,6 +389,52 @@ def attend_expanded(q_nope, q_pe, latent, bias, w_kvb, *, rank: int,
         jnp.zeros((B, H, S), jnp.float32),
         jnp.zeros((B, S, H, w_kvb.shape[-1] - nope), jnp.float32)))
     return (acc / l.transpose(0, 2, 1)[..., None]).astype(latent.dtype)
+
+
+def mask_bias(start, q_len: int, key_valid, width: int):
+    """The additive float32 mask ``[B, q_len, width]`` of a step: key
+    ``j`` is seen by query ``s`` of row ``b`` iff ``j <= start[b] + s``
+    and ``key_valid[b, j]`` (None: every key)."""
+    q_slot = start[:, None] + jnp.arange(q_len)[None, :]
+    seen = jnp.arange(width)[None, None, :] <= q_slot[:, :, None]
+    if key_valid is not None:
+        seen = seen & key_valid[:, None, :]
+    return jnp.where(seen, 0.0, NEG_INF).astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def attend_expanded_kernel(q_nope, q_pe, latent, w_kvb, start, key_valid,
+                           rank: int, scale: float, block: int):
+    """:func:`attend_expanded` through the fused kernel
+    (``ops/pallas_latent_attention.py``): the same function of the same
+    operands, with the mask given as what it is made from (``start``
+    [B], ``key_valid`` [B, W] or None: :func:`mask_bias`) so that no
+    ``[B, S, W]`` bias is built, and no score leaves the chip. The kernel
+    has no backward pass of its own: a gradient recomputes through the
+    XLA form."""
+    return pallas_latent_attention.latent_prefill_attention(
+        q_nope, q_pe, latent, w_kvb, start, key_valid, rank=rank,
+        scale=scale, block=block)
+
+
+def _kernel_fwd(q_nope, q_pe, latent, w_kvb, start, key_valid, rank, scale,
+                block):
+    out = attend_expanded_kernel(q_nope, q_pe, latent, w_kvb, start,
+                                 key_valid, rank, scale, block)
+    return out, (q_nope, q_pe, latent, w_kvb, start, key_valid)
+
+
+def _kernel_bwd(rank, scale, block, res, g):
+    q_nope, q_pe, latent, w_kvb, start, key_valid = res
+    bias = mask_bias(start, q_nope.shape[1], key_valid, latent.shape[1])
+    _, vjp = jax.vjp(
+        lambda qn, qp, lat, w: attend_expanded(
+            qn, qp, lat, bias, w, rank=rank, scale=scale, key_block=block),
+        q_nope, q_pe, latent, w_kvb)
+    return (*vjp(g), None, None)
+
+
+attend_expanded_kernel.defvjp(_kernel_fwd, _kernel_bwd)
 
 
 def attend_absorbed(q_nope, q_pe, latent, bias, w_kvb, *, rank: int,
@@ -393,7 +498,7 @@ class DeepseekV2Attention(nn.Module):
             "kv_b_proj", nn.initializers.normal(cfg.initializer_range),
             (rank, H, nope + vd), cfg.param_dtype).astype(cfg.dtype)
 
-        q_slot = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        start = jnp.zeros((B,), jnp.int32)      # each row's first query
         if decode:
             is_init = self.has_variable("cache", "cached_latent")
             cached = self.variable("cache", "cached_latent", jnp.zeros,
@@ -403,23 +508,26 @@ class DeepseekV2Attention(nn.Module):
             if is_init:
                 # the write protocol of models/llama.py::write_kv_cache:
                 # each row's new latent rows at its own write index
-                cur = cache_index.value
+                start = cache_index.value
                 buf = jax.vmap(
                     lambda b, new, i: lax.dynamic_update_slice(
-                        b, new, (0, i, 0)))(cached.value, latent[:, None], cur)
+                        b, new, (0, i, 0)))(cached.value, latent[:, None],
+                                            start)
                 cached.value = buf
-                cache_index.value = cur + S
+                cache_index.value = start + S
                 latent = buf[:, 0]                          # [B, W, width]
-                q_slot = cur[:, None] + q_slot
         W = latent.shape[1]
-        seen = jnp.arange(W)[None, None, :] <= q_slot[:, :, None]
-        if key_valid is not None:
-            seen = seen & key_valid[:, None, :]
-        bias = jnp.where(seen, 0.0, NEG_INF).astype(jnp.float32)
-        attend = (attend_absorbed if latent_path(S) == "absorbed"
-                  else attend_expanded)
-        ctx = attend(q_nope, q_pe, latent, bias, w_kvb, rank=rank,
-                     scale=cfg.softmax_scale)
+        path = latent_path(S)
+        if path == "expanded" and _seen_form(cfg, S, W) == "kernel":
+            ctx = attend_expanded_kernel(
+                q_nope, q_pe, latent, w_kvb, start, key_valid, rank,
+                cfg.softmax_scale, KEY_BLOCK)
+        else:
+            attend = (attend_absorbed if path == "absorbed"
+                      else attend_expanded)
+            ctx = attend(q_nope, q_pe, latent,
+                         mask_bias(start, S, key_valid, W), w_kvb,
+                         rank=rank, scale=cfg.softmax_scale)
         return _dense(cfg, cfg.hidden_size, "o_proj")(
             ctx.reshape(B, S, H * vd))
 
@@ -530,6 +638,12 @@ class DeepseekV2ForCausalLM(nn.Module):
     config: DeepseekV2Config
 
     latent_path = staticmethod(latent_path)
+    EXPANDED_FORMS = EXPANDED_FORMS
+
+    def expanded_form(self, q_len: int, width: int) -> str:
+        """:func:`expanded_form` for a call of this model in this
+        process: what the serving engine writes beside ``latent_path``."""
+        return _seen_form(self.config, q_len, width)
 
     def setup(self):
         cfg = self.config

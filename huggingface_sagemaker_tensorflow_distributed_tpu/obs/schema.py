@@ -217,6 +217,10 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               "moe_expert_load_max": (list,),
               "moe_expert_load_mean": (list,),
               "latent_bytes_per_token": (int,),
+              # a latent-attention model's report (ISSUE 32): prefill
+              # dispatches by how the expanded form attended
+              # ({"kernel": n, "xla_loop": n})
+              "prefill_dispatches_by_form": (dict,),
               "dispatch_s": _NUM,
               "fetch_wait_s": _NUM,
               "commit_s": _NUM,

@@ -1151,6 +1151,10 @@ class EngineStats(NamedTuple):
     latent_bytes_per_token: Optional[int] = None
     moe_pairs: int = 0
     moe_pairs_held: int = 0
+    # how the prefill dispatches of a latent-attention model attended
+    # (ISSUE 32): dispatches by the model's ``expanded_form`` (None: a
+    # K/V cache)
+    prefill_dispatches_by_form: Optional[dict] = None
 
 
 class ServeEngine:
@@ -1213,7 +1217,12 @@ class ServeEngine:
     taken is ``decode_path`` in ``stats()``, the ``report`` event and
     the ``serve/decode_step`` span's arguments. Speculative
     engines keep draft/verify on the assembled path either way (the
-    kernel is single-token). ``kv_cache_dtype`` (None reads
+    kernel is single-token). A latent-attention model chooses for its
+    own prefill chunks (its ``expanded_form``: a fused kernel on a TPU,
+    an XLA loop elsewhere); the engine writes the answer beside
+    ``latent_path`` on each ``serve/prefill_chunk`` span and counts the
+    dispatches by form (``prefill_dispatches_by_form`` in ``stats()``,
+    ``slo_summary()`` and the ``report`` event). ``kv_cache_dtype`` (None reads
     ``HSTD_SERVE_KV_DTYPE``, default = the model config's own value)
     selects pool storage; ``int8`` rebuilds the serving module around
     ``kv_cache_dtype='int8'`` (params untouched) and the exactness
@@ -1563,6 +1572,8 @@ class ServeEngine:
         self.decode_steps = 0
         self.prefill_chunks = 0
         self.prefill_dispatches = 0
+        self.prefill_dispatches_by_form = (
+            dict.fromkeys(model.EXPANDED_FORMS, 0) if self._latent else None)
         self.prefill_keys_needed = 0
         self.prefill_keys_attended = 0
         self.tokens_generated = 0
@@ -2091,6 +2102,8 @@ class ServeEngine:
         # latent cache / routed experts: absent for any other model
         if self._latent:
             out["latent_bytes_per_token"] = self.blocks.token_bytes
+            out["prefill_dispatches_by_form"] = dict(
+                self.prefill_dispatches_by_form)
         if self._routes:
             self._moe_resolve(everything=True)
             out["moe_pairs"] = self.moe_pairs
@@ -2246,6 +2259,9 @@ class ServeEngine:
                                     if self._latent else None),
             moe_pairs=self.moe_pairs,
             moe_pairs_held=self.moe_pairs_held,
+            prefill_dispatches_by_form=(
+                dict(self.prefill_dispatches_by_form)
+                if self._latent else None),
             decode_steps=self.decode_steps,
             prefill_chunks=self.prefill_chunks,
             prefill_dispatches=self.prefill_dispatches,
@@ -2538,13 +2554,19 @@ class ServeEngine:
         self.iterations += 1
 
 
-    def _latent_kw(self, q_len: int) -> dict:
+    def _latent_kw(self, q_len: int, width: Optional[int] = None) -> dict:
         """``{"latent_path": "absorbed" | "expanded"}`` for a dispatch
         of ``q_len`` queries a row of a latent-attention model (the
-        model's own rule on the shape), ``{}`` for any other model."""
+        model's own rule on the shape), and for an expanded one over
+        ``width`` keys ``"expanded_form": "kernel" | "xla_loop"`` (the
+        model's own rule on the shapes and the backend, the one its
+        trace follows); ``{}`` for any other model."""
         if not self._latent:
             return {}
-        return {"latent_path": self.model.latent_path(q_len)}
+        out = {"latent_path": self.model.latent_path(q_len)}
+        if out["latent_path"] == "expanded":
+            out["expanded_form"] = self.model.expanded_form(q_len, width)
+        return out
 
     def _moe_dispatched(self, moe: list, tokens: int, decode: bool) -> None:
         """Keep a dispatch's routed counts (a device array, NOT fetched
@@ -2734,9 +2756,10 @@ class ServeEngine:
                         keys[i] = self._keys[req.rid]
                         folds[i] = self._generated(req)
         t0 = self._lap(_STAGE)
+        latent_kw = self._latent_kw(C, width)
         with obs.span("serve/prefill_chunk",
                       {"chunks": len(slots), "rows": G, "width": width,
-                       **self._latent_kw(C)}
+                       **latent_kw}
                       if obs.has_sink() else None):
             tok, self._pools, *moe = self._prefill_fn(
                 self.model, self.params, self._pools, chunks, tables,
@@ -2766,6 +2789,8 @@ class ServeEngine:
             self.prefill_keys_needed += slot.prefill_pos
         self.prefill_chunks += len(slots)
         self.prefill_dispatches += 1
+        if latent_kw:
+            self.prefill_dispatches_by_form[latent_kw["expanded_form"]] += 1
         self.prefill_keys_attended += G * width
         if finals:
             self._lap(_COMMIT)

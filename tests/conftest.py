@@ -267,3 +267,47 @@ def pytest_collection_modifyitems(items):
         base_id = f"{fname}::{item.originalname or item.name}"
         if fname in _SLOW_TESTS or base_id in _SLOW_TESTS:
             item.add_marker(pytest.mark.slow)
+
+
+@pytest.fixture(scope="module")
+def lane_latent():
+    """``(cfg, model, params)`` of a tiny DeepSeek-V2 whose latent widths
+    are whole lane tiles (rank 128, heads of 128 + 64 rotary, values of
+    128): shapes the fused latent-prefill kernel has blocks for."""
+    import jax.numpy as jnp
+
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models import (
+        deepseek_v2 as D,
+    )
+
+    cfg = D.DeepseekV2Config(
+        vocab_size=128, hidden_size=32, num_layers=2, num_heads=2,
+        q_lora_rank=12, kv_lora_rank=128, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=48,
+        moe_intermediate_size=16, n_routed_experts=8, n_shared_experts=1,
+        num_experts_per_tok=2, n_group=4, topk_group=2, experts_held=4,
+        max_position_embeddings=128, eos_token_id=127, pad_token_id=0)
+    model = D.DeepseekV2ForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.ones((1, 8), jnp.int32))["params"]
+    return cfg, model, params
+
+
+@pytest.fixture
+def seen_as_tpu(monkeypatch):
+    """DeepSeek-V2's chooser answering for a TPU with blocks of 8, the
+    kernel itself in interpret mode: steered here, in the tests (the
+    program has no option for it). The jitted steps are keyed on the
+    model, not on what it sees, so a case starts and ends with empty
+    caches."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models import (
+        deepseek_v2 as D,
+    )
+
+    jax.clear_caches()
+    monkeypatch.setattr(D, "KEY_BLOCK", 8)
+    monkeypatch.setattr(
+        D, "_seen_form", lambda cfg, q_len, width: D.expanded_form(
+            cfg, q_len, width, platform="tpu", mesh=False))
+    yield
+    jax.clear_caches()

@@ -1,0 +1,284 @@
+"""The fused latent-prefill kernel (``ops/pallas_latent_attention.py``,
+ISSUE 32) against the XLA key-block loop it replaces on a TPU
+(``models/deepseek_v2.py::attend_expanded``), in interpret mode on the
+CPU: the same mathematics, the same mask from ``start`` and
+``key_valid``, and the skip a row at a time. Then the chooser
+(``expanded_form``: a pure function of platform, mesh, shapes and type),
+the model's plain forward by either form, the gradient through the
+kernel form, and the kernel compiled for the v5e at the published
+widths."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from huggingface_sagemaker_tensorflow_distributed_tpu.models import (
+    deepseek_v2 as D,
+)
+from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+    pallas_latent_attention as K,
+)
+
+# small widths, a row of one lane tile as the cache stores it
+RANK, NOPE, ROT, VD, ROW, HEADS, BLOCK = 16, 16, 8, 16, 128, 2, 16
+TOL = {jnp.float32: 2e-6, jnp.bfloat16: 2e-2}
+
+
+def _operands(dtype, B, S, W, seed=3):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q_nope = jax.random.normal(k[0], (B, S, HEADS, NOPE)).astype(dtype)
+    q_pe = jax.random.normal(k[1], (B, S, HEADS, ROT)).astype(dtype)
+    latent = jnp.pad(jax.random.normal(k[2], (B, W, RANK + ROT)),
+                     [(0, 0), (0, 0), (0, ROW - RANK - ROT)]).astype(dtype)
+    w = (jax.random.normal(k[3], (RANK, HEADS, NOPE + VD)) * 0.3).astype(dtype)
+    return q_nope, q_pe, latent, w
+
+
+def _both(ops, start, valid, S, W, **kw):
+    """(XLA loop, kernel[, steps]) on the same operands and mask."""
+    q_nope, q_pe, latent, w = ops
+    start = jnp.asarray(start, jnp.int32)
+    want = D.attend_expanded(
+        q_nope, q_pe, latent, D.mask_bias(start, S, valid, W), w,
+        rank=RANK, scale=0.2, key_block=BLOCK)
+    got = K.latent_prefill_attention(
+        q_nope, q_pe, latent, w, start, valid, rank=RANK, scale=0.2,
+        block=BLOCK, **kw)
+    return want, got
+
+
+def _engine_valid(start, S, W):
+    """``_prefill_chunk``'s key_valid: the keys below ``start + C``."""
+    return jnp.arange(W)[None, :] < jnp.asarray(start)[:, None] + S
+
+
+# (rows' starts, queries, bucket, key_valid): every edge of a dispatch
+_CASES = {
+    "one_row": ([32], 16, 64, "engine"),
+    "four_rows_four_starts": ([0, 16, 32, 48], 16, 64, "engine"),
+    # a prefix-cache hit leaves a start that is no multiple of the block:
+    # two blocks straddle the diagonal
+    "starts_off_the_block": ([4, 20, 41, 7], 16, 64, "engine"),
+    # a prompt's last chunk: real tokens, then a pad tail whose keys sit
+    # behind every real query; nothing but the mask says so
+    "ragged_last_chunk": ([16, 48], 16, 64, "engine"),
+    # a pad row rides start 0 against the null table's zeros
+    "pad_row": ([48, 0], 16, 64, "pad"),
+    "bucket_four_times_the_context": ([0, 16], 16, 128, "engine"),
+    # the plain forward: no cache, start 0, two query blocks, a padding
+    # mask with holes
+    "plain_forward_with_holes": ([0, 0], 32, 32, "holes"),
+    "plain_forward_no_mask": ([0, 0, 0], 32, 32, None),
+}
+
+
+@pytest.mark.parametrize("dtype,q_rows", [
+    (jnp.float32, None), (jnp.bfloat16, None), (jnp.float32, 8)],
+    ids=["float32", "bfloat16", "float32_two_passes"])
+@pytest.mark.parametrize("case", list(_CASES))
+def test_kernel_is_the_xla_form(case, dtype, q_rows, monkeypatch):
+    """``q_rows``: the queries a pass of a step attends, cut to half a
+    block so that a step makes two passes as it does on the chip (512
+    queries, 256 a pass)."""
+    if q_rows:
+        monkeypatch.setattr(K, "_Q_ROWS", q_rows)
+    start, S, W, mask = _CASES[case]
+    ops = _operands(dtype, len(start), S, W)
+    if mask is None:
+        valid = None
+    elif mask == "holes":
+        holes = jax.random.uniform(jax.random.PRNGKey(9), (len(start), W))
+        valid = (holes > 0.3).at[:, 0].set(True)
+    else:
+        valid = _engine_valid(start, S, W)
+        if mask == "pad":
+            ops = (*ops[:2], ops[2].at[-1].set(0), ops[3])
+    want, got = _both(ops, start, valid, S, W)
+    assert got.shape == want.shape == (len(start), S, HEADS, VD)
+    assert got.dtype == want.dtype == dtype
+    err = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max()
+    assert float(err) <= TOL[dtype], f"{case}: {float(err)}"
+
+
+@pytest.mark.parametrize("start,S,W", [
+    ([0, 16, 32, 48], 16, 128),        # a bucket twice to eight times
+    ([4, 20, 41, 7], 16, 128),         # the context, starts off the block
+    ([0, 40], 32, 128),                # two query blocks a row
+], ids=["aligned", "off_the_block", "two_query_blocks"])
+def test_a_row_runs_the_blocks_its_own_context_needs(start, S, W):
+    """The skip is per row: a (row, head, query block) runs the key
+    blocks up to its last query's and no other, whatever the bucket and
+    whatever the other rows hold (the XLA form runs a block when any row
+    sees it). What is skipped is not read either: NaN rows behind every
+    row's last needed block leave the result finite and unchanged."""
+    q_nope, q_pe, latent, w = _operands(jnp.float32, len(start), S, W)
+    valid = _engine_valid(start, S, W)
+    want, (got, steps) = _both((q_nope, q_pe, latent, w), start, valid, S, W,
+                               count_steps=True)
+    nq = S // BLOCK
+    expect = [[(s + (iq + 1) * BLOCK - 1) // BLOCK + 1 for iq in range(nq)]
+              for s in start]
+    assert steps.shape == (len(start), HEADS, nq)
+    for h in range(HEADS):
+        assert np.asarray(steps[:, h]).tolist() == expect
+    assert int(steps.sum()) < len(start) * HEADS * nq * (W // BLOCK)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    past = (jnp.arange(W)[None, :]
+            >= jnp.asarray([e[-1] * BLOCK for e in expect])[:, None])
+    poisoned = jnp.where(past[:, :, None], jnp.nan, latent)
+    again = K.latent_prefill_attention(
+        q_nope, q_pe, poisoned, w, jnp.asarray(start, jnp.int32), valid,
+        rank=RANK, scale=0.2, block=BLOCK)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+def test_shapes_off_the_block_are_refused_by_name():
+    ops = _operands(jnp.float32, 1, 12, 64)
+    with pytest.raises(ValueError, match="by the XLA form"):
+        K.latent_prefill_attention(*ops, rank=RANK, scale=0.2, block=BLOCK)
+
+
+def test_a_gradient_through_the_kernel_form_is_the_xla_forms():
+    """The kernel has no backward pass; ``attend_expanded_kernel``
+    recomputes one through the XLA form, so a plain forward that took the
+    kernel still trains."""
+    q_nope, q_pe, latent, w = _operands(jnp.float32, 2, 16, 32)
+    start = jnp.zeros((2,), jnp.int32)
+    valid = jnp.ones((2, 32), bool).at[1, 5].set(False)
+    bias = D.mask_bias(start, 16, valid, 32)
+
+    def loss(form):
+        def f(qn, qp, lat, w):
+            out = (D.attend_expanded_kernel(qn, qp, lat, w, start, valid,
+                                            RANK, 0.2, BLOCK)
+                   if form == "kernel" else
+                   D.attend_expanded(qn, qp, lat, bias, w, rank=RANK,
+                                     scale=0.2, key_block=BLOCK))
+            return (out ** 2).sum()
+        return jax.grad(f, argnums=(0, 1, 2, 3))(q_nope, q_pe, latent, w)
+
+    for a, b in zip(loss("kernel"), loss("xla_loop")):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+# -- the chooser ---------------------------------------------------------------
+
+_PUBLISHED = D.DeepseekV2Config(dtype=jnp.bfloat16)
+_SEEN = dict(cfg=_PUBLISHED, q_len=512, width=8192, platform="tpu",
+             mesh=False)
+
+
+@pytest.mark.parametrize("seen,want", [
+    # the engine's three prefill programs on the chip ...
+    ({}, "kernel"),
+    ({"width": 2048}, "kernel"),
+    ({"q_len": 1024, "width": 1024}, "kernel"),
+    ({"cfg": dataclasses.replace(_PUBLISHED, dtype=jnp.float32)}, "kernel"),
+    # ... and the XLA loop for anything else the code can see
+    ({"platform": "cpu"}, "xla_loop"),
+    ({"platform": "gpu"}, "xla_loop"),
+    ({"mesh": True}, "xla_loop"),
+    ({"q_len": 8, "width": 8}, "xla_loop"),            # model.init's dummy
+    ({"q_len": 40, "width": 8192}, "xla_loop"),
+    ({"width": 8192 + 256}, "xla_loop"),
+    ({"cfg": dataclasses.replace(_PUBLISHED, dtype=jnp.float16)},
+     "xla_loop"),
+    ({"cfg": dataclasses.replace(_PUBLISHED, kv_lora_rank=448)},
+     "xla_loop"),
+    ({"cfg": dataclasses.replace(_PUBLISHED, qk_nope_head_dim=64)},
+     "xla_loop"),
+    ({"cfg": dataclasses.replace(_PUBLISHED, v_head_dim=192)}, "xla_loop"),
+])
+def test_the_form_is_a_function_of_what_the_code_sees(seen, want):
+    kw = {**_SEEN, **seen}
+    assert D.expanded_form(kw.pop("cfg"), kw.pop("q_len"), kw.pop("width"),
+                           **kw) == want
+    assert want in D.EXPANDED_FORMS
+
+
+def test_on_this_cpu_every_call_is_the_xla_loop():
+    model = D.DeepseekV2ForCausalLM(_PUBLISHED)
+    assert model.expanded_form(512, 8192) == "xla_loop"
+    # and the path stays two-valued: HOW a chunk attends is another answer
+    assert {D.latent_path(n) for n in (1, 2, 512)} == {"absorbed",
+                                                       "expanded"}
+
+
+# -- the model by either form --------------------------------------------------
+
+@pytest.mark.parametrize("masked", [False, True], ids=["whole", "padded"])
+def test_the_plain_forward_by_the_kernel_is_the_one_by_the_loop(
+        lane_latent, seen_as_tpu, monkeypatch, masked):
+    _cfg, model, params = lane_latent
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 3, 120)
+    mask = (jnp.ones((2, 24), jnp.int32).at[1, 17:].set(0) if masked
+            else None)
+    assert model.expanded_form(24, 24) == "kernel"
+    assert model.expanded_form(20, 20) == "xla_loop"
+    ran = []
+    kernel = K.latent_prefill_attention
+    monkeypatch.setattr(K, "latent_prefill_attention",
+                        lambda *a, **kw: ran.append(1) or kernel(*a, **kw))
+    forward = lambda: jax.jit(                              # noqa: E731
+        lambda p: model.apply({"params": p}, ids, mask))(params)
+    got = forward()
+    assert len(ran) == 2                      # once a layer
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert model.expanded_form(24, 24) == "xla_loop"
+    want = forward()
+    real = np.ones((2, 24), bool) if mask is None else np.asarray(mask) > 0
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real],
+                               atol=2e-5)
+
+
+# -- compiled for the chip -----------------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows,width,dtype", [
+    (4, 8192, jnp.bfloat16), (1, 2048, jnp.bfloat16), (4, 2048, jnp.float32)],
+    ids=["g4_w8192_bf16", "g1_w2048_bf16", "g4_w2048_f32"])
+def test_the_kernel_compiles_for_the_v5e_at_published_widths(
+        one_chip, rows, width, dtype):
+    """Mosaic's tile rules and the 16 MiB of VMEM a kernel gets are not
+    seen in interpret mode: compile doc-sat's dispatches for the chip
+    that is described, not attached. No score block is among the
+    program's buffers."""
+    cfg = dataclasses.replace(_PUBLISHED, dtype=dtype)
+    H, C = cfg.num_heads, 512
+    assert D.expanded_form(cfg, C, width, platform="tpu",
+                           mesh=False) == "kernel"
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda qn, qp, lat, w, st, kv: K.latent_prefill_attention(
+            qn, qp, lat, w, st, kv, rank=cfg.kv_lora_rank,
+            scale=cfg.softmax_scale, block=D.KEY_BLOCK, interpret=False)
+    ).lower(sds((rows, C, H, cfg.qk_nope_head_dim)),
+            sds((rows, C, H, cfg.qk_rope_head_dim)),
+            sds((rows, width, D.latent_width(cfg))),
+            sds((cfg.kv_lora_rank, H, cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            sds((rows,), jnp.int32), sds((rows, width), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[{rows},{H},{C},{D.KEY_BLOCK}]" not in text
+    # queries head-major and padded, and nothing else of any size
+    q_bytes = rows * H * C * 256 * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2.1 * q_bytes

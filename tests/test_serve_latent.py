@@ -326,3 +326,107 @@ def test_an_untraced_run_keeps_and_fetches_no_count(latent):
         eng.submit(p, 6)
     eng.run()
     assert eng._moe_flight == [] and eng.stats().moe_pairs == 0
+
+
+# -- how a prefill dispatch attended (ISSUE 32) --------------------------------
+
+def _form_run(model, params, tmp_path):
+    """Six requests through an engine under a sink: (engine, events)."""
+    trace = [(p, 5) for p in _prompts(11, (5, 23, 40, 17, 33, 9))]
+    obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
+    try:
+        eng = _serve_exact(model, params, trace, num_slots=4, num_blocks=80)
+        obs.flush()
+        return eng, _events(tmp_path)
+    finally:
+        obs.reset(enabled=False)
+
+
+def _assert_every_dispatch_went(form, eng, events):
+    other = (set(D.EXPANDED_FORMS) - {form}).pop()
+    st = eng.stats()
+    assert st.prefill_dispatches > 0
+    assert st.prefill_dispatches_by_form == {form: st.prefill_dispatches,
+                                             other: 0}
+    assert eng.slo_summary()["prefill_dispatches_by_form"][form] \
+        == st.prefill_dispatches
+    spans = [e["args"] for e in events if e.get("type") == "span"
+             and e["name"] == "serve/prefill_chunk"]
+    assert len(spans) == st.prefill_dispatches
+    assert all(a["latent_path"] == "expanded"
+               and a["expanded_form"] == form for a in spans)
+    # a decode step is absorbed: the form is an expanded call's
+    assert all("expanded_form" not in e["args"] for e in events
+               if e.get("type") == "span"
+               and e["name"] == "serve/decode_step")
+    report = [e for e in events if e.get("event") == "report"][-1]
+    assert report["prefill_dispatches_by_form"] == {
+        form: st.prefill_dispatches, other: 0}
+
+
+def test_on_a_cpu_every_prefill_dispatch_is_the_xla_loop(lane_latent,
+                                                         tmp_path):
+    """Shapes the kernel has blocks for (chunks of 8 are whole multiples
+    of nothing the chooser sees: ``KEY_BLOCK`` is 512), on a CPU: the
+    XLA loop, written where ``decode_path`` is."""
+    cfg, model, params = lane_latent
+    eng, events = _form_run(model, params, tmp_path)
+    _assert_every_dispatch_went("xla_loop", eng, events)
+
+
+def test_seen_as_a_tpu_every_prefill_dispatch_is_the_kernel(
+        lane_latent, seen_as_tpu, tmp_path, monkeypatch):
+    """The forced TPU answer: the engine's chunked prefill attends
+    through the fused kernel (interpret mode), row by row at its own
+    ``start`` against both buckets, and stays ``generate_causal`` token
+    for token (``_serve_exact``); span, stats, summary and report say
+    ``kernel``."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+        pallas_latent_attention as K,
+    )
+
+    cfg, model, params = lane_latent
+    traced = []
+    kernel = K.latent_prefill_attention
+    monkeypatch.setattr(
+        K, "latent_prefill_attention", lambda *a, **kw: traced.append(
+            (a[0].shape[0], a[2].shape[1])) or kernel(*a, **kw))
+    eng, events = _form_run(model, params, tmp_path)
+    _assert_every_dispatch_went("kernel", eng, events)
+    # every prefill program traced the kernel, once a layer: [1, C] at
+    # the first bucket, [4, C] at both (generate_causal's own prefills
+    # of 8, 16, 24... tokens take it too)
+    assert {(1, 16), (4, 16), (4, 64)} <= set(traced)
+
+
+def test_a_k_v_engine_has_no_form(tmp_path):
+    cfg, model, params = _llama()
+    eng = ServeEngine(model, params, num_slots=4, num_blocks=30, **GEOM)
+    eng.submit(_prompts(12, (9,))[0], 3)
+    eng.run()
+    assert eng.stats().prefill_dispatches_by_form is None
+    assert "prefill_dispatches_by_form" not in eng.slo_summary()
+
+
+def test_no_compile_after_warmup(latent, tmp_path):
+    """The rule of ``tests/test_serve_gates.py::test_no_compile_after_
+    warmup`` for a latent engine: from empty jit caches, ``warmup()``
+    compiles every program the run needs (three prefill programs, two
+    decode buckets), whichever form the prefill ones attend by."""
+    cfg, model, params = latent
+    jax.clear_caches()
+    obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
+    try:
+        tracker = obs.compile_tracker()
+        eng = ServeEngine(model, params, num_slots=4, num_blocks=80, **GEOM)
+        eng.warmup()
+        count0 = tracker.count
+        assert count0 > 0
+        for p in _prompts(13, (5, 23, 40, 17, 33, 9)):
+            eng.submit(p, 9)
+        eng.run()
+        st = eng.stats()
+        assert st.bucket_switches > 0 and st.prefill_dispatches > 3
+        assert tracker.count == count0, "compiled after warm-up"
+    finally:
+        obs.reset()
