@@ -37,6 +37,7 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.models import (
     electra,
     gpt2,
     llama,
+    olmo_hybrid,
     roberta,
     t5,
 )
@@ -73,6 +74,7 @@ MODEL_REGISTRY: dict[tuple[str, str], Any] = {
     ("gpt2", "causal-lm"): gpt2.Gpt2LMHeadModel,
     ("llama", "causal-lm"): llama.LlamaForCausalLM,
     ("deepseek_v2", "causal-lm"): deepseek_v2.DeepseekV2ForCausalLM,
+    ("olmo_hybrid", "causal-lm"): olmo_hybrid.OlmoHybridForCausalLM,
     ("bert", "mlm"): bert.BertForMaskedLM,
     ("roberta", "mlm"): roberta.RobertaForMaskedLM,
     ("distilbert", "mlm"): distilbert.DistilBertForMaskedLM,
@@ -97,6 +99,7 @@ CONFIG_BUILDERS = {
     "gpt2": gpt2.gpt2_config_from_hf,
     "llama": llama.llama_config_from_hf,
     "deepseek_v2": deepseek_v2.deepseek_v2_config_from_hf,
+    "olmo_hybrid": olmo_hybrid.olmo_hybrid_config_from_hf,
     "deberta-v2": deberta.deberta_config_from_hf,
     "bart": bart.bart_config_from_hf,
     # mBART hardcodes pre-LN + per-stack final LN in its modeling class
@@ -404,7 +407,8 @@ def from_pretrained(
             "layout is supported — silently loading would leave a random "
             "head (HF's own non-legacy forward is broken in transformers "
             "4.57: tie_weights clobbers lm_head.dense)")
-    if family in ("gpt2", "llama", "deepseek_v2") and task != "causal-lm":
+    if (family in ("gpt2", "llama", "deepseek_v2", "olmo_hybrid")
+            and task != "causal-lm"):
         raise ValueError(
             f"{model_name_or_path!r} is a {family} (decoder-only) "
             f"checkpoint; it only supports task='causal-lm', got "
@@ -425,9 +429,10 @@ def from_pretrained(
     params = init_params(model, config, seed=seed)
     has_weights = os.path.exists(os.path.join(model_name_or_path, "model.safetensors")) or \
         os.path.exists(os.path.join(model_name_or_path, "pytorch_model.bin"))
-    if family == "deepseek_v2" and has_weights and not from_scratch:
+    if (family in ("deepseek_v2", "olmo_hybrid") and has_weights
+            and not from_scratch):
         raise ValueError(
-            f"{model_name_or_path!r} holds deepseek_v2 weights: loading a "
+            f"{model_name_or_path!r} holds {family} weights: loading a "
             "published checkpoint of this family is not implemented "
             "(models/convert.py has no mapping for it); pass "
             "from_scratch=True for seeded random weights")

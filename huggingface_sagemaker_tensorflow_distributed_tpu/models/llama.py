@@ -119,6 +119,18 @@ class LlamaConfig:
     moe_every: int = 1
     expert_capacity_factor: float = 1.25
     router_aux_coef: float = 0.02          # router_aux_loss_coef
+    # The Olmo 2 / Olmo 3 block (models/olmo_hybrid.py runs its full
+    # layers through this file). Each default leaves every other
+    # configuration's parameter tree and traced program as they were.
+    # RMSNorm over the WHOLE query and the whole key projection, before
+    # the heads are split (params ``q_norm`` / ``k_norm``)
+    qk_norm: bool = False
+    # False: no rotary embedding at all (position comes from elsewhere)
+    use_rope: bool = True
+    # True: ``h = x + Norm(Attn(x))``, ``out = h + Norm(MLP(h))`` (a norm
+    # after each sub-layer, before the residual add; params
+    # ``post_attn_ln`` / ``post_mlp_ln``) instead of the pre-norm block
+    post_norm: bool = False
 
     @property
     def resolved_head_dim(self) -> int:
@@ -136,6 +148,13 @@ class LlamaConfig:
             raise ValueError(
                 f"unknown kv_cache_dtype {self.kv_cache_dtype!r} "
                 "(fp | int8)")
+        if (self.post_norm or self.qk_norm or not self.use_rope) and (
+                self.num_experts or self.pipeline_stages):
+            raise ValueError(
+                "post_norm / qk_norm / use_rope=False are the dense, "
+                "unpipelined block's options: routed experts and "
+                "pipeline_stages run blocks of their own that do not "
+                "read them")
         if self.num_experts and self.model_type != "mixtral":
             # The only HF layout that can carry the expert bank is
             # Mixtral's: with any other model_type, save_pretrained
@@ -506,15 +525,20 @@ class LlamaAttention(nn.Module):
             return x.reshape(B, S, n_heads, head_dim).transpose(0, 2, 1, 3)
 
         qb = cfg.qkv_bias
-        q = split(_dense(cfg, cfg.num_heads * head_dim, "q_proj",
-                         use_bias=qb)(hidden), cfg.num_heads)
-        k = split(_dense(cfg, cfg.num_kv_heads * head_dim, "k_proj",
-                         use_bias=qb)(hidden), cfg.num_kv_heads)
+        q = _dense(cfg, cfg.num_heads * head_dim, "q_proj",
+                   use_bias=qb)(hidden)
+        k = _dense(cfg, cfg.num_kv_heads * head_dim, "k_proj",
+                   use_bias=qb)(hidden)
+        if cfg.qk_norm:
+            q = LlamaRMSNorm(cfg, name="q_norm")(q)
+            k = LlamaRMSNorm(cfg, name="k_norm")(k)
+        q, k = split(q, cfg.num_heads), split(k, cfg.num_kv_heads)
         v = split(_dense(cfg, cfg.num_kv_heads * head_dim, "v_proj",
                          use_bias=qb)(hidden), cfg.num_kv_heads)
 
-        q = apply_rope(q, rope)
-        k = apply_rope(k, rope)
+        if rope is not None:
+            q = apply_rope(q, rope)
+            k = apply_rope(k, rope)
 
         causal = True
         if decode:
@@ -649,9 +673,16 @@ class LlamaBlock(nn.Module):
         plain, banded = masks if isinstance(masks, tuple) else (masks, None)
         attn_mask = banded if (self.use_window and banded is not None) \
             else plain
-        attn = LlamaAttention(cfg, use_window=self.use_window,
-                              kernel_window=self.kernel_window,
-                              name="self_attn")(
+        attention = LlamaAttention(cfg, use_window=self.use_window,
+                                   kernel_window=self.kernel_window,
+                                   name="self_attn")
+        if cfg.post_norm:
+            attn = attention(hidden, attn_mask, rope, position_ids,
+                             deterministic, decode)
+            hidden = hidden + LlamaRMSNorm(cfg, name="post_attn_ln")(attn)
+            return hidden + LlamaRMSNorm(cfg, name="post_mlp_ln")(
+                LlamaMlp(cfg, name="mlp")(hidden))
+        attn = attention(
             LlamaRMSNorm(cfg, name="input_ln")(hidden), attn_mask,
             rope, position_ids, deterministic, decode)
         hidden = hidden + attn
@@ -735,8 +766,9 @@ class LlamaModel(nn.Module):
             band_mask = jnp.where(band, 0.0, NEG_INF)
             banded_mask = (band_mask if additive_mask is None
                            else additive_mask + band_mask)
-        rope = rope_tables(position_ids, cfg.resolved_head_dim,
-                           cfg.rope_theta, cfg.rope_scaling_dict)
+        rope = (rope_tables(position_ids, cfg.resolved_head_dim,
+                            cfg.rope_theta, cfg.rope_scaling_dict)
+                if cfg.use_rope else None)
 
         x = embed(input_ids)
         if cfg.embed_scale:

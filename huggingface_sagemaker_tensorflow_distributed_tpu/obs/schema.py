@@ -113,7 +113,9 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               "acceptance_rate": _NUM,
               "verify_read_waste_peak": _NUM,
               "verify_read_waste_mean": _NUM,
-              "prefix_cache": (bool,),
+              # the option's bool; "off (recurrent state)" where the
+              # index stands down for a model with recurrent state
+              "prefix_cache": (bool, str),
               "prefix_cached_tokens": (int,),
               "cache_hit_rate": _NUM,
               "blocks_shared_peak": (int,),
@@ -217,6 +219,21 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               "moe_expert_load_max": (list,),
               "moe_expert_load_mean": (list,),
               "latent_bytes_per_token": (int,),
+              # recurrent state (ISSUE 33), on the ledger lines and the
+              # report of a model that has it: rows whose state the
+              # iteration's dispatches read and wrote (prefill rows +
+              # decode slots), the tokens its decode dispatch attended,
+              # the real prompt tokens of its prefill dispatches,
+              # the most slots that held a request at once; and on the
+              # report the bytes of one slot's state, of the state pools
+              # and of one resident token's K/V
+              "state_slots": (int,),
+              "kv_tokens_resident": (int,),
+              "prefill_tokens": (int,),
+              "state_slots_peak": (int,),
+              "state_bytes_per_slot": (int,),
+              "state_pool_bytes": (int,),
+              "kv_token_bytes": (int,),
               # a latent-attention model's report (ISSUE 32): prefill
               # dispatches by how the expanded form attended
               # ({"kernel": n, "xla_loop": n})

@@ -235,6 +235,24 @@ def scatter_paged_kv(pool, block_tables, positions, values):
     n = positions.shape[0]
     block_ids = jnp.take_along_axis(
         block_tables, (positions // bs)[:, None], axis=1)[:, 0]
+    if pool.ndim == 4:
+        from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_attention import (
+            head_major_rows,
+        )
+
+        if head_major_rows(pool.shape[2]):
+            # the chip stores such a page head-major ([H][block][D]): the
+            # write goes to that view's rows, one a (token, head) pair, in
+            # place. Through the logical [block, H] axes the compiler
+            # re-lays the whole pool out before the scatter and back after
+            # it (two 437 MB copies a pool a step at 30 heads: rehearsal
+            # compile for the v5e, PR 33)
+            N, _, H, D = pool.shape
+            rows = pool.transpose(0, 2, 1, 3).reshape(N * H * bs, D)
+            at = ((block_ids[:, None] * H + jnp.arange(H)[None, :]) * bs
+                  + (positions % bs)[:, None]).reshape(-1)
+            rows = rows.at[at].set(values.reshape(n * H, D))
+            return rows.reshape(N, H, bs, D).transpose(0, 2, 1, 3)
     return pool.at[block_ids, positions % bs].set(values)
 
 

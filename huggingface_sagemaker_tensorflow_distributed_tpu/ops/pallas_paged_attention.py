@@ -31,7 +31,12 @@ once, and nothing else (Kwon et al. 2023 pay a single fused read here):
   for the v5e, PR 29: bf16 ``[N, 16, 2, 128]`` is tiled ``(2, 128)``
   with the two heads packed in one 32-bit word, byte for byte what
   ``[N, 32, 128]`` tiled ``(8, 128)`` is), so the layout that prefill,
-  copy-on-write, swap and the prefix index share stays as it is;
+  copy-on-write, swap and the prefix index share stays as it is. Where
+  the chip stores a page HEAD-major instead (:func:`head_major_rows`:
+  kv head counts that are no multiple of 8, such as 30), the rows are
+  taken in that order, one row a (kv head, key) pair, which is the
+  bitcast there; the keys' positions then come from a small table and
+  not from the column's index;
 - **QKᵀ and PV on the MXU**: ``[H, D] x [D, rows]`` for all query heads
   against all rows of the block, the (query head, row) pairs whose kv
   heads differ masked out with the keys past the context, float32
@@ -120,8 +125,24 @@ def block_pages(block_size: int, kv_heads: int, head_dim: int, itemsize: int,
     return pages
 
 
+def head_major_rows(kv_heads: int) -> bool:
+    """True where the v5e stores a page of ``[block_size, kv_heads, D]``
+    with its HEADS major: ``[N, 16, H, 128]`` has the default layout
+    ``{3,1,2,0}`` for ``H`` = 12 or 30, bf16 or float32, and ``{3,2,1,0}``
+    for 2, 4, 8, 16, 24, 32, 40 (rehearsal compiles for the v5e, PR 33:
+    the compiler moves a second-minor dimension that is no multiple of 8
+    sublanes out of the tile, and tiles ``block_size x D``). Read as
+    key-major rows such a pool is copied whole for every call (eight
+    437 MB copies in a decode step of Olmo-Hybrid's four full layers);
+    read as head-major rows it is a bitcast. A pure function of the
+    shape, the same on every backend: an interpreted call on a CPU runs
+    the form the chip would."""
+    return kv_heads % 8 != 0 and kv_heads not in (1, 2, 4)
+
+
 def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
-                  block_size, kv_heads, pages, table_width, window, int8):
+                  block_size, kv_heads, pages, table_width, window, int8,
+                  head_major=False):
     """The whole call: every slot's walk over its pages.
 
     ``tbl_ref`` (SMEM, ``[slots * table_width]``) and ``ctx_ref`` (SMEM,
@@ -131,7 +152,11 @@ def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
     The pools (``[N, rows, D]``, rows = block_size * kv_heads) stay in
     HBM; ``bufs`` are their double buffers ``[2, pages, rows, D]``. With
     ``int8`` two more VMEM inputs follow the bias: the gathered K and V
-    scales, ``[slots, 1, (table_width + pages) * rows]``."""
+    scales, ``[slots, 1, (table_width + pages) * rows]``. With
+    ``head_major`` (never with ``int8``) one VMEM input follows the bias
+    instead: ``[1, pages * rows]`` int32, each row's key offset within a
+    block."""
+    key_off_ref, refs = (refs[0], refs[1:]) if head_major else (None, refs)
     scales, refs = (refs[:2], refs[2:]) if int8 else ((), refs)
     pools, o_ref, bufs, sem = refs[:2], refs[2], refs[3:5], refs[5]
     num_slots, num_heads, _ = q_ref.shape
@@ -262,10 +287,17 @@ def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
             # of kv head c % kv_heads: the head through the bias, the
             # position without a division
             base = page * block_size
-            keep = col < (ctx - base) * kv_heads
-            if window is not None:
-                keep = jnp.logical_and(
-                    keep, col >= (ctx - window - base) * kv_heads)
+            if head_major:
+                # rows run (page, kv head, key): the key's offset is read
+                off = key_off_ref[...]
+                keep = off < ctx - base
+                if window is not None:
+                    keep = jnp.logical_and(keep, off >= ctx - window - base)
+            else:
+                keep = col < (ctx - base) * kv_heads
+                if window is not None:
+                    keep = jnp.logical_and(
+                        keep, col >= (ctx - window - base) * kv_heads)
             logits = jnp.where(keep, logits + head_bias_ref[...], _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -311,29 +343,42 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens,
         k_pool, v_pool = (jnp.pad(p, ((0, 0),) * 3 + ((0, lane_pad),))
                           for p in (k_pool, v_pool))
     D = head_dim + lane_pad
-    # pages as rows: one row a (key, kv head) pair (a bitcast on the chip)
-    pools = [k_pool.reshape(N, rows, D), v_pool.reshape(N, rows, D)]
+    head_major = head_major_rows(Hkv) and not int8
+    if head_major:
+        # pages as rows, one row a (kv head, key) pair: the bitcast where
+        # the chip stores a page head-major
+        pools = [p.transpose(0, 2, 1, 3).reshape(N, rows, D)
+                 for p in (k_pool, v_pool)]
+    else:
+        # pages as rows: one row a (key, kv head) pair (a bitcast on the
+        # chip)
+        pools = [k_pool.reshape(N, rows, D), v_pool.reshape(N, rows, D)]
     # the scales of each slot's table span as one row vector, with a
     # block of zeros behind it for the last block's slice to end in
     scales = [jnp.pad(sp[block_tables].reshape(S, 1, nb * rows),
                       ((0, 0), (0, 0), (0, pages * rows)))
               for sp in ((k_scale_pool, v_scale_pool) if int8 else ())]
     # query head j attends kv head j // G; row c of a block holds kv head
-    # c % Hkv
+    # c % Hkv (head-major: row c of a page holds kv head c // bs)
+    c = jnp.arange(pages * rows)
+    row_head = (c % rows) // bs if head_major else c % Hkv
     head_bias = jnp.where(
-        (jnp.arange(Hq) // (Hq // Hkv))[:, None]
-        == (jnp.arange(pages * rows) % Hkv)[None, :],
+        (jnp.arange(Hq) // (Hq // Hkv))[:, None] == row_head[None, :],
         0.0, _NEG_INF).astype(jnp.float32)
+    key_off = ([((c // rows) * bs + c % bs).astype(jnp.int32)[None, :]]
+               if head_major else [])
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, block_size=bs, kv_heads=Hkv,
-        pages=pages, table_width=nb, window=window, int8=int8)
+        pages=pages, table_width=nb, window=window, int8=int8,
+        **({"head_major": True} if head_major else {}))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
-        in_specs=[smem, smem, vmem, vmem] + [vmem] * len(scales) + [hbm] * 2,
+        in_specs=([smem, smem, vmem, vmem]
+                  + [vmem] * (len(key_off) + len(scales)) + [hbm] * 2),
         out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((S, Hq, D), q.dtype),
         scratch_shapes=(
@@ -342,7 +387,8 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens,
         interpret=interpret,
         name="paged_decode",
     )(block_tables.astype(jnp.int32).reshape(-1),
-      context_lens.astype(jnp.int32), q, head_bias, *scales, *pools)
+      context_lens.astype(jnp.int32), q, head_bias, *key_off, *scales,
+      *pools)
     return out[..., :head_dim] if lane_pad else out
 
 

@@ -121,6 +121,26 @@ speculation; vLLM + Orca + Sarathi + Leviathan lineage):
   per-chip ``kv_pool_bytes`` admits ~tp× the concurrent requests —
   the measurable capacity win even on CPU meshes.
 
+- **Recurrent state beside the pools** (ISSUE 33) — a model with
+  linear-attention layers (``models/olmo_hybrid.py``) carries, besides
+  the paged K/V of its full-attention layers, state that is indexed by
+  SLOT and never grows: :class:`CachePlan`'s ``state`` kind, one state
+  pool a leaf (``[num_slots, ...]``, sized from ``num_slots`` alone).
+  Every prefill dispatch and decode step hands each row its slot's rows
+  and writes them back, the pools chaining
+  through dispatch-ahead as the block pools do; a chunk that starts at 0
+  starts from zeros in the program, and pad tails, pad rows and inactive
+  slots hold the state still (the model's ``token_mask``). What carries
+  or rolls back blocks only stands down or raises by name for such a
+  model: the prefix index matches nothing (``stats().prefix_cache`` says
+  ``off (recurrent state)``), ``swap``, ``speculate_k``, a mesh and
+  ``transport.migrate_request`` raise; preemption recomputes from 0.
+  ``state_form`` / ``state_rows`` on the step spans, ``state_slots`` /
+  ``kv_tokens_resident`` / ``prefill_tokens`` / ``state_slots_peak`` on
+  the ledger lines, ``state_bytes_per_slot`` / ``state_pool_bytes`` in
+  ``stats()`` and the report; a model without such layers has none of
+  them and traces what it always did.
+
 Decoding is greedy by default and token-for-token identical to
 per-request ``generate_causal`` — the exactness gate
 ``tests/test_serve.py`` pins, including with bucketing enabled and
@@ -454,7 +474,21 @@ class CachePlan(NamedTuple):
     it rides the same gather/scatter/COW/swap machinery), ``("index",)``
     for the per-row
     write indices, ``("scalar",)`` for model-level counters (unused
-    under explicit position_ids). ``paths`` holds each leaf's key path
+    under explicit position_ids), and ``("state", state_index)`` for what
+    a recurrent (linear-attention) layer carries, ``recurrent_state`` /
+    ``conv_state``: leaves indexed BY ROW and of a size that does not
+    grow with the context, so no block table reaches them. The engine
+    keeps one STATE POOL a leaf, ``[num_slots, ...]`` (row ``s`` is slot
+    ``s``'s; a pad row of a prefill dispatch names row ``num_slots``,
+    which is no row: it reads zeros and its write is dropped), sized from
+    ``num_slots`` alone; ``state_shapes`` holds each one's ``(shape
+    without the row axis, dtype name)``. No null row: a decode step takes
+    the pools whole, and a ``[:num_slots]`` slice of a larger pool was a
+    copy of the state a layer a step on the v5e (chip run, PR 33). A step
+    hands every row its slot's state and writes it back; a prefix hit, a swap,
+    a migration and a speculative rewind carry or roll back blocks only,
+    so an engine with a ``state`` kind stands each of them down by name
+    (``ServeEngine.__init__``). ``paths`` holds each leaf's key path
     so the PAGED cache (kernel mode) can be built as a nested dict with
     a ``block_tables`` sibling injected per attention scope — and the
     mutated pools re-extracted by NAME, immune to the flatten-order
@@ -476,7 +510,11 @@ class CachePlan(NamedTuple):
     kinds: tuple
     paths: tuple
     kv_shardings: tuple = ()
+    state_shapes: tuple = ()
 
+
+# the leaves a recurrent layer carries a row (``("state", i)`` kinds)
+_STATE_LEAVES = ("recurrent_state", "conv_state")
 
 # the kinds of cache leaf that live in a block pool
 _POOLED = ("kv", "latent")
@@ -507,6 +545,13 @@ def _routes(model) -> bool:
     """True for a model with DROPLESS routed experts (it takes a
     ``token_mask`` and sows ``moe_stats``); static under jit."""
     return bool(getattr(model.config, "n_routed_experts", 0))
+
+
+def _masks_tokens(model, plan: CachePlan) -> bool:
+    """True for a model whose apply takes ``token_mask`` (which tokens of
+    the dispatch are real): routed experts count by it, recurrent layers
+    hold their state still where it is False."""
+    return _routes(model) or bool(plan.state_shapes)
 
 
 def _constrain_pools(pools, plan: CachePlan):
@@ -553,7 +598,7 @@ def build_cache_plan(model, params, max_ctx: int,
 
     shapes = jax.eval_shape(init_cache, params)
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    kinds, pool_shapes, paths = [], [], []
+    kinds, pool_shapes, paths, state_shapes = [], [], [], []
     for path, leaf in flat:
         names = tuple(p.key if hasattr(p, "key") else str(p)
                       for p in path)
@@ -575,6 +620,10 @@ def build_cache_plan(model, params, max_ctx: int,
                     f"cache leaf {name} has width {s}, expected {max_ctx}")
             kinds.append(("latent", len(pool_shapes)))
             pool_shapes.append((h, d, leaf.dtype))
+        elif name in _STATE_LEAVES:
+            kinds.append(("state", len(state_shapes)))
+            state_shapes.append((tuple(int(d) for d in leaf.shape[1:]),
+                                 str(leaf.dtype)))
         elif name == "cache_index":
             kinds.append(("index",))
         elif name == "position_index":
@@ -582,15 +631,26 @@ def build_cache_plan(model, params, max_ctx: int,
         else:
             raise ValueError(
                 f"unsupported cache leaf {name!r}: the serve engine "
-                "speaks the cached_key/cached_value (+ int8 scale) and "
-                "cached_latent protocols only")
+                "speaks the cached_key/cached_value (+ int8 scale), "
+                "cached_latent and recurrent_state/conv_state protocols "
+                "only")
         paths.append(names)
+    if state_shapes and not pool_shapes:
+        raise ValueError(
+            "a model with recurrent state and no paged K/V or latent "
+            "layer is not served: the scheduler counts a request's "
+            "residency in blocks")
     kv_shardings: tuple = ()
     if mesh is not None and mesh.shape.get("tensor", 1) > 1:
         from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.sharding import (
             kv_pool_sharding,
         )
 
+        if state_shapes:
+            raise ValueError(
+                "recurrent state (recurrent_state / conv_state) cannot be "
+                "served under a tensor-parallel mesh: a state pool "
+                "sharded over its heads is not wired (ROADMAP R3)")
         if any(k[0] == "latent" for k in kinds):
             # a latent row has no heads axis to shard: every device
             # would need all of it (a replicated pool, and attention
@@ -605,7 +665,7 @@ def build_cache_plan(model, params, max_ctx: int,
         kv_shardings = tuple(kv_pool_sharding(mesh, h)
                              for h, _d, _dt in pool_shapes)
     result = (CachePlan(treedef, tuple(kinds), tuple(paths),
-                        kv_shardings), pool_shapes)
+                        kv_shardings, tuple(state_shapes)), pool_shapes)
     _PLAN_CACHE[key] = result
     return result
 
@@ -634,15 +694,19 @@ def _pool_rows(pool, rows):
 
 
 def _assemble_cache(plan: CachePlan, pools, block_tables, context_lens,
-                    width: Optional[int] = None):
+                    width: Optional[int] = None, state_rows=()):
     """The model-facing cache pytree: contiguous per-slot KV gathered
     from the pools (restricted to the static ``width`` bucket when
-    given), write indices set to each slot's context length."""
+    given), write indices set to each slot's context length, and each
+    recurrent leaf's rows as ``state_rows`` has them (one ``[rows, ...]``
+    array a ``state`` kind, by its index)."""
     leaves = []
     for kind in plan.kinds:
         if kind[0] in _POOLED:
             leaves.append(gather_paged_kv(_pool_view(pools[kind[1]]),
                                           block_tables, width=width))
+        elif kind[0] == "state":
+            leaves.append(state_rows[kind[1]])
         elif kind[0] == "index":
             leaves.append(context_lens.astype(jnp.int32))
         else:
@@ -652,7 +716,7 @@ def _assemble_cache(plan: CachePlan, pools, block_tables, context_lens,
 
 def _decode_step(model, params, pools, tokens, block_tables, context_lens,
                  active, temps, top_ks, top_ps, keys, folds,
-                 plan: CachePlan, width: int, sampled: bool):
+                 plan: CachePlan, width: int, sampled: bool, states=()):
     """One decode iteration over ALL slots (static [S] shapes): feed
     each slot's last token against a ``width``-bucket gathered cache,
     write its K/V at ``context_len`` (scattered back to the pools;
@@ -662,9 +726,12 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
     on. Callers guarantee ``context_len + 1 <= width`` for every active
     slot. Returns ``(next_tok, pools)``; a model with routed experts adds
     a third, ``[expert layers, experts held]`` int32: the pairs each held
-    expert got from the ACTIVE slots."""
+    expert got from the ACTIVE slots. ``states`` (the state pools of a
+    plan with ``state`` kinds, row ``s`` slot ``s``'s) come back last, as
+    one list: an active slot's rows advanced by the token, every other
+    row as it was."""
     cache = _assemble_cache(plan, pools, block_tables, context_lens,
-                            width=width)
+                            width=width, state_rows=states)
     # kv-buffer validity includes the slot being written this step —
     # exactly generate_causal's decode-step mask, at bucket width
     valid = (jnp.arange(width)[None, :]
@@ -675,7 +742,8 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
         position_ids=context_lens[:, None], decode=True,
         deterministic=True,
         mutable=["cache", "moe_stats"] if routes else ["cache"],
-        **({"token_mask": active[:, None]} if routes else {}))
+        **({"token_mask": active[:, None]}
+           if _masks_tokens(model, plan) else {}))
     last = logits[:, -1, :].astype(jnp.float32)
     if sampled:
         next_tok = sample_per_slot(last, temps, top_ks, top_ps, keys, folds)
@@ -686,8 +754,11 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
     safe_tables = jnp.where(active[:, None], block_tables, 0)
     pos = jnp.where(active, context_lens, 0)
     mut_leaves = jax.tree_util.tree_leaves(mut["cache"])
-    new_pools = list(pools)
+    new_pools, new_states = list(pools), list(states)
     for leaf, kind in zip(mut_leaves, plan.kinds):
+        if kind[0] == "state":
+            # the model held an inactive row's state still (token_mask)
+            new_states[kind[1]] = leaf
         if kind[0] not in _POOLED:
             continue
         written = jnp.take_along_axis(
@@ -696,10 +767,11 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
             new_pools[kind[1]], safe_tables, pos,
             _pool_rows(new_pools[kind[1]], written))
     return (next_tok, _constrain_pools(new_pools, plan),
-            *_moe_counts(mut))
+            *_moe_counts(mut), *((new_states,) if states else ()))
 
 
-def _paged_cache(plan: CachePlan, pools, block_tables, context_lens):
+def _paged_cache(plan: CachePlan, pools, block_tables, context_lens,
+                 state_rows=()):
     """The model-facing PAGED cache pytree (kernel mode): every KV leaf
     is its whole block pool (no gather — the fused kernel walks the
     tables in-attention), write indices are the context lengths, and a
@@ -714,6 +786,8 @@ def _paged_cache(plan: CachePlan, pools, block_tables, context_lens):
             node = node.setdefault(key, {})
         if kind[0] == "kv":
             node[path[-1]] = pools[kind[1]]
+        elif kind[0] == "state":
+            node[path[-1]] = state_rows[kind[1]]
         elif kind[0] == "index":
             node[path[-1]] = context_lens.astype(jnp.int32)
             node["block_tables"] = block_tables
@@ -724,23 +798,26 @@ def _paged_cache(plan: CachePlan, pools, block_tables, context_lens):
 
 def _paged_decode_step(model, params, pools, tokens, block_tables,
                        context_lens, active, temps, top_ks, top_ps, keys,
-                       folds, plan: CachePlan, width: int, sampled: bool):
+                       folds, plan: CachePlan, width: int, sampled: bool,
+                       states=()):
     """One FUSED decode iteration over all slots (kernel mode): the
     model's paged decode branch scatters each slot's new K/V straight
     into the pools and attends via the Pallas paged kernel — no dense
     [S, H, width, D] intermediate is ever materialized. ``width``
     restricts the block-table walk to the iteration's gather bucket
     (same ladder, same compile-per-bucket contract as the XLA path);
-    inactive slots route writes to null block 0 at context 0."""
+    inactive slots route writes to null block 0 at context 0. ``states``
+    as in :func:`_decode_step`."""
     bs = pools[0].shape[1]
     tables = block_tables[:, :width // bs]
     safe_tables = jnp.where(active[:, None], tables, 0)
     ctx = jnp.where(active, context_lens, 0)
-    cache = _paged_cache(plan, pools, safe_tables, ctx)
+    cache = _paged_cache(plan, pools, safe_tables, ctx, states)
     logits, mut = model.apply(
         {"params": params, "cache": cache}, tokens[:, None], None,
         position_ids=ctx[:, None], decode=True, deterministic=True,
-        mutable=["cache"])
+        mutable=["cache"],
+        **({"token_mask": active[:, None]} if states else {}))
     last = logits[:, -1, :].astype(jnp.float32)
     if sampled:
         next_tok = sample_per_slot(last, temps, top_ks, top_ps, keys, folds)
@@ -752,16 +829,19 @@ def _paged_decode_step(model, params, pools, tokens, block_tables,
     flat, _ = jax.tree_util.tree_flatten_with_path(mut["cache"])
     by_path = {tuple(p.key if hasattr(p, "key") else str(p)
                      for p in path): leaf for path, leaf in flat}
-    new_pools = list(pools)
+    new_pools, new_states = list(pools), list(states)
     for path, kind in zip(plan.paths, plan.kinds):
         if kind[0] == "kv":
             new_pools[kind[1]] = by_path[path]
-    return next_tok, new_pools
+        elif kind[0] == "state":
+            new_states[kind[1]] = by_path[path]
+    return (next_tok, new_pools, *((new_states,) if states else ()))
 
 
 def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
                    temps, top_ks, top_ps, keys, folds, plan: CachePlan,
-                   sampled: bool, width: Optional[int] = None):
+                   sampled: bool, width: Optional[int] = None, states=(),
+                   state_rows=None):
     """One BATCHED prefill dispatch: up to G prefilling slots' chunks as
     G independent rows (static [G, C] shape; unused rows carry pad
     tokens against the null block table). Each row writes its chunk's
@@ -781,11 +861,27 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     ``start + C <= width`` for every row; the write-back goes through
     the full tables either way. Returns ``(next_tok, pools)``, and for a
     model with routed experts a third, the REAL tokens' pair counts per
-    held expert (as :func:`_decode_step`)."""
+    held expert (as :func:`_decode_step`).
+
+    ``states`` / ``state_rows`` (a plan with ``state`` kinds): row g reads
+    and writes row ``state_rows[g]`` of every state pool, its slot's (a
+    pad row names ``num_slots``, no row of the pool: it reads zeros and
+    its write is dropped). A row whose chunk starts at 0 starts from
+    ZEROS, whatever the slot's last request left there: slot reuse needs
+    no clearing pass on the host. The pad tail of a final chunk and a pad
+    row do not advance the state (the model's ``token_mask``). The state
+    pools come back last, as one list."""
     G, C = chunks.shape
     bs = pools[0].shape[1]
     max_ctx = block_tables.shape[1] * bs if width is None else width
-    cache = _assemble_cache(plan, pools, block_tables, start, width=width)
+    fresh = start == 0
+    cache = _assemble_cache(
+        plan, pools, block_tables, start, width=width,
+        state_rows=[jnp.where(fresh.reshape((G,) + (1,) * (st.ndim - 1)),
+                              jnp.zeros((), st.dtype),
+                              st.at[state_rows].get(mode="fill",
+                                                    fill_value=0))
+                    for st in states])
     # chunk slots are marked valid; the model's step mask
     # (key_slot <= cache_index + q_index) imposes causality within the
     # chunk, and pad-tail keys sit AFTER every real query so they are
@@ -796,19 +892,28 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     pos_ids = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     routes = _routes(model)
     extra = {}
-    if routes:
-        # the real tokens of the dispatch, for the routed layers' counts:
+    if _masks_tokens(model, plan):
+        # the real tokens of the dispatch, for the routed layers' counts
+        # and the recurrent layers' state:
         # a pad row rides the null block table, a final chunk is real up
         # to ``rel``, any other chunk is real whole
         n_real = jnp.where(rel >= 0, rel + 1, C) * (block_tables[:, 0] != 0)
         extra["token_mask"] = jnp.arange(C)[None, :] < n_real[:, None]
+    last = jnp.clip(rel, 0, C - 1)
+    picks = getattr(model, "takes_logit_positions", False)
+    if picks:
+        # the model runs its head on the one row a chunk needs
+        extra["logit_positions"] = last
     logits, mut = model.apply(
         {"params": params, "cache": cache}, chunks, valid,
         position_ids=pos_ids, decode=True, deterministic=True,
         mutable=["cache", "moe_stats"] if routes else ["cache"], **extra)
-    sel = jnp.take_along_axis(
-        logits.astype(jnp.float32),
-        jnp.clip(rel, 0, C - 1)[:, None, None], axis=1)[:, 0]  # [G, V]
+    if picks:
+        sel = logits[:, 0].astype(jnp.float32)                 # [G, V]
+    else:
+        sel = jnp.take_along_axis(
+            logits.astype(jnp.float32), last[:, None, None],
+            axis=1)[:, 0]                                      # [G, V]
     if sampled:
         next_tok = sample_per_slot(sel, temps, top_ks, top_ps, keys, folds)
     else:
@@ -817,8 +922,11 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
                  + jnp.arange(C, dtype=jnp.int32)[None, :]).reshape(-1)
     tables_tok = jnp.repeat(block_tables, C, axis=0)       # [G*C, nb]
     mut_leaves = jax.tree_util.tree_leaves(mut["cache"])
-    new_pools = list(pools)
+    new_pools, new_states = list(pools), list(states)
     for leaf, kind in zip(mut_leaves, plan.kinds):
+        if kind[0] == "state":
+            new_states[kind[1]] = states[kind[1]].at[state_rows].set(
+                leaf, mode="drop")
         if kind[0] not in _POOLED:
             continue
         h, d = leaf.shape[1], leaf.shape[3]
@@ -830,7 +938,7 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
             new_pools[kind[1]], tables_tok, positions,
             _pool_rows(new_pools[kind[1]], written))
     return (next_tok, _constrain_pools(new_pools, plan),
-            *_moe_counts(mut))
+            *_moe_counts(mut), *((new_states,) if states else ()))
 
 
 @functools.lru_cache(maxsize=2)
@@ -842,7 +950,7 @@ def _decode_step_jit(donate: bool):
     updates them in place (CPU has no donation and would warn every
     call)."""
     return jax.jit(_decode_step, static_argnums=(0, 12, 13, 14),
-                   donate_argnums=(2,) if donate else ())
+                   donate_argnums=(2, 15) if donate else ())
 
 
 @functools.lru_cache(maxsize=2)
@@ -850,7 +958,7 @@ def _prefill_chunk_jit(donate: bool):
     """Process-wide jitted prefill dispatch: one compile per (model,
     plan, sampled, width) and row count."""
     return jax.jit(_prefill_chunk, static_argnums=(0, 12, 13, 14),
-                   donate_argnums=(2,) if donate else ())
+                   donate_argnums=(2, 15) if donate else ())
 
 
 @functools.lru_cache(maxsize=2)
@@ -859,7 +967,7 @@ def _paged_decode_step_jit(donate: bool):
     static/donation contract as :func:`_decode_step_jit`: one compile
     per (model, plan, bucket, sampled)."""
     return jax.jit(_paged_decode_step, static_argnums=(0, 12, 13, 14),
-                   donate_argnums=(2,) if donate else ())
+                   donate_argnums=(2, 15) if donate else ())
 
 
 def _copy_block(pools, src, dst):
@@ -1103,7 +1211,8 @@ class EngineStats(NamedTuple):
     verify_waste_peak: float = 0.0
     verify_waste_mean: float = 0.0
     # prefix caching (ISSUE 8)
-    prefix_cache: bool = False
+    # the option, or "off (recurrent state)" where the index stands down
+    prefix_cache: Union[bool, str] = False
     prefix_cached_tokens: int = 0
     cache_hit_rate: Optional[float] = None
     blocks_shared_peak: int = 0
@@ -1155,6 +1264,13 @@ class EngineStats(NamedTuple):
     # (ISSUE 32): dispatches by the model's ``expanded_form`` (None: a
     # K/V cache)
     prefill_dispatches_by_form: Optional[dict] = None
+    # recurrent state (ISSUE 33), None for a model without it: bytes a
+    # slot's state costs over all recurrent layers (it never grows), the
+    # state pools' bytes (num_slots of them), and the most slots that held
+    # a request at once
+    state_bytes_per_slot: Optional[int] = None
+    state_pool_bytes: Optional[int] = None
+    state_slots_peak: Optional[int] = None
 
 
 class ServeEngine:
@@ -1426,6 +1542,9 @@ class ServeEngine:
         # which form each dispatch attends by is the model's own rule on
         # the dispatch's shape, named in the step spans' arguments
         self._latent = any(k[0] == "latent" for k in plan.kinds)
+        # a model with recurrent (linear-attention) layers: per-slot state
+        # pools beside the block pools (``CachePlan``'s ``state`` kind)
+        self._stateful = bool(plan.state_shapes)
         self._routes = _routes(model)
         # (token, expert) pairs one real token makes over the model
         self._moe_fanout = (int(cfg.num_experts_per_tok)
@@ -1447,6 +1566,13 @@ class ServeEngine:
             raise ValueError(
                 "speculative decoding is not wired for latent-"
                 "attention or routed-expert models")
+        if self._stateful and self.speculate_k:
+            raise ValueError(
+                "speculate_k: speculative decoding is not wired for a "
+                "model with recurrent state: a rejected draft token has "
+                "already advanced the state, and the rewind rolls back "
+                "block tables only (ROADMAP R3: verify with state "
+                "roll-back)")
         # bytes one resident token costs across every pool (int8 KV +
         # its fp32 scale plane included) — the figure that sizes a
         # byte-budgeted pool and denominates kv_bytes_read telemetry.
@@ -1467,8 +1593,13 @@ class ServeEngine:
             # draft's pools ride on top (its layer share).
             block_bytes = block_size * max(token_bytes, 1)
             num_blocks = max(2, 1 + int(kv_pool_bytes) // block_bytes)
+        # a prefix hit hands a request K/V blocks and no state, which
+        # would be silently wrong: for a plan with a ``state`` kind the
+        # index matches nothing and registers nothing (requests are still
+        # counted, at zero cached tokens)
         self.blocks = BlockManager(num_blocks, block_size,
-                                   token_bytes=token_bytes)
+                                   token_bytes=token_bytes,
+                                   prefix_matching=not self._stateful)
         self.sched = Scheduler(num_slots, self.blocks, prefill_chunk,
                                self.max_model_len,
                                decode_lookahead=self.speculate_k + 1,
@@ -1509,6 +1640,14 @@ class ServeEngine:
         # exactly the footprint a bigger-than-a-chip model cannot fit
         self._pools = self._init_pools(num_blocks, block_size,
                                        pool_shapes, plan)
+        # one state pool a recurrent leaf, row s slot s's: sized from
+        # ``num_slots`` alone, no option sets it
+        self._states = [jnp.zeros((self.num_slots,) + shape, dtype)
+                        for shape, dtype in plan.state_shapes]
+        self.state_bytes_per_slot = sum(
+            int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for shape, dtype in plan.state_shapes)
+        self.state_pool_bytes = self.num_slots * self.state_bytes_per_slot
         # speculative mode: the draft model's paged pools ride the SAME
         # block tables/allocator as the target's — one allocation
         # domain, two KV address spaces (per-block bytes grow by the
@@ -1633,6 +1772,13 @@ class ServeEngine:
         self._iter_prefill_s = 0.0
         self._iter_decode_s = 0.0
         self._iter_decode_slots = 0
+        # recurrent state (a model that has it): rows whose state this
+        # iteration's dispatches read and wrote, the tokens its decode
+        # dispatch attended (each slot's context and the token written),
+        # and the real prompt tokens of its prefill dispatches
+        self._iter_state_slots = 0
+        self._iter_kv_resident = 0
+        self._iter_prefill_tokens = 0
         # the iteration's account of its own wall time (always on:
         # stamps and float adds): the four parts of THIS iteration, the
         # last stamp, when the previous iteration returned, and the
@@ -1648,6 +1794,12 @@ class ServeEngine:
         # prefix cache on) the BlockManager gets the spill/demotion
         # hook, both closing over the live pools.
         self.swap = parse_swap(swap)
+        if self._stateful and self.swap != "off":
+            raise ValueError(
+                f"swap={self.swap!r}: the host spill tier is not wired for "
+                "a model with recurrent state: a swapped BlockSet carries "
+                "blocks and no state (ROADMAP R3: state snapshots). "
+                "Preemption recomputes: a re-admitted request starts at 0")
         self.swap_bytes = parse_swap_bytes(swap_bytes)
         self.swap_ins = 0
         self.swap_outs = 0
@@ -1939,15 +2091,17 @@ class ServeEngine:
                             np.zeros((G, nb), np.int32),
                             zi, np.full((G,), -1, np.int32), zf, zi, zf,
                             np.zeros((G, 2), np.uint32), zi)
+                    null_rows = np.full((G,), S, np.int32)
                     with obs.lifecycle_span(f"serve/warmup/prefill_g{G}"):
                         for width in (self.prefill_buckets
                                       if G == self.prefill_batch
                                       else self.prefill_buckets[:1]):
                             with obs.lifecycle_span(
                                     f"serve/warmup/prefill_g{G}/w{width}"):
-                                tok, self._pools, *_ = self._prefill_fn(
+                                tok, _ = self._step_out(self._prefill_fn(
                                     self.model, self.params, self._pools,
-                                    *null, self._plan, mode, width)
+                                    *null, self._plan, mode, width,
+                                    *self._state_args(null_rows)))
                                 if self.speculative and not mode:
                                     tok, self._d_pools, *_ = self._prefill_fn(
                                         self.draft_model, self.draft_params,
@@ -1975,9 +2129,10 @@ class ServeEngine:
                                     tokens, np.zeros((S, nb), np.int32),
                                     si, np.zeros((S,), bool), sf, si, sf,
                                     np.zeros((S, 2), np.uint32), si,
-                                    self._plan, bucket, mode)
+                                    self._plan, bucket, mode,
+                                    *self._state_args())
 
-                            tok, self._pools, *_ = decode(si)
+                            tok, _ = self._step_out(decode(si))
                             if self.overlap:
                                 # the dispatch-ahead loop feeds the
                                 # previous step's device-resident tokens
@@ -1987,7 +2142,7 @@ class ServeEngine:
                                 # a second compile (found on four chips:
                                 # two 5 s compiles mid-serve); on one
                                 # device it is a cache hit
-                                tok, self._pools, *_ = decode(tok)
+                                tok, _ = self._step_out(decode(tok))
                         jax.block_until_ready(tok)
             if (self.overlap and not self.speculative
                     and not self._warmed_modes):
@@ -2108,6 +2263,12 @@ class ServeEngine:
             self._moe_resolve(everything=True)
             out["moe_pairs"] = self.moe_pairs
             out["moe_pairs_held"] = self.moe_pairs_held
+        # recurrent state: absent for any other model
+        if self._stateful:
+            out["state_bytes_per_slot"] = self.state_bytes_per_slot
+            out["state_pool_bytes"] = self.state_pool_bytes
+            out["state_slots_peak"] = self.peak_resident
+            out["kv_token_bytes"] = self.blocks.token_bytes
         # multi-replica serving (ISSUE 14): a router-owned replica's
         # report names itself so the merged cross-host report (and
         # `obsctl slo`'s per-replica grouping) can attribute it; absent
@@ -2155,7 +2316,7 @@ class ServeEngine:
         if self.prefix_cache:
             cached = sum(r.prefix_cached_tokens for r in reqs)
             admitted = sum(r.prefix_prompt_tokens for r in reqs)
-            out["prefix_cache"] = True
+            out["prefix_cache"] = self.prefix_cache_state
             out["prefix_cached_tokens"] = cached
             out["cache_hit_rate"] = (round(cached / admitted, 4)
                                      if admitted else 0.0)
@@ -2284,7 +2445,7 @@ class ServeEngine:
             spec_windows=self.spec_windows,
             verify_waste_peak=self.blocks.peak_verify_waste,
             verify_waste_mean=self.blocks.verify_waste(),
-            prefix_cache=self.prefix_cache,
+            prefix_cache=self.prefix_cache_state,
             prefix_cached_tokens=sum(
                 r.prefix_cached_tokens for r in self.finished.values()),
             cache_hit_rate=self._aggregate_hit_rate(),
@@ -2320,7 +2481,22 @@ class ServeEngine:
                 if self.swap != "off" else None),
             migrations_in=self.migrations_in,
             migrations_out=self.migrations_out,
-            migration_bytes=self.migration_bytes)
+            migration_bytes=self.migration_bytes,
+            state_bytes_per_slot=(self.state_bytes_per_slot
+                                  if self._stateful else None),
+            state_pool_bytes=(self.state_pool_bytes
+                              if self._stateful else None),
+            state_slots_peak=(self.peak_resident
+                              if self._stateful else None))
+
+    @property
+    def prefix_cache_state(self):
+        """``prefix_cache`` as ``stats()``, ``slo_summary()`` and the
+        ``report`` event say it: the option's bool, or for a model with
+        recurrent state, whose prefix index stands down, the reason."""
+        if self.prefix_cache and self._stateful:
+            return "off (recurrent state)"
+        return self.prefix_cache
 
     def _aggregate_hit_rate(self) -> Optional[float]:
         """Prompt tokens served from cache / prompt tokens admitted,
@@ -2385,6 +2561,9 @@ class ServeEngine:
         self._iter_prefill_s = 0.0
         self._iter_decode_s = 0.0
         self._iter_decode_slots = 0
+        self._iter_state_slots = 0
+        self._iter_kv_resident = 0
+        self._iter_prefill_tokens = 0
         sink = obs.has_sink()
         with obs.span("serve/step",
                       {"iteration": self.iterations} if sink else None):
@@ -2512,6 +2691,12 @@ class ServeEngine:
         totals[_DUR] += dur_s
         totals[_GAP] += gap_s
         moe_kw = self._moe_resolve() if sink else {}
+        # absent from the ledger of a model without recurrent state
+        state_kw = dict(state_slots=self._iter_state_slots,
+                        kv_tokens_resident=self._iter_kv_resident,
+                        prefill_tokens=self._iter_prefill_tokens,
+                        state_slots_peak=self.peak_resident) \
+            if self._stateful else {}
         if sink and self.timeline:
             # the engine ledger: one line per iteration with the phase
             # mix (prefill vs decode dispatch seconds inside the
@@ -2539,7 +2724,7 @@ class ServeEngine:
                 waiting=waiting,
                 preemptions=self.sched.n_preemptions,
                 kv_used_frac=round(self.blocks.utilization(), 4),
-                **moe_kw, **arrival_kw, **self._replica_kw())
+                **moe_kw, **state_kw, **arrival_kw, **self._replica_kw())
         elif sink:
             # timeline off: the per-iteration gauges as series, which
             # `obsctl tail` falls back to (with it on, the ledger line
@@ -2567,6 +2752,33 @@ class ServeEngine:
         if out["latent_path"] == "expanded":
             out["expanded_form"] = self.model.expanded_form(q_len, width)
         return out
+
+    def _state_kw(self, q_len: int, rows: int) -> dict:
+        """``{"state_form": "step" | "chunked", "state_rows": n}`` for a
+        dispatch of ``q_len`` tokens a row of a model with recurrent
+        state, ``rows`` of them real (their state is read and written);
+        ``{}`` for any other model."""
+        if not self._stateful:
+            return {}
+        return {"state_form": self.model.state_form(q_len),
+                "state_rows": rows}
+
+    def _state_args(self, rows=None) -> tuple:
+        """The state operands of a step of a model with recurrent state
+        (the pools, and for a prefill dispatch each row's pool row);
+        ``()`` for any other model, whose call is the one it always
+        was."""
+        if not self._stateful:
+            return ()
+        return (self._states,) if rows is None else (self._states, rows)
+
+    def _step_out(self, out: tuple) -> tuple:
+        """``(token array, routed counts)`` of a step's result, the block
+        pools and the state pools it returned kept as the engine's."""
+        tok, self._pools, *rest = out
+        if self._stateful:
+            self._states = rest.pop()
+        return tok, rest
 
     def _moe_dispatched(self, moe: list, tokens: int, decode: bool) -> None:
         """Keep a dispatch's routed counts (a device array, NOT fetched
@@ -2736,6 +2948,10 @@ class ServeEngine:
             top_ps = np.zeros((G,), np.float32)
             keys = np.zeros((G, 2), np.uint32)
             folds = np.zeros((G,), np.int32)
+            # each row's row of the state pools: its slot's; a pad row
+            # names none (num_slots: it reads zeros, its write is dropped)
+            rows = np.full((G,), self.num_slots, np.int32)
+            rows[:len(slots)] = [slot.index for slot in slots]
             finals = []
             sampled = False
             for i, slot in enumerate(slots):
@@ -2756,18 +2972,22 @@ class ServeEngine:
                         keys[i] = self._keys[req.rid]
                         folds[i] = self._generated(req)
         t0 = self._lap(_STAGE)
+        # the dispatch's real prompt tokens (a final chunk may be short)
+        real_tokens = sum(min(C, len(s.request.prompt) - s.prefill_pos)
+                          for s in slots)
         latent_kw = self._latent_kw(C, width)
         with obs.span("serve/prefill_chunk",
                       {"chunks": len(slots), "rows": G, "width": width,
-                       **latent_kw}
+                       **latent_kw, **self._state_kw(C, len(slots))}
                       if obs.has_sink() else None):
-            tok, self._pools, *moe = self._prefill_fn(
+            tok, moe = self._step_out(self._prefill_fn(
                 self.model, self.params, self._pools, chunks, tables,
                 start, rel, temps, top_ks, top_ps, keys, folds,
-                self._plan, sampled, width)
-            self._moe_dispatched(moe, sum(
-                min(C, len(s.request.prompt) - s.prefill_pos)
-                for s in slots), False)
+                self._plan, sampled, width, *self._state_args(rows)))
+            if self._stateful:
+                self._iter_state_slots += len(slots)
+                self._iter_prefill_tokens += real_tokens
+            self._moe_dispatched(moe, real_tokens, False)
             if self.speculative:
                 # the draft's pools must hold the prompt KV too — same
                 # chunks/tables, its own address space; the returned
@@ -2877,12 +3097,15 @@ class ServeEngine:
         with obs.span("serve/decode_step",
                       {"active": len(ds), "gather_bucket": bucket,
                        "decode_path": self.decode_path,
-                       **self._latent_kw(1)}
+                       **self._latent_kw(1), **self._state_kw(1, len(ds))}
                       if obs.has_sink() else None):
-            nxt, self._pools, *moe = self._decode_fn(
+            nxt, moe = self._step_out(self._decode_fn(
                 self.model, self.params, self._pools, tokens, tables,
                 ctx, active, temps, top_ks, top_ps, keys, folds,
-                self._plan, bucket, sampled)
+                self._plan, bucket, sampled, *self._state_args()))
+            if self._stateful:
+                self._iter_state_slots += len(ds)
+                self._iter_kv_resident += int(ctx.sum()) + len(ds)
             self._moe_dispatched(moe, len(ds), True)
             self._lap(_DISPATCH)
             with obs.span("serve/commit_fetch"):
@@ -2996,12 +3219,15 @@ class ServeEngine:
         with obs.span("serve/decode_step",
                       {"active": len(ds), "gather_bucket": bucket,
                        "decode_path": self.decode_path,
-                       **self._latent_kw(1)}
+                       **self._latent_kw(1), **self._state_kw(1, len(ds))}
                       if obs.has_sink() else None):
-            nxt, self._pools, *moe = self._decode_fn(
+            nxt, moe = self._step_out(self._decode_fn(
                 self.model, self.params, self._pools, tokens, tables,
                 ctx, active, temps, top_ks, top_ps, keys, folds,
-                self._plan, bucket, sampled)
+                self._plan, bucket, sampled, *self._state_args()))
+            if self._stateful:
+                self._iter_state_slots += len(ds)
+                self._iter_kv_resident += int(ctx.sum()) + len(ds)
             self._moe_dispatched(moe, len(ds), True)
         dispatch_s = self._lap(_DISPATCH) - t0
         if self.timeline:

@@ -314,7 +314,7 @@ class BlockManager:
     handed out."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 token_bytes: int = 0):
+                 token_bytes: int = 0, prefix_matching: bool = True):
         if num_blocks < 2:
             raise ValueError(f"need >= 2 blocks (block 0 is the reserved "
                              f"null block), got {num_blocks}")
@@ -334,6 +334,12 @@ class BlockManager:
         # budget — and every byte-denominated gauge derived here —
         # per DEVICE: same per-chip budget, tp× the blocks.
         self.token_bytes = int(token_bytes)
+        # False: the prefix index STANDS DOWN: :meth:`peek_prefix` and
+        # :meth:`peek_hosted` match nothing and :meth:`register_prefix`
+        # registers nothing. The engine's choice for a model whose
+        # requests carry state that lives outside the blocks (recurrent
+        # layers): a hit would hand a request K/V blocks and no state
+        self.prefix_matching = bool(prefix_matching)
         # LIFO free list: recently-freed (cache-warm) blocks are reused
         # first; block 0 excluded for good
         self._free = list(range(self.num_blocks - 1, 0, -1))
@@ -678,6 +684,8 @@ class BlockManager:
         generation)."""
         out: list[int] = []
         revivals = 0
+        if not self.prefix_matching:
+            return out, revivals
         parent = _CHAIN_ROOT
         for key, chunk in self.chain_keys(tokens):
             if max_blocks is not None and len(out) >= max_blocks:
@@ -734,6 +742,8 @@ class BlockManager:
         existing entry — the first writer wins, later identical blocks
         stay private and flow back to the free list on release."""
         registered = 0
+        if not self.prefix_matching:
+            return registered
         parent = _CHAIN_ROOT
         for i, (key, chunk) in enumerate(self.chain_keys(tokens)):
             if i >= len(table):
@@ -808,6 +818,8 @@ class BlockManager:
         admission probe re-runs every iteration)."""
         out: list[int] = []
         missed = False
+        if not self.prefix_matching:
+            return out, missed
         parent = _CHAIN_ROOT
         for i, (key, chunk) in enumerate(self.chain_keys(tokens)):
             if i < start:

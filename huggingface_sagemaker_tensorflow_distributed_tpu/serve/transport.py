@@ -132,6 +132,11 @@ def migrate_request(src, dst, rid: int, prefetched=None,
     if src is dst:
         raise TransportError(
             f"request {rid}: source and destination are the same engine")
+    if src._stateful or dst._stateful:
+        raise TransportError(
+            f"request {rid}: migrate_request is not wired for a model "
+            "with recurrent state: a BlockSet carries blocks and no "
+            "state (ROADMAP R3: state snapshots)")
     if pool_signature(src) != pool_signature(dst):
         raise TransportError(
             f"request {rid}: engine pool geometries differ "
