@@ -35,7 +35,14 @@ the real chip at real shapes:
   engine's ``key_valid`` and one with holes, bf16 and float32 — anchored
   on float64, and held closer than the others: the kernel's
   root-mean-square error may be 1.1 times the XLA form's, its largest 2
-  times.
+  times;
+- latent paged decode attention
+  (``ops/pallas_paged_latent_attention.py``) against the absorbed form
+  over a gathered cache (``models/deepseek_v2.py::attend_absorbed``) at
+  the same widths with all 128 heads: 8 slots whose contexts run from 0
+  to the 2,048 bucket's width through scattered pages of 16, bf16 and
+  float32, from the absorbed query to ``W_uv`` — anchored on float64
+  under the same two limits.
 
 Prints one PASS/FAIL line per check and exits non-zero on any FAIL.
 Run on the chip:  python benchmarks/tpu_kernel_parity.py
@@ -306,6 +313,70 @@ def latent_prefill_parity() -> None:
                 FAILED.append(name + " rms")
 
 
+def latent_decode_parity() -> None:
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models import (
+        deepseek_v2 as D,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
+        gather_paged_kv,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_latent_attention import (
+        paged_latent_decode_attention,
+    )
+
+    H, W, bs, rank, nope, rot, vd, row = 128, 2048, 16, 512, 128, 64, 128, 640
+    scale = D.DeepseekV2Config().softmax_scale
+    rng = np.random.RandomState(5)
+    ctx = np.asarray([0, 1, 16, 511, 512, 513, 1300, W], np.int32)
+    S, nb = len(ctx), W // bs
+    N = 1 + S * nb
+    tables = rng.permutation(np.arange(1, N)).reshape(S, nb).astype(np.int32)
+    live = ctx > 0
+    seen = np.arange(W)[None, :] < ctx[:, None]
+    for dtype in (jnp.bfloat16, jnp.float32):
+        # a normalised c and a rotated key of order one a row, queries of
+        # order one, W_kvb at what keeps the scores of order one
+        q_nope, q_pe, pool, w = [jnp.asarray(x, dtype) for x in (
+            rng.randn(S, 1, H, nope), rng.randn(S, 1, H, rot),
+            np.pad(rng.randn(N, bs, rank + rot),
+                   [(0, 0), (0, 0), (0, row - rank - rot)]),
+            rng.randn(rank, H, nope + vd) * 0.05)]
+        qn, qp, w64 = (np.asarray(x, np.float64) for x in (q_nope, q_pe, w))
+        lat = np.asarray(pool, np.float64)[tables].reshape(S, W, row)
+        q_lat = np.einsum("bshd,rhd->bshr", qn, w64[..., :nope])
+        logit = (np.einsum("bshr,bwr->bhsw", q_lat, lat[..., :rank])
+                 + np.einsum("bshd,bwd->bhsw", qp,
+                             lat[..., rank:rank + rot])) * scale
+        logit = np.where(seen[:, None, None], logit, -1e30)
+        p = np.exp(logit - logit.max(-1, keepdims=True))
+        o_lat = np.einsum("bhsw,bwr->bshr", p / p.sum(-1, keepdims=True),
+                          lat[..., :rank])
+        ref = np.einsum("bshr,rhd->bshd", o_lat, w64[..., nope:])[live]
+        tb, cx = jnp.asarray(tables), jnp.asarray(ctx)
+
+        def paged(q_nope, q_pe, pool, w):
+            o_lat = paged_latent_decode_attention(
+                D.absorbed_query(q_nope, q_pe, w, row, pool.dtype)[:, 0],
+                pool, tb, cx, rank=rank, scale=scale)
+            return D.absorbed_values(o_lat[:, None], w, nope)
+
+        def gathered(q_nope, q_pe, pool, w):
+            latent = gather_paged_kv(pool[:, :, None, :], tb)[:, 0]
+            bias = jnp.where(jnp.asarray(seen)[:, None, :], 0.0, D.NEG_INF)
+            return D.attend_absorbed(q_nope, q_pe, latent,
+                                     bias.astype(jnp.float32), w, rank=rank,
+                                     scale=scale)
+
+        got = {name: np.asarray(jax.jit(fn)(q_nope, q_pe, pool, w),
+                                np.float64)[live]
+               for name, fn in (("pallas", paged), ("xla", gathered))}
+        # bf16 rounds the folded query, the weights and the output
+        check_anchored(f"latent paged decode ({jnp.dtype(dtype).name})",
+                       got["pallas"], got["xla"], ref,
+                       floor=4e-3 if dtype == jnp.bfloat16 else 1e-6,
+                       ceiling=3e-2, label="pallas")
+
+
 def vocab_ce_parity() -> None:
     import optax
 
@@ -394,6 +465,7 @@ def main() -> None:
     vocab_ce_parity()
     paged_parity()
     latent_prefill_parity()
+    latent_decode_parity()
     if FAILED:
         print(f"FAILED: {FAILED}")
         sys.exit(1)

@@ -22,7 +22,19 @@ What differs from the Llama layout (``models/llama.py``, whose
   * *absorbed* (one query a row, the decode step): ``W_kvb``'s key half
     is folded into the query (``q_nope W_uk^T``) and its value half is
     applied after the weighted sum, so attention runs in the latent
-    space and the cache is never expanded;
+    space and the cache is never expanded. Over a contiguous cache
+    (``generate_causal``, the serving engine's gather step) that is
+    :func:`attend_absorbed`; where the cache scope holds a
+    ``block_tables`` sibling (the engine's paged step, its choice on a
+    TPU) the variable ``cached_latent`` is the layer's POOL
+    ``[num_blocks, block_size, width]``: the step's row is written into
+    it in place and ONE fused kernel attends the pages each row holds
+    (``ops/pallas_paged_latent_attention.py``), with no copy of the
+    cache. A layer-call of 32 slots at doc-sat's contexts (2,860 a
+    slot in the 8,192 bucket) is 0.34 ms by the kernel (38% of the
+    MXU's peak) where the gather's four operations were 3.5 ms of the
+    step's program (my chip runs, PR 34, and the ledger's PR 33 line;
+    PERF.md 6);
   * *expanded* (a chunk of queries, prefill and the plain forward): the
     latent rows are expanded to per-head ``k_nope | v`` through
     ``W_kvb``, a block of keys at a time under a running softmax, so that
@@ -97,6 +109,12 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.models.moe import (
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
     pallas_latent_attention,
+)
+from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
+    scatter_paged_kv,
+)
+from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_latent_attention import (
+    paged_latent_decode_attention,
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
     maybe_current_mesh,
@@ -437,6 +455,24 @@ def _kernel_bwd(rank, scale, block, res, g):
 attend_expanded_kernel.defvjp(_kernel_fwd, _kernel_bwd)
 
 
+def absorbed_query(q_nope, q_pe, w_kvb, width: int, dtype):
+    """The query of the absorbed form, ``q_nope W_uk^T | q_pe`` padded
+    with zeros to the cached row's ``width``: [B, 1, H, width] in the
+    cache's ``dtype``, so that a score is one dot product with the row
+    as stored."""
+    nope = q_nope.shape[-1]
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :nope])
+    q_cat = jnp.concatenate([q_lat.astype(dtype), q_pe], axis=-1)
+    return jnp.pad(q_cat, [(0, 0)] * 3 + [
+        (0, width - q_cat.shape[-1])])                  # the row's zeros
+
+
+def absorbed_values(o_lat, w_kvb, nope: int):
+    """``W_uv`` after the weighted sum: ``o_lat`` [B, 1, H, rank] in the
+    latent space to [B, 1, H, v]."""
+    return jnp.einsum("bshr,rhd->bshd", o_lat, w_kvb[..., nope:])
+
+
 def attend_absorbed(q_nope, q_pe, latent, bias, w_kvb, *, rank: int,
                     scale: float):
     """Latent attention, absorbed form, for ONE query a row (the decode
@@ -446,19 +482,18 @@ def attend_absorbed(q_nope, q_pe, latent, bias, w_kvb, *, rank: int,
     themselves: ``score = (q_nope W_uk^T | q_pe) . (c | k_pe) * scale``,
     ``out = (softmax(score) c) W_uv``. (For a chunk of queries it
     measured slower than the expanded form on every dispatch: PERF.md
-    6, PR 28.)"""
+    6, PR 28.) This is the form over a GATHERED cache ``latent`` [B, W,
+    width]; over the pages of a pool the middle of it is one fused
+    kernel (``DeepseekV2Attention``'s paged branch)."""
     assert q_nope.shape[1] == 1, "absorbed attention takes one query a row"
-    nope = q_nope.shape[-1]
-    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :nope])
-    q_cat = jnp.concatenate([q_lat.astype(latent.dtype), q_pe], axis=-1)
-    q_cat = jnp.pad(q_cat, [(0, 0)] * 3 + [
-        (0, latent.shape[-1] - q_cat.shape[-1])])       # the row's zeros
+    q_cat = absorbed_query(q_nope, q_pe, w_kvb, latent.shape[-1],
+                           latent.dtype)
     scores = jnp.einsum("bshc,bwc->bhsw", q_cat, latent,
                         preferred_element_type=jnp.float32) * scale
     p = jax.nn.softmax(scores + bias[:, None], axis=-1).astype(latent.dtype)
     o_lat = jnp.einsum("bhsw,bwr->bshr", p, latent[..., :rank])
-    return jnp.einsum("bshr,rhd->bshd", o_lat.astype(latent.dtype),
-                      w_kvb[..., nope:])
+    return absorbed_values(o_lat.astype(latent.dtype), w_kvb,
+                           q_nope.shape[-1])
 
 
 def _to_half_split(x):
@@ -505,6 +540,30 @@ class DeepseekV2Attention(nn.Module):
                                    (B, 1, S, width), latent.dtype)
             cache_index = self.variable(
                 "cache", "cache_index", lambda: jnp.zeros((B,), jnp.int32))
+            if self.has_variable("cache", "block_tables"):
+                # the serving engine's paged decode step: ``cached``
+                # holds the layer's POOL [num_blocks, block_size, width]
+                # and a block table a row rides beside the write index.
+                # The step's row goes into the pool in place, then ONE
+                # fused kernel attends the pages each row holds, in the
+                # absorbed form: no copy of the cache is assembled
+                if S != 1:
+                    raise ValueError(
+                        "paged decode is single-token (the fused kernel "
+                        f"takes one query a row, got q_len {S})")
+                tables = self.get_variable("cache", "block_tables")
+                cur = cache_index.value                         # [B]
+                cached.value = scatter_paged_kv(cached.value, tables, cur,
+                                                latent[:, 0])
+                cache_index.value = cur + 1
+                o_lat = paged_latent_decode_attention(
+                    absorbed_query(q_nope, q_pe, w_kvb, width,
+                                   latent.dtype)[:, 0],
+                    cached.value, tables, cur + 1, rank=rank,
+                    scale=cfg.softmax_scale)
+                ctx = absorbed_values(o_lat[:, None], w_kvb, nope)
+                return _dense(cfg, cfg.hidden_size, "o_proj")(
+                    ctx.reshape(B, 1, H * vd))
             if is_init:
                 # the write protocol of models/llama.py::write_kv_cache:
                 # each row's new latent rows at its own write index

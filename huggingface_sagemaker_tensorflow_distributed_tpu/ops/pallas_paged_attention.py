@@ -66,6 +66,10 @@ once, and nothing else (Kwon et al. 2023 pay a single fused read here):
   rehearsal compile, PR 29), so the result is right and not fast, and
   the serving engine's own choice never takes the kernel there.
 
+The walk itself (:func:`page_copies`, :func:`walk_slots`) knows nothing
+of K/V: ``ops/pallas_paged_latent_attention.py`` runs it over ONE latent
+pool with a body of its own (PR 34).
+
 Inactive rows (``context_len == 0``) return ZEROS (the XLA path returns
 a softmax over fully-masked junk instead — callers discard those rows
 either way).
@@ -140,32 +144,13 @@ def head_major_rows(kv_heads: int) -> bool:
     return kv_heads % 8 != 0 and kv_heads not in (1, 2, 4)
 
 
-def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
-                  block_size, kv_heads, pages, table_width, window, int8,
-                  head_major=False):
-    """The whole call: every slot's walk over its pages.
-
-    ``tbl_ref`` (SMEM, ``[slots * table_width]``) and ``ctx_ref`` (SMEM,
-    ``[slots]``) drive the DMAs; ``q_ref`` / ``o_ref`` are ``[slots, H,
-    D]`` in VMEM; ``head_bias_ref`` ``[H, pages * rows]`` is 0 where a
-    block's row belongs to the query head's kv head and -1e30 elsewhere.
-    The pools (``[N, rows, D]``, rows = block_size * kv_heads) stay in
-    HBM; ``bufs`` are their double buffers ``[2, pages, rows, D]``. With
-    ``int8`` two more VMEM inputs follow the bias: the gathered K and V
-    scales, ``[slots, 1, (table_width + pages) * rows]``. With
-    ``head_major`` (never with ``int8``) one VMEM input follows the bias
-    instead: ``[1, pages * rows]`` int32, each row's key offset within a
-    block."""
-    key_off_ref, refs = (refs[0], refs[1:]) if head_major else (None, refs)
-    scales, refs = (refs[:2], refs[2:]) if int8 else ((), refs)
-    pools, o_ref, bufs, sem = refs[:2], refs[2], refs[3:5], refs[5]
-    num_slots, num_heads, _ = q_ref.shape
-    rows = block_size * kv_heads
-    P = pages
-    # a scale vector is sliced along lanes at a block's first row: keep
-    # that a multiple of the lane width by starting a banded walk on a
-    # page that is one
-    align = 128 // math.gcd(rows, 128) if int8 else 1
+def page_copies(tbl_ref, pools, bufs, sem, table_width: int, P: int):
+    """``(start, wait)`` of a compute block's page copies: from each of
+    ``pools`` (HBM, ``[N, rows, D]``) by the block table ``tbl_ref``
+    (SMEM, ``[slots * table_width]``) into ``bufs`` (VMEM, ``[2, P, rows,
+    D]`` each), signalled on ``sem`` (``[2, len(pools)]``). Both take
+    ``(slot, first table column, pages that hold keys <= P, buffer
+    half)``."""
 
     def copies(s, page, buf_slot, p):
         """Page ``p`` of a block starting at table column ``page``."""
@@ -212,6 +197,87 @@ def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
             each_page(n, lambda p: [c.wait()
                                     for c in copies(s, page, buf_slot, p)])
 
+    return start, wait
+
+
+def walk_slots(num_slots: int, P: int, walk, start, wait, init, attend,
+               finish):
+    """Every slot's walk over its compute blocks, the next block's pages
+    in flight while one is attended, across slot boundaries too.
+    ``walk(s)`` gives slot ``s``'s ``(context, first page, end page)``;
+    ``start`` / ``wait`` are :func:`page_copies`'; a slot's running state
+    starts as ``init(s)``, ``attend(s, ctx, page, buf_slot, state)`` folds
+    the landed block at table column ``page`` into it, and ``finish(s,
+    state)`` writes the slot's output (a slot that walks no page
+    finishes on ``init(s)``)."""
+
+    def slot_body(s, carry):
+        done, prefetched = carry     # blocks so far; is my first in flight
+        ctx, first, end = walk(s)
+        nblk = lax.div(end - first + (P - 1), jnp.int32(P))
+        nxt = jnp.minimum(s + 1, num_slots - 1)
+        _, first_n, end_n = walk(nxt)
+        next_walks = jnp.logical_and(s + 1 < num_slots, end_n > first_n)
+
+        @pl.when(jnp.logical_and(nblk > 0, prefetched == 0))
+        def _first():
+            start(s, first, jnp.minimum(P, end - first), done % 2)
+
+        def block_body(b, state):
+            buf_slot = (done + b) % 2
+            page = first + b * P
+            n = jnp.minimum(P, end - page)
+
+            # the next block's pages fly while this one is attended: this
+            # slot's next block, or the next slot's first
+            @pl.when(b + 1 < nblk)
+            def _ahead():
+                start(s, page + P, jnp.minimum(P, end - page - P),
+                      1 - buf_slot)
+
+            @pl.when(jnp.logical_and(b + 1 == nblk, next_walks))
+            def _ahead_slot():
+                start(nxt, first_n, jnp.minimum(P, end_n - first_n),
+                      1 - buf_slot)
+
+            wait(s, page, n, buf_slot)
+            return attend(s, ctx, page, buf_slot, state)
+
+        finish(s, lax.fori_loop(0, nblk, block_body, init(s)))
+        return (done + nblk,
+                jnp.logical_and(nblk > 0, next_walks).astype(jnp.int32))
+
+    lax.fori_loop(0, num_slots, slot_body, (jnp.int32(0), jnp.int32(0)))
+
+
+def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
+                  block_size, kv_heads, pages, table_width, window, int8,
+                  head_major=False):
+    """The whole call: every slot's walk over its pages.
+
+    ``tbl_ref`` (SMEM, ``[slots * table_width]``) and ``ctx_ref`` (SMEM,
+    ``[slots]``) drive the DMAs; ``q_ref`` / ``o_ref`` are ``[slots, H,
+    D]`` in VMEM; ``head_bias_ref`` ``[H, pages * rows]`` is 0 where a
+    block's row belongs to the query head's kv head and -1e30 elsewhere.
+    The pools (``[N, rows, D]``, rows = block_size * kv_heads) stay in
+    HBM; ``bufs`` are their double buffers ``[2, pages, rows, D]``. With
+    ``int8`` two more VMEM inputs follow the bias: the gathered K and V
+    scales, ``[slots, 1, (table_width + pages) * rows]``. With
+    ``head_major`` (never with ``int8``) one VMEM input follows the bias
+    instead: ``[1, pages * rows]`` int32, each row's key offset within a
+    block."""
+    key_off_ref, refs = (refs[0], refs[1:]) if head_major else (None, refs)
+    scales, refs = (refs[:2], refs[2:]) if int8 else ((), refs)
+    pools, o_ref, bufs, sem = refs[:2], refs[2], refs[3:5], refs[5]
+    num_slots, num_heads, _ = q_ref.shape
+    rows = block_size * kv_heads
+    P = pages
+    # a scale vector is sliced along lanes at a block's first row: keep
+    # that a multiple of the lane width by starting a banded walk on a
+    # page that is one
+    align = 128 // math.gcd(rows, 128) if int8 else 1
+    start, wait = page_copies(tbl_ref, pools, bufs, sem, table_width, P)
+
     def walk(s):
         """(context, first page, end page) of slot ``s``."""
         ctx = ctx_ref[s]
@@ -245,84 +311,55 @@ def _paged_kernel(tbl_ref, ctx_ref, q_ref, head_bias_ref, *refs, scale,
         return scales[i][s, :, pl.ds(pl.multiple_of(page * rows, 128),
                                      P * rows)]
 
-    def slot_body(s, carry):
-        done, prefetched = carry     # blocks so far; is my first in flight
-        ctx, first, end = walk(s)
-        nblk = lax.div(end - first + (P - 1), jnp.int32(P))
-        nxt = jnp.minimum(s + 1, num_slots - 1)
-        _, first_n, end_n = walk(nxt)
-        next_walks = jnp.logical_and(s + 1 < num_slots, end_n > first_n)
+    def init(s):
+        return (jnp.full((num_heads, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((num_heads, 1), jnp.float32),
+                jnp.zeros(o_ref.shape[1:], jnp.float32))
 
-        @pl.when(jnp.logical_and(nblk > 0, prefetched == 0))
-        def _first():
-            start(s, first, jnp.minimum(P, end - first), done % 2)
+    def attend(s, ctx, page, buf_slot, state):
+        m, l, acc = state
+        k = load(0, buf_slot)
+        logits = lax.dot_general(
+            q_ref[s], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=precision) * scale          # [H, P * rows]
+        if int8:
+            logits = logits * scale_row(0, s, page)
+        # row c of the block is key (page * block_size + c // kv_heads)
+        # of kv head c % kv_heads: the head through the bias, the
+        # position without a division
+        base = page * block_size
+        if head_major:
+            # rows run (page, kv head, key): the key's offset is read
+            off = key_off_ref[...]
+            keep = off < ctx - base
+            if window is not None:
+                keep = jnp.logical_and(keep, off >= ctx - window - base)
+        else:
+            keep = col < (ctx - base) * kv_heads
+            if window is not None:
+                keep = jnp.logical_and(
+                    keep, col >= (ctx - window - base) * kv_heads)
+        logits = jnp.where(keep, logits + head_bias_ref[...], _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(logits - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        if int8:
+            # a scale past the context may be junk: 0 * NaN again
+            p = jnp.where(keep, p * scale_row(1, s, page), 0.0)
+        acc_new = alpha * acc + lax.dot_general(
+            p.astype(compute), load(1, buf_slot),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        return m_new, l_new, acc_new
 
-        def block_body(b, state):
-            m, l, acc = state
-            buf_slot = (done + b) % 2
-            page = first + b * P
-            n = jnp.minimum(P, end - page)
-
-            # the next block's pages fly while this one is attended: this
-            # slot's next block, or the next slot's first
-            @pl.when(b + 1 < nblk)
-            def _ahead():
-                start(s, page + P, jnp.minimum(P, end - page - P),
-                      1 - buf_slot)
-
-            @pl.when(jnp.logical_and(b + 1 == nblk, next_walks))
-            def _ahead_slot():
-                start(nxt, first_n, jnp.minimum(P, end_n - first_n),
-                      1 - buf_slot)
-
-            wait(s, page, n, buf_slot)
-            k = load(0, buf_slot)
-            logits = lax.dot_general(
-                q_ref[s], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=precision) * scale          # [H, P * rows]
-            if int8:
-                logits = logits * scale_row(0, s, page)
-            # row c of the block is key (page * block_size + c // kv_heads)
-            # of kv head c % kv_heads: the head through the bias, the
-            # position without a division
-            base = page * block_size
-            if head_major:
-                # rows run (page, kv head, key): the key's offset is read
-                off = key_off_ref[...]
-                keep = off < ctx - base
-                if window is not None:
-                    keep = jnp.logical_and(keep, off >= ctx - window - base)
-            else:
-                keep = col < (ctx - base) * kv_heads
-                if window is not None:
-                    keep = jnp.logical_and(
-                        keep, col >= (ctx - window - base) * kv_heads)
-            logits = jnp.where(keep, logits + head_bias_ref[...], _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(logits - m_new)
-            l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-            if int8:
-                # a scale past the context may be junk: 0 * NaN again
-                p = jnp.where(keep, p * scale_row(1, s, page), 0.0)
-            acc_new = alpha * acc + lax.dot_general(
-                p.astype(compute), load(1, buf_slot),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=precision)
-            return m_new, l_new, acc_new
-
-        m, l, acc = lax.fori_loop(
-            0, nblk, block_body,
-            (jnp.full((num_heads, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((num_heads, 1), jnp.float32),
-             jnp.zeros(o_ref.shape[1:], jnp.float32)))
+    def finish(s, state):
+        _, l, acc = state
         # a context-0 (inactive) row walks no page: l == 0, output 0
         o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-        return (done + nblk,
-                jnp.logical_and(nblk > 0, next_walks).astype(jnp.int32))
 
-    lax.fori_loop(0, num_slots, slot_body, (jnp.int32(0), jnp.int32(0)))
+    walk_slots(num_slots, P, walk, start, wait, init, attend, finish)
 
 
 @functools.partial(

@@ -58,20 +58,24 @@ speculation; vLLM + Orca + Sarathi + Leviathan lineage):
   them. Writes into still-shared blocks privatize first via a
   device-side block copy (COW) — output stays token-exact vs cold
   start.
-- **Fused paged-attention kernel** (``decode_path='paged_kernel'``) —
+- **Fused paged-attention kernels** (``decode_path='paged_kernel'``) —
   the decode step's gather→dense-attend HBM round trip collapses into
   ONE fused read: the model's paged decode branch scatters each slot's
-  new K/V straight into the pools and attends via the Pallas kernel
-  (``ops/pallas_paged_attention.py``), which walks the block tables
-  inside the attention read — no ``[S, H, width, D]`` intermediate, and
+  new K/V (a latent-attention model: its one latent row) straight into
+  the pools and attends via a Pallas kernel that walks the block tables
+  inside the attention read (``ops/pallas_paged_attention.py`` for K/V
+  pools; ``ops/pallas_paged_latent_attention.py`` for latent pools, the
+  absorbed form, ISSUE 34) — no ``[S, H, width, D]`` intermediate, and
   no page past a slot's context is read. Rides the same bucket ladder
-  (one compile per bucket). It IS the decode path where
-  :func:`resolve_decode_path` finds it measured to win: on a TPU, for
-  K/V pools of 128-wide heads stored in a floating type, without a
-  mesh. Everywhere else the gather path decodes: on a CPU (the kernel
-  is interpret mode there: correct but slow, and the tests' reference
-  is the gather), for latent pools, under a tensor-parallel mesh, and
-  in the speculative step. ``kernel='xla' | 'pallas'`` forces either.
+  (one compile per bucket), and returns what the gather step returns
+  (the routed experts' pair counts among it). It IS the decode path
+  where :func:`resolve_decode_path` finds it measured to win: on a TPU,
+  without a mesh, for pools stored in a floating type that are all K/V
+  of 128-wide heads or all latent rows of whole lane tiles. Everywhere
+  else the gather path decodes: on a CPU (a kernel is interpret mode
+  there: correct but slow, and the tests' reference is the gather),
+  under a tensor-parallel mesh, for int8 pools, and in the speculative
+  step. ``kernel='xla' | 'pallas'`` forces either.
 - **int8 KV pools** (``kv_cache_dtype='int8'``) — pools store K/V as
   symmetric per-(position, head) int8 with fp32 scales riding parallel
   scale pools (written by the model's own ``kv_quantize`` protocol at
@@ -255,10 +259,11 @@ def parse_tp(spec) -> int:
 def parse_kernel(spec: Union[str, None]) -> Optional[str]:
     """The decode-kernel knob: ``xla`` forces the gather path (gather +
     dense attention — the reference, CPU-native), ``pallas`` the fused
-    paged-decode kernel (``ops/pallas_paged_attention.py`` —
-    interpret-mode off TPU). None reads ``HSTD_SERVE_KERNEL``; with that
-    unset too the answer is None: the engine chooses
-    (:func:`resolve_decode_path`)."""
+    paged-decode kernel of the model's pools
+    (``ops/pallas_paged_attention.py``, or for latent pools
+    ``ops/pallas_paged_latent_attention.py`` — interpret-mode off TPU).
+    None reads ``HSTD_SERVE_KERNEL``; with that unset too the answer is
+    None: the engine chooses (:func:`resolve_decode_path`)."""
     if spec is None:
         spec = os.environ.get(ENV_KERNEL)
     if spec is None or not str(spec).strip():
@@ -281,25 +286,29 @@ _KERNEL_HEAD_DIMS = (128,)
 
 
 def resolve_decode_path(kernel: Optional[str], *, platform: str,
-                        pool_kinds: Sequence[str], routed: bool,
-                        mesh: bool, head_dim: int, kv_dtype: str) -> str:
-    """Which way a decode step attends: ``paged_kernel`` (the fused
-    kernel walks the block tables; ``_paged_decode_step``) or ``gather``
-    (a bucket-wide copy of the cache; ``_decode_step``). A pure function
-    of what the engine can see: the backend's ``platform``, the kinds of
-    the plan's pooled leaves (``kv`` | ``latent``), whether the model
-    routes tokens to experts, whether a tensor-parallel ``mesh`` is on,
-    the pools' ``head_dim`` and storage ``kv_dtype`` (``fp`` | ``int8``),
-    and the explicit ``kernel`` (``xla`` | ``pallas`` | None = choose).
+                        pool_kinds: Sequence[str], mesh: bool,
+                        head_dim: int, kv_dtype: str) -> str:
+    """Which way a decode step attends: ``paged_kernel`` (a fused kernel
+    walks the block tables; ``_paged_decode_step``) or ``gather`` (a
+    bucket-wide copy of the cache; ``_decode_step``). A pure function of
+    what the engine can see: the backend's ``platform``, the kinds of the
+    plan's pooled leaves (``kv`` | ``latent``), whether a tensor-parallel
+    ``mesh`` is on, the pools' ``head_dim`` (a latent pool's row width)
+    and storage ``kv_dtype`` (``fp`` | ``int8``), and the explicit
+    ``kernel`` (``xla`` | ``pallas`` | None = choose). Whether the model
+    routes tokens to experts is no input: both steps count its pairs.
 
-    An explicit value wins, and ``pallas`` raises where the kernel has
-    no form: latent pools (and routed experts, whose step it does not
-    count), a mesh. Left to choose, the kernel is taken only where it was
-    measured to win: on a TPU, K/V pools, no mesh, a head size of
-    ``_KERNEL_HEAD_DIMS``, pools in a floating type (an int8 pool of two
-    KV heads is stored head-major within a page on the v5e and would be
-    copied whole for every call: rehearsal compile, PR 29)."""
-    fits = all(k == "kv" for k in pool_kinds) and not routed
+    An explicit value wins, and ``pallas`` raises where no kernel has a
+    form: a mesh, a plan that mixes K/V and latent pools (each kernel
+    walks its own kind; no model holds both). Left to choose, a kernel is
+    taken only where it was measured to win: on a TPU, no mesh, pools in
+    a floating type (an int8 pool of two KV heads is stored head-major
+    within a page on the v5e and would be copied whole for every call:
+    rehearsal compile, PR 29), and then for K/V pools with a head size of
+    ``_KERNEL_HEAD_DIMS`` (``ops/pallas_paged_attention.py``) and for
+    latent pools whose rows are whole lane tiles
+    (``ops/pallas_paged_latent_attention.py``: PERF.md 6, PR 34)."""
+    kinds = set(pool_kinds)
     if kernel == "pallas":
         if mesh:
             raise ValueError(
@@ -308,16 +317,17 @@ def resolve_decode_path(kernel: Optional[str], *, platform: str,
                 "would need a shard_map port — serve TP with the xla "
                 "gather path (the kernel is a per-chip bandwidth "
                 "optimization; TP is a capacity one)")
-        if not fits:
+        if len(kinds) > 1:
             raise ValueError(
-                "kernel='pallas' (the fused paged K/V kernel) has no "
-                "latent-attention form: serve this model with the "
-                "xla gather path")
+                "kernel='pallas': no fused paged kernel has a form for a "
+                f"plan that mixes pool kinds ({sorted(kinds)}): serve "
+                "this model with the xla gather path")
         return "paged_kernel"
     if kernel == "xla":
         return "gather"
-    chosen = (platform == "tpu" and fits and not mesh
-              and head_dim in _KERNEL_HEAD_DIMS and kv_dtype == "fp")
+    fits = ((kinds == {"kv"} and head_dim in _KERNEL_HEAD_DIMS)
+            or (kinds == {"latent"} and head_dim % 128 == 0))
+    chosen = platform == "tpu" and fits and not mesh and kv_dtype == "fp"
     return "paged_kernel" if chosen else "gather"
 
 
@@ -772,19 +782,19 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
 
 def _paged_cache(plan: CachePlan, pools, block_tables, context_lens,
                  state_rows=()):
-    """The model-facing PAGED cache pytree (kernel mode): every KV leaf
-    is its whole block pool (no gather — the fused kernel walks the
-    tables in-attention), write indices are the context lengths, and a
-    ``block_tables`` leaf rides next to each attention scope's
-    ``cache_index`` (the marker the model's paged decode branch keys
-    on). Built as a nested dict from the plan's recorded paths — the
+    """The model-facing PAGED cache pytree (kernel mode): every K/V or
+    latent leaf is its whole block pool (no gather — the model's fused
+    kernel walks the tables in-attention), write indices are the context
+    lengths, and a ``block_tables`` leaf rides next to each attention
+    scope's ``cache_index`` (the marker the model's paged decode branch
+    keys on). Built as a nested dict from the plan's recorded paths — the
     treedef can't be reused because of the injected sibling."""
     root: dict = {}
     for path, kind in zip(plan.paths, plan.kinds):
         node = root
         for key in path[:-1]:
             node = node.setdefault(key, {})
-        if kind[0] == "kv":
+        if kind[0] in _POOLED:
             node[path[-1]] = pools[kind[1]]
         elif kind[0] == "state":
             node[path[-1]] = state_rows[kind[1]]
@@ -801,13 +811,14 @@ def _paged_decode_step(model, params, pools, tokens, block_tables,
                        folds, plan: CachePlan, width: int, sampled: bool,
                        states=()):
     """One FUSED decode iteration over all slots (kernel mode): the
-    model's paged decode branch scatters each slot's new K/V straight
-    into the pools and attends via the Pallas paged kernel — no dense
-    [S, H, width, D] intermediate is ever materialized. ``width``
-    restricts the block-table walk to the iteration's gather bucket
-    (same ladder, same compile-per-bucket contract as the XLA path);
-    inactive slots route writes to null block 0 at context 0. ``states``
-    as in :func:`_decode_step`."""
+    model's paged decode branch scatters each slot's new K/V (or latent
+    row) straight into the pools and attends via its Pallas paged kernel
+    — no dense [S, H, width, D] intermediate is ever materialized.
+    ``width`` restricts the block-table walk to the iteration's gather
+    bucket (same ladder, same compile-per-bucket contract as the XLA
+    path); inactive slots route writes to null block 0 at context 0.
+    Returns what :func:`_decode_step` returns, in the same positions: the
+    routed counts of a model with routed experts, ``states`` last."""
     bs = pools[0].shape[1]
     tables = block_tables[:, :width // bs]
     safe_tables = jnp.where(active[:, None], tables, 0)
@@ -816,8 +827,9 @@ def _paged_decode_step(model, params, pools, tokens, block_tables,
     logits, mut = model.apply(
         {"params": params, "cache": cache}, tokens[:, None], None,
         position_ids=ctx[:, None], decode=True, deterministic=True,
-        mutable=["cache"],
-        **({"token_mask": active[:, None]} if states else {}))
+        mutable=["cache", "moe_stats"] if _routes(model) else ["cache"],
+        **({"token_mask": active[:, None]}
+           if _masks_tokens(model, plan) else {}))
     last = logits[:, -1, :].astype(jnp.float32)
     if sampled:
         next_tok = sample_per_slot(last, temps, top_ks, top_ps, keys, folds)
@@ -831,11 +843,12 @@ def _paged_decode_step(model, params, pools, tokens, block_tables,
                      for p in path): leaf for path, leaf in flat}
     new_pools, new_states = list(pools), list(states)
     for path, kind in zip(plan.paths, plan.kinds):
-        if kind[0] == "kv":
+        if kind[0] in _POOLED:
             new_pools[kind[1]] = by_path[path]
         elif kind[0] == "state":
             new_states[kind[1]] = by_path[path]
-    return (next_tok, new_pools, *((new_states,) if states else ()))
+    return (next_tok, new_pools, *_moe_counts(mut),
+            *((new_states,) if states else ()))
 
 
 def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
@@ -1328,12 +1341,13 @@ class ServeEngine:
     paged-decode kernel — gather folded into the attention read, int8
     dequant in-tile, no page outside the context or the sliding band
     read. Left to choose (:func:`resolve_decode_path`), the engine takes
-    the kernel on a TPU for K/V pools of 128-wide heads in a floating
-    type without a mesh, and the gather path anywhere else; the path
+    a kernel on a TPU without a mesh for pools in a floating type (K/V
+    pools of 128-wide heads; latent pools, through the model's own
+    absorbed-form kernel), and the gather path anywhere else; the path
     taken is ``decode_path`` in ``stats()``, the ``report`` event and
     the ``serve/decode_step`` span's arguments. Speculative
     engines keep draft/verify on the assembled path either way (the
-    kernel is single-token). A latent-attention model chooses for its
+    kernels are single-token). A latent-attention model chooses for its
     own prefill chunks (its ``expanded_form``: a fused kernel on a TPU,
     an XLA loop elsewhere); the engine writes the answer beside
     ``latent_path`` on each ``serve/prefill_chunk`` span and counts the
@@ -1555,7 +1569,7 @@ class ServeEngine:
         path = resolve_decode_path(
             kernel, platform=jax.default_backend(),
             pool_kinds=[k[0] for k in plan.kinds if k[0] in _POOLED],
-            routed=self._routes, mesh=self.mesh is not None,
+            mesh=self.mesh is not None,
             head_dim=max(d for _h, d, _dt in pool_shapes),
             kv_dtype=self.kv_cache_dtype)
         self.kernel = "pallas" if path == "paged_kernel" else "xla"

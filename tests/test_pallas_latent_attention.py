@@ -6,7 +6,9 @@ CPU: the same mathematics, the same mask from ``start`` and
 (``expanded_form``: a pure function of platform, mesh, shapes and type),
 the model's plain forward by either form, the gradient through the
 kernel form, and the kernel compiled for the v5e at the published
-widths."""
+widths, with the paged DECODE kernel of the absorbed form
+(``ops/pallas_paged_latent_attention.py``, ISSUE 34) beside it at the
+cell's shape: one file describes the topology."""
 
 import dataclasses
 
@@ -282,3 +284,49 @@ def test_the_kernel_compiles_for_the_v5e_at_published_widths(
     # queries head-major and padded, and nothing else of any size
     q_bytes = rows * H * C * 256 * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes <= 2.1 * q_bytes
+
+
+@pytest.mark.parametrize("slots", [32, 64])
+def test_the_paged_decode_kernel_compiles_for_the_v5e_at_the_cells_shape(
+        one_chip, slots):
+    """The fused paged DECODE kernel of the absorbed form
+    (``ops/pallas_paged_latent_attention.py``, ISSUE 34; its parity
+    tests are ``tests/test_paged_latent_kernel.py``) at doc-sat's shape:
+    32 slots of 128 heads against a pool of 19,531 pages of ``[16,
+    640]`` bf16 through tables of the 8,192 bucket, the step's row
+    written first as the model's paged branch writes it. The pool is
+    written where it lies and read by the kernel from there: no copy of
+    it is in the program, and nothing else of any size. Twice the slots
+    compile too: a slot's query and output pass through the kernel's
+    fast memory one at a time."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
+        scatter_paged_kv,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_latent_attention import (
+        paged_latent_decode_attention,
+    )
+
+    cfg = _PUBLISHED
+    row, pages = D.latent_width(cfg), 19531
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(q, pool, tables, ctx, new):
+        pool = scatter_paged_kv(pool, tables, ctx, new)
+        return paged_latent_decode_attention(
+            q, pool, tables, ctx + 1, rank=cfg.kv_lora_rank,
+            scale=cfg.softmax_scale, interpret=False), pool
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        sds((slots, cfg.num_heads, row)), sds((pages, 16, row)),
+        sds((slots, 8192 // 16), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, row))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pool_ops = [line for line in text.splitlines()
+                if f"= bf16[{pages},16,{row}]" in line]
+    assert pool_ops and not any(" copy(" in line for line in pool_ops)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= 1 << 20
+    assert memory.alias_size_in_bytes >= pages * 16 * row * 2

@@ -1305,31 +1305,42 @@ def test_kv_pool_bytes_doubles_int8_admission(gpt2_setup):
             >= 2 * fp_eng.stats().peak_resident_requests)
 
 
-_SEEN = dict(platform="tpu", pool_kinds=("kv", "kv"), routed=False,
-             mesh=False, head_dim=128, kv_dtype="fp")
+_SEEN = dict(platform="tpu", pool_kinds=("kv", "kv"), mesh=False,
+             head_dim=128, kv_dtype="fp")
+# a latent-attention plan as the engine sees it: one pool a layer, the
+# "head size" its row width (640 for the published 576)
+_LATENT = {"pool_kinds": ("latent", "latent"), "head_dim": 640}
 
 
 @pytest.mark.parametrize("kernel,seen,want", [
-    # left to choose: the kernel where it was measured to win ...
+    # left to choose: a kernel where it was measured to win ...
     (None, {}, "paged_kernel"),
+    (None, _LATENT, "paged_kernel"),
     # ... and the gather path for anything else the engine can see
     (None, {"platform": "cpu"}, "gather"),
     (None, {"platform": "gpu"}, "gather"),
-    (None, {"pool_kinds": ("latent",)}, "gather"),
+    (None, {**_LATENT, "platform": "cpu"}, "gather"),
     (None, {"pool_kinds": ("kv", "latent")}, "gather"),
-    (None, {"routed": True}, "gather"),
+    (None, {"pool_kinds": ("kv", "latent"), "head_dim": 640}, "gather"),
     (None, {"mesh": True}, "gather"),
+    (None, {**_LATENT, "mesh": True}, "gather"),
     (None, {"head_dim": 64}, "gather"),
+    (None, {**_LATENT, "head_dim": 576}, "gather"),
     (None, {"kv_dtype": "int8"}, "gather"),
+    (None, {**_LATENT, "kv_dtype": "int8"}, "gather"),
     # an explicit value wins, on any platform
     ("xla", {}, "gather"),
+    ("xla", _LATENT, "gather"),
     ("pallas", {"platform": "cpu"}, "paged_kernel"),
     ("pallas", {"platform": "cpu", "head_dim": 64, "kv_dtype": "int8"},
      "paged_kernel"),
-    # ... except where the kernel has no form
-    ("pallas", {"pool_kinds": ("latent",)}, "no latent-attention form"),
-    ("pallas", {"routed": True}, "no latent-attention form"),
+    # ... a latent-attention model with routed experts among them (the
+    # paged step counts the pairs as the gather step does: ISSUE 34)
+    ("pallas", {**_LATENT, "platform": "cpu"}, "paged_kernel"),
+    # ... except where no kernel has a form
+    ("pallas", {"pool_kinds": ("kv", "latent")}, "mixes pool kinds"),
     ("pallas", {"mesh": True}, "tensor-parallel"),
+    ("pallas", {**_LATENT, "mesh": True}, "tensor-parallel"),
 ])
 def test_decode_path_is_a_function_of_what_the_engine_sees(kernel, seen,
                                                            want):

@@ -1,9 +1,12 @@
 """A latent-attention model with dropless routed experts through the
 serving engine (ISSUE 28): token for token against ``generate_causal``
 (plain, with a prefix-cache hit and its copy-on-write, swapped out and in,
-preempted and resumed) on latent pools; the pools, the plan and the
-programs of a K/V model left as they were; what the engine refuses; and
-the routed counters on the ledger."""
+preempted and resumed) on latent pools, by the gather step and by the
+paged step whose attention is the fused latent kernel (ISSUE 34:
+``kernel="pallas"``, interpret mode here; the step a TPU takes by
+itself); the pools, the plan and the programs of a K/V model left as they
+were; what the engine refuses; and the routed counters on the ledger,
+the same by either step."""
 
 import json
 
@@ -29,6 +32,12 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.serve.paged_kv import (
 )
 
 GEOM = dict(block_size=4, prefill_chunk=8, max_model_len=64)
+
+# the decode step by both paths: left to choose on a CPU the engine
+# gathers; ``pallas`` is the paged step a TPU takes for latent pools
+BOTH_PATHS = pytest.mark.parametrize(
+    "kernel,path", [(None, "gather"), ("pallas", "paged_kernel")],
+    ids=["gather", "paged_kernel"])
 
 
 @pytest.fixture(scope="module")
@@ -69,15 +78,20 @@ def _serve_exact(model, params, trace, **kw):
     return eng
 
 
+@BOTH_PATHS
 @pytest.mark.parametrize("overlap", ["on", "off"])
 @pytest.mark.parametrize("buckets", [None, "full"], ids=["ladder", "full"])
-def test_engine_is_generate_causal_token_for_token(latent, overlap, buckets):
+def test_engine_is_generate_causal_token_for_token(latent, overlap, buckets,
+                                                   kernel, path):
     cfg, model, params = latent
     trace = [(p, 9) for p in _prompts(1, (5, 23, 40, 17, 33, 9))]
     eng = _serve_exact(model, params, trace, num_slots=4, num_blocks=80,
-                       overlap=overlap, gather_buckets=buckets)
+                       overlap=overlap, gather_buckets=buckets,
+                       kernel=kernel)
     st = eng.stats()
     assert st.preemptions == 0 and st.decode_steps > 0
+    other = ({"paged_kernel", "gather"} - {path}).pop()
+    assert st.decode_steps_by_path == {path: st.decode_steps, other: 0}
     # one pool a layer, rows with no heads axis
     assert len(eng._pools) == cfg.num_layers
     assert all(p.shape == (80, 4, 128) for p in eng._pools)
@@ -85,7 +99,8 @@ def test_engine_is_generate_causal_token_for_token(latent, overlap, buckets):
     assert [k[0] for k in eng._plan.kinds].count("latent") == 3
 
 
-def test_prefix_hit_and_copy_on_write_stay_exact(latent):
+@BOTH_PATHS
+def test_prefix_hit_and_copy_on_write_stay_exact(latent, kernel, path):
     """The recipe of ``test_serve.py``'s forced-COW gate: a long request,
     then short riders over its 12-token prefix admitted while it still
     holds its blocks; blocks of 4 under chunks of 8 re-align the cached
@@ -98,19 +113,22 @@ def test_prefix_hit_and_copy_on_write_stay_exact(latent):
              for t, m in zip(tails, (14, 2, 4, 3, 4))]
     trace[1] = (prefix.copy(), 2)               # the prompt IS the prefix
     eng = _serve_exact(model, params, trace, num_slots=2, num_blocks=40,
-                       max_model_len=32)
+                       max_model_len=32, kernel=kernel)
     st = eng.stats()
+    assert st.decode_path == path
     assert st.prefix_cached_tokens > 0 and st.cache_hit_rate > 0
     assert st.cow_copies > 0 and st.blocks_shared_peak > 0
     assert eng.blocks.num_used == 0
 
 
-def test_swap_out_and_in_stays_exact(latent):
+@BOTH_PATHS
+def test_swap_out_and_in_stays_exact(latent, kernel, path):
     cfg, model, params = latent
     trace = [(p, 18) for p in _prompts(4, (9, 9, 9, 9, 9))]
     eng = _serve_exact(model, params, trace, num_slots=4, num_blocks=10,
-                       max_model_len=32, swap="always")
+                       max_model_len=32, swap="always", kernel=kernel)
     st = eng.stats()
+    assert st.decode_path == path
     assert st.preemptions > 0 and st.swap_outs > 0 and st.swap_ins > 0
     assert eng.blocks.num_used == 0
 
@@ -136,11 +154,13 @@ def test_extract_and_insert_round_trip_bitwise(latent):
     np.testing.assert_array_equal(np.asarray(moved[1][17]), before[1][ids[0]])
 
 
-def test_preemption_and_recompute_resume_stay_exact(latent):
+@BOTH_PATHS
+def test_preemption_and_recompute_resume_stay_exact(latent, kernel, path):
     cfg, model, params = latent
     trace = [(p, 18) for p in _prompts(6, (9, 9, 9, 9, 9))]
     eng = _serve_exact(model, params, trace, num_slots=4, num_blocks=10,
-                       max_model_len=32)
+                       max_model_len=32, kernel=kernel)
+    assert eng.stats().decode_path == path
     assert eng.stats().preemptions > 0 and eng.stats().swap_outs == 0
 
 
@@ -210,12 +230,13 @@ def test_latent_pool_under_tensor_parallelism_is_refused(latent, devices8):
         E.build_cache_plan(model, params, 32, mesh=tensor_parallel_mesh(2))
 
 
-def test_the_paged_kernel_and_speculation_are_refused(latent):
+@pytest.mark.parametrize("kernel", [None, "pallas"])
+def test_speculation_is_refused(latent, kernel):
+    """By either decode path (the paged step itself builds: the
+    exactness tests above run on it)."""
     cfg, model, params = latent
-    with pytest.raises(ValueError, match="no latent-attention form"):
-        ServeEngine(model, params, kernel="pallas", **GEOM)
     with pytest.raises(ValueError, match="speculative decoding is not"):
-        ServeEngine(model, params, speculate_k=2, **GEOM)
+        ServeEngine(model, params, speculate_k=2, kernel=kernel, **GEOM)
 
 
 def test_capacity_slot_experts_are_still_refused_with_the_reason():
@@ -240,22 +261,31 @@ def _events(tmp_path):
         return [json.loads(line) for line in f]
 
 
-@pytest.mark.parametrize("overlap", ["on", "off"])
-def test_ledger_carries_the_routed_counts(latent, tmp_path, overlap):
-    cfg, model, params = latent
+def _counted_run(model, params, tmp_path, prompts, **kw):
+    """The prompts, nine tokens each, through an engine under a sink:
+    (stats, events)."""
     obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
     try:
         eng = ServeEngine(model, params, num_slots=4, num_blocks=80,
-                          overlap=overlap, **GEOM)
-        prompts = _prompts(8, (5, 23, 40, 17, 33, 9))
+                          **kw, **GEOM)
         for p in prompts:
             eng.submit(p, 9)
         eng.run()
         st = eng.stats()
         obs.flush()
-        events = _events(tmp_path)
+        return st, _events(tmp_path)
     finally:
         obs.reset(enabled=False)
+
+
+@BOTH_PATHS
+@pytest.mark.parametrize("overlap", ["on", "off"])
+def test_ledger_carries_the_routed_counts(latent, tmp_path, overlap, kernel,
+                                          path):
+    cfg, model, params = latent
+    prompts = _prompts(8, (5, 23, 40, 17, 33, 9))
+    st, events = _counted_run(model, params, tmp_path, prompts,
+                              overlap=overlap, kernel=kernel)
     lines = [e for e in events if e.get("event") == "iteration_ledger"]
     assert lines and all("moe_pairs" in e for e in lines)
     for e in lines:
@@ -280,11 +310,41 @@ def test_ledger_carries_the_routed_counts(latent, tmp_path, overlap):
     assert report["moe_pairs"] == st.moe_pairs
     assert report["latent_bytes_per_token"] == st.latent_bytes_per_token
     spans = [e for e in events if e.get("type") == "span"]
-    paths = {(e["name"], (e.get("args") or {}).get("latent_path"))
+    paths = {(e["name"], (e.get("args") or {}).get("latent_path"),
+              (e.get("args") or {}).get("decode_path"))
              for e in spans if e["name"] in ("serve/prefill_chunk",
                                              "serve/decode_step")}
-    assert paths == {("serve/prefill_chunk", "expanded"),
-                     ("serve/decode_step", "absorbed")}
+    assert paths == {("serve/prefill_chunk", "expanded", None),
+                     ("serve/decode_step", "absorbed", path)}
+    assert report["decode_path"] == st.decode_path == path
+
+
+_MOE_FIELDS = ("moe_pairs", "moe_pairs_held", "moe_decode_pairs",
+               "moe_decode_pairs_held", "moe_experts_touched",
+               "moe_expert_load_max", "moe_expert_load_mean")
+
+
+def test_the_paged_step_counts_what_the_gather_step_counts(latent, tmp_path):
+    """The same requests by both steps, serial (a ledger line then holds
+    the counts of its own iteration's dispatches): every routed field of
+    every ledger line is the same, so the benchmark's readers of them
+    (``moe_decode_roofline_share``, ``moe_expert_load_max_over_mean``)
+    read a paged run as they read a gathered one."""
+    cfg, model, params = latent
+    prompts = _prompts(8, (5, 23, 40, 17, 33, 9))
+    runs = {}
+    for kernel in (None, "pallas"):
+        st, events = _counted_run(model, params, tmp_path / str(kernel),
+                                  prompts, overlap="off", kernel=kernel)
+        lines = [e for e in events if e.get("event") == "iteration_ledger"]
+        runs[st.decode_path] = (st, [{k: e.get(k) for k in _MOE_FIELDS}
+                                     for e in lines])
+    (st_g, lines_g), (st_p, lines_p) = runs["gather"], runs["paged_kernel"]
+    assert lines_g == lines_p
+    assert any(line["moe_decode_pairs_held"] for line in lines_p)
+    assert any(line["moe_experts_touched"] for line in lines_p)
+    assert (st_g.moe_pairs, st_g.moe_pairs_held) \
+        == (st_p.moe_pairs, st_p.moe_pairs_held)
 
 
 def test_a_ledger_line_never_waits_for_the_step_in_flight(latent, tmp_path):
@@ -408,17 +468,21 @@ def test_a_k_v_engine_has_no_form(tmp_path):
     assert "prefill_dispatches_by_form" not in eng.slo_summary()
 
 
-def test_no_compile_after_warmup(latent, tmp_path):
+@BOTH_PATHS
+def test_no_compile_after_warmup(latent, tmp_path, kernel, path):
     """The rule of ``tests/test_serve_gates.py::test_no_compile_after_
     warmup`` for a latent engine: from empty jit caches, ``warmup()``
     compiles every program the run needs (three prefill programs, two
-    decode buckets), whichever form the prefill ones attend by."""
+    decode buckets), whichever form the prefill ones attend by and
+    whichever step decodes."""
     cfg, model, params = latent
     jax.clear_caches()
     obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
     try:
         tracker = obs.compile_tracker()
-        eng = ServeEngine(model, params, num_slots=4, num_blocks=80, **GEOM)
+        eng = ServeEngine(model, params, num_slots=4, num_blocks=80,
+                          kernel=kernel, **GEOM)
+        assert eng.decode_path == path
         eng.warmup()
         count0 = tracker.count
         assert count0 > 0
