@@ -40,6 +40,7 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.models import (
     olmo_hybrid,
     roberta,
     t5,
+    xing4,
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.models.convert import (
     hf_to_params,
@@ -75,6 +76,7 @@ MODEL_REGISTRY: dict[tuple[str, str], Any] = {
     ("llama", "causal-lm"): llama.LlamaForCausalLM,
     ("deepseek_v2", "causal-lm"): deepseek_v2.DeepseekV2ForCausalLM,
     ("olmo_hybrid", "causal-lm"): olmo_hybrid.OlmoHybridForCausalLM,
+    ("xing4_0", "causal-lm"): xing4.Xing4ForCausalLM,
     ("bert", "mlm"): bert.BertForMaskedLM,
     ("roberta", "mlm"): roberta.RobertaForMaskedLM,
     ("distilbert", "mlm"): distilbert.DistilBertForMaskedLM,
@@ -100,6 +102,7 @@ CONFIG_BUILDERS = {
     "llama": llama.llama_config_from_hf,
     "deepseek_v2": deepseek_v2.deepseek_v2_config_from_hf,
     "olmo_hybrid": olmo_hybrid.olmo_hybrid_config_from_hf,
+    "xing4_0": xing4.xing4_config_from_hf,
     "deberta-v2": deberta.deberta_config_from_hf,
     "bart": bart.bart_config_from_hf,
     # mBART hardcodes pre-LN + per-stack final LN in its modeling class
@@ -407,7 +410,7 @@ def from_pretrained(
             "layout is supported — silently loading would leave a random "
             "head (HF's own non-legacy forward is broken in transformers "
             "4.57: tie_weights clobbers lm_head.dense)")
-    if (family in ("gpt2", "llama", "deepseek_v2", "olmo_hybrid")
+    if (family in ("gpt2", "llama", "deepseek_v2", "olmo_hybrid", "xing4_0")
             and task != "causal-lm"):
         raise ValueError(
             f"{model_name_or_path!r} is a {family} (decoder-only) "
@@ -429,7 +432,7 @@ def from_pretrained(
     params = init_params(model, config, seed=seed)
     has_weights = os.path.exists(os.path.join(model_name_or_path, "model.safetensors")) or \
         os.path.exists(os.path.join(model_name_or_path, "pytorch_model.bin"))
-    if (family in ("deepseek_v2", "olmo_hybrid") and has_weights
+    if (family in ("deepseek_v2", "olmo_hybrid", "xing4_0") and has_weights
             and not from_scratch):
         raise ValueError(
             f"{model_name_or_path!r} holds {family} weights: loading a "

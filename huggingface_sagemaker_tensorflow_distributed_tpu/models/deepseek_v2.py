@@ -265,11 +265,12 @@ class DeepseekV2Config:
                 / yarn_mscale(s["factor"], s.get("mscale_all_dim", 0)))
 
 
-def deepseek_v2_config_from_hf(hf_config: dict, **overrides) -> DeepseekV2Config:
-    """The program's configuration from an HF ``config.json`` mapping.
-    ``experts_held`` / ``expert_rank`` (not HF keys) may ride in the
-    mapping or in ``overrides``. Raises on what the modules do not
-    compute rather than load and diverge."""
+def latent_moe_config_kw(hf_config: dict, family: str) -> dict:
+    """The keyword arguments of a :class:`DeepseekV2Config` (or of a
+    subclass: ``models/xing4.py``) that an HF ``config.json`` mapping
+    gives whichever gate the family has: widths, depth, YaRN, the share
+    held here, ids. Raises on what latent attention here does not
+    compute."""
     scaling = hf_config.get("rope_scaling")
     rope_scaling = None
     if scaling:
@@ -277,32 +278,17 @@ def deepseek_v2_config_from_hf(hf_config: dict, **overrides) -> DeepseekV2Config
         if rope_type != "yarn":
             raise ValueError(
                 f"rope_scaling type {rope_type!r} is not implemented for "
-                f"deepseek_v2 (yarn only): {scaling!r}")
+                f"{family} (yarn only): {scaling!r}")
         missing = [k for k in ("factor", "original_max_position_embeddings")
                    if k not in scaling]
         if missing:
             raise ValueError(f"yarn rope_scaling is missing {missing}: "
                              f"{scaling!r}")
         rope_scaling = tuple(sorted(scaling.items()))
-    unsupported = {
-        "scoring_func": ("softmax", hf_config.get("scoring_func", "softmax")),
-        "topk_method": ("group_limited_greedy",
-                        hf_config.get("topk_method", "group_limited_greedy")),
-        "norm_topk_prob": (False, hf_config.get("norm_topk_prob", False)),
-        "moe_layer_freq": (1, hf_config.get("moe_layer_freq", 1)),
-        "attention_bias": (False, hf_config.get("attention_bias", False)),
-    }
-    for key, (want, got) in unsupported.items():
-        if got != want:
-            raise ValueError(
-                f"deepseek_v2 {key}={got!r} is not implemented (only "
-                f"{want!r}): sigmoid and bias-corrected gates, "
-                "renormalised weights and sparse expert placement are "
-                "other models' (ROADMAP)")
     if hf_config.get("q_lora_rank") is None:
-        raise ValueError("deepseek_v2 without q_lora_rank (the Lite "
+        raise ValueError(f"{family} without q_lora_rank (the Lite "
                          "layout: an uncompressed query) is not implemented")
-    kw = dict(
+    return dict(
         vocab_size=hf_config["vocab_size"],
         hidden_size=hf_config["hidden_size"],
         num_layers=hf_config["num_hidden_layers"],
@@ -338,6 +324,36 @@ def deepseek_v2_config_from_hf(hf_config: dict, **overrides) -> DeepseekV2Config
                       if hf_config.get("pad_token_id") is not None
                       else hf_config.get("eos_token_id", 100001)),
     )
+
+
+def refuse_unless(family: str, hf_config: dict, wanted: dict,
+                  why: str) -> None:
+    """Raise, by key, on a ``config.json`` value other than the one this
+    family's modules compute: ``wanted`` maps a key to ``(the value run,
+    what an absent key means)``."""
+    for key, (want, default) in wanted.items():
+        got = hf_config.get(key, default)
+        if got != want:
+            raise ValueError(f"{family} {key}={got!r} is not implemented "
+                             f"(only {want!r}): {why}")
+
+
+def deepseek_v2_config_from_hf(hf_config: dict, **overrides) -> DeepseekV2Config:
+    """The program's configuration from an HF ``config.json`` mapping.
+    ``experts_held`` / ``expert_rank`` (not HF keys) may ride in the
+    mapping or in ``overrides``. Raises on what the modules do not
+    compute rather than load and diverge."""
+    refuse_unless("deepseek_v2", hf_config, {
+        "scoring_func": ("softmax", "softmax"),
+        "topk_method": ("group_limited_greedy", "group_limited_greedy"),
+        "norm_topk_prob": (False, False),
+        "moe_layer_freq": (1, 1),
+        "attention_bias": (False, False),
+    }, "this family has a softmax, group-limited gate. A sigmoid, "
+       "bias-corrected gate with renormalised weights is the xing4_0 "
+       "family's (models/xing4.py); sparse expert placement is no "
+       "family's here (ROADMAP)")
+    kw = latent_moe_config_kw(hf_config, "deepseek_v2")
     kw.update(overrides)
     kw.pop("use_pooler", None)             # encoder-family knob
     return DeepseekV2Config(**kw)
@@ -597,6 +613,15 @@ class DeepseekV2MoE(nn.Module):
 
     config: DeepseekV2Config
 
+    def gate(self, logits):
+        """``(ids, weights)`` [T, k] of the router's float32 ``logits``
+        [T, all experts]: this family's gate (a family with another one
+        overrides this, and may declare the gate's own parameters)."""
+        cfg = self.config
+        return group_limited_gate(
+            jax.nn.softmax(logits, axis=-1), cfg.n_group, cfg.topk_group,
+            cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+
     @nn.compact
     def __call__(self, hidden, token_mask=None):
         cfg = self.config
@@ -618,9 +643,7 @@ class DeepseekV2MoE(nn.Module):
         logits = jnp.einsum("th,he->te", x.astype(jnp.float32),
                             router.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        ids, weights = group_limited_gate(
-            jax.nn.softmax(logits, axis=-1), cfg.n_group, cfg.topk_group,
-            cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+        ids, weights = self.gate(logits)
         y, counts = dropless_experts(
             x, ids, weights, w_gate.astype(cfg.dtype),
             w_up.astype(cfg.dtype), w_down.astype(cfg.dtype),
@@ -653,6 +676,38 @@ class DeepseekV2Block(nn.Module):
         return hidden + DeepseekV2MoE(cfg, name="moe")(normed, token_mask)
 
 
+def embed_and_rope(module: nn.Module, input_ids, attention_mask,
+                   position_ids, decode: bool):
+    """What a backbone of this layout does before its first block, inside
+    ``module``'s compact call: ``(embedded tokens [B, S, C], key_valid
+    [B, W] or None, (cos, sin))``. Positions are the caller's, or count on
+    from the cache's ``position_index`` (``generate_causal``'s steps)."""
+    cfg = module.config
+    B, S = input_ids.shape
+    if position_ids is None:
+        offset = 0
+        if decode:
+            is_init = module.has_variable("cache", "position_index")
+            idx = module.variable("cache", "position_index",
+                                  lambda: jnp.array(0, jnp.int32))
+            if is_init:
+                offset = idx.value
+                idx.value = offset + S
+        position_ids = jnp.broadcast_to(
+            offset + jnp.arange(S)[None, :], (B, S))
+    cos, sin = rope_tables(position_ids, cfg.qk_rope_head_dim,
+                           cfg.rope_theta, cfg.rope_scaling_dict)
+    if cfg.rope_factor != 1.0:
+        cos, sin = cos * cfg.rope_factor, sin * cfg.rope_factor
+    key_valid = None if attention_mask is None else attention_mask > 0
+    x = nn.Embed(
+        cfg.vocab_size, cfg.hidden_size,
+        embedding_init=nn.initializers.normal(cfg.initializer_range),
+        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+        name="embed_tokens")(input_ids)
+    return x, key_valid, (cos, sin)
+
+
 class DeepseekV2Model(nn.Module):
     config: DeepseekV2Config
 
@@ -660,31 +715,11 @@ class DeepseekV2Model(nn.Module):
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
                  decode: bool = False, token_mask=None):
         cfg = self.config
-        B, S = input_ids.shape
-        if position_ids is None:
-            offset = 0
-            if decode:
-                is_init = self.has_variable("cache", "position_index")
-                idx = self.variable("cache", "position_index",
-                                    lambda: jnp.array(0, jnp.int32))
-                if is_init:
-                    offset = idx.value
-                    idx.value = offset + S
-            position_ids = jnp.broadcast_to(
-                offset + jnp.arange(S)[None, :], (B, S))
-        cos, sin = rope_tables(position_ids, cfg.qk_rope_head_dim,
-                               cfg.rope_theta, cfg.rope_scaling_dict)
-        if cfg.rope_factor != 1.0:
-            cos, sin = cos * cfg.rope_factor, sin * cfg.rope_factor
-        key_valid = None if attention_mask is None else attention_mask > 0
-        x = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size,
-            embedding_init=nn.initializers.normal(cfg.initializer_range),
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            name="embed_tokens")(input_ids)
+        x, key_valid, rope = embed_and_rope(self, input_ids, attention_mask,
+                                            position_ids, decode)
         for i in range(cfg.num_layers):
             x = DeepseekV2Block(cfg, layer_index=i, name=f"layers_{i}")(
-                x, key_valid, (cos, sin), decode, token_mask)
+                x, key_valid, rope, decode, token_mask)
         return LlamaRMSNorm(cfg, name="final_ln")(x)
 
 

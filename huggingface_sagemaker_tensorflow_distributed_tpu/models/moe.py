@@ -10,7 +10,7 @@ token-routed MoE FFN designed TPU-first —
   use (encoder MoE, Mixtral); it is not the only one with static shapes:
   :func:`dropless_experts` below sorts the (token, expert) pairs by
   expert and runs grouped matmuls over them, drops nothing, and is what
-  the served DeepSeek-V2 family routes with.
+  the served DeepSeek-V2 and Xing4 families route with.
 - **Expert parallelism via sharding annotations**: expert weights carry
   ``PartitionSpec("expert", ...)`` (``parallel/sharding.py``) and the
   dispatched activations are constrained expert-major, so XLA inserts
@@ -27,8 +27,9 @@ TPU). The Switch load-balance auxiliary loss is sowed into the
 ``losses`` collection; the Trainer adds every sowed value to the task
 loss (``train/trainer.py``).
 
-**Dropless routed experts** (:func:`group_limited_gate`,
-:func:`dropless_experts`): a token's routing depends on that token alone
+**Dropless routed experts** (:func:`group_limited_gate` or
+:func:`sigmoid_bias_gate`, then :func:`dropless_experts`): a token's
+routing depends on that token alone
 (no capacity, no slot competition), so chunked prefill, one-shot prefill
 and decode route alike, which is what lets the serving engine take the
 layer. The layer is told which experts it holds (one chip's share of an
@@ -297,6 +298,22 @@ def group_limited_gate(probs, n_group: int, topk_group: int, top_k: int,
     masked = jnp.where(keep[:, :, None], groups, 0.0).reshape(T, E)
     weights, ids = lax.top_k(masked, top_k)
     return ids.astype(jnp.int32), weights * scale
+
+
+def sigmoid_bias_gate(scores, bias, top_k: int, scale: float):
+    """The ``noaux_tc`` gate over float32 ``scores`` [T, E] (the SIGMOID
+    of the router's logits, each expert on its own scale) with one group:
+    a token's experts are the ``top_k`` largest of ``scores + bias``
+    (``bias`` [E], the load-balancing correction: it selects and never
+    weighs; ties go to the lower index, ``lax.top_k``), and their weights
+    are the chosen SCORES renormalised to sum to ``scale``
+    (``norm_topk_prob``, ``routed_scaling_factor``): ``w_i = scale * s_i /
+    (sum of the chosen s + 1e-20)``. Returns ``(ids [T, top_k] int32,
+    weights [T, top_k] float32)``, as :func:`group_limited_gate` does."""
+    _, ids = lax.top_k(scores + bias[None, :], top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=1)
+    weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20) * scale
+    return ids.astype(jnp.int32), weights
 
 
 def dropless_experts(x, ids, weights, w_gate, w_up, w_down,

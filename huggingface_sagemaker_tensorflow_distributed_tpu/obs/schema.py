@@ -219,6 +219,14 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               "moe_expert_load_max": (list,),
               "moe_expert_load_mean": (list,),
               "latent_bytes_per_token": (int,),
+              # hyper-connections under a sigmoid gate (ISSUE 35), on the
+              # report of a model that has them: residual streams a
+              # token and the gate's name; on its ledger lines the
+              # largest |row or column sum - 1| of any H_res the
+              # iteration's landed dispatches made
+              "residual_streams": (int,),
+              "gate": (str,),
+              "mhc_defect_max": _NUM,
               # recurrent state (ISSUE 33), on the ledger lines and the
               # report of a model that has it: rows whose state the
               # iteration's dispatches read and wrote (prefill rows +

@@ -534,21 +534,32 @@ def _moe_counts(mut):
     """The routed layers' per-expert pair counts ``[expert layers, experts
     held]`` out of a step's mutated collections, in layer order; ``()``
     for a model that routes nothing (its step then returns exactly what
-    it always did)."""
+    it always did). A model whose residual path is hyper-connections sows
+    a ``mhc_defect`` a wrap beside them (``models/xing4.py``): their
+    maximum, one float a step, follows the counts; a model without the
+    wrap returns the counts alone, as it always did."""
     stats = mut.get("moe_stats")
     if not stats:
         return ()
-    flat = [(path, leaf) for path, leaf in
-            jax.tree_util.tree_flatten_with_path(stats)[0]
-            if any(getattr(p, "key", None) == "expert_counts" for p in path)]
+    leaves = jax.tree_util.tree_flatten_with_path(stats)[0]
+
+    def named(name):
+        return [(path, leaf) for path, leaf in leaves
+                if any(getattr(p, "key", None) == name for p in path)]
+
+    flat = named("expert_counts")
+    defects = [leaf for _, leaf in named("mhc_defect")]
 
     def layer(path):
         name = next(str(getattr(p, "key", p)) for p in path
                     if str(getattr(p, "key", p)).startswith("layers_"))
         return int(name.rsplit("_", 1)[1])
 
-    return (jnp.stack([leaf for _, leaf in
-                       sorted(flat, key=lambda pl: layer(pl[0]))]),)
+    counts = jnp.stack([leaf for _, leaf in
+                        sorted(flat, key=lambda pl: layer(pl[0]))])
+    if defects:
+        return counts, jnp.max(jnp.stack(defects))
+    return (counts,)
 
 
 def _routes(model) -> bool:
@@ -1273,6 +1284,11 @@ class EngineStats(NamedTuple):
     latent_bytes_per_token: Optional[int] = None
     moe_pairs: int = 0
     moe_pairs_held: int = 0
+    # a model whose residual path is hyper-connections under its own gate
+    # (ISSUE 35; what the model's ``residual_kw`` says): streams a token
+    # and the gate's name, None for any other model
+    residual_streams: Optional[int] = None
+    gate: Optional[str] = None
     # how the prefill dispatches of a latent-attention model attended
     # (ISSUE 32): dispatches by the model's ``expanded_form`` (None: a
     # K/V cache)
@@ -1560,6 +1576,11 @@ class ServeEngine:
         # pools beside the block pools (``CachePlan``'s ``state`` kind)
         self._stateful = bool(plan.state_shapes)
         self._routes = _routes(model)
+        # what a model with a residual path of its own says of itself
+        # (``residual_streams``, ``gate``): written beside ``latent_path``
+        # on the step spans, in stats() and on the report; {} otherwise
+        self._residual_kw = (dict(model.residual_kw())
+                             if hasattr(model, "residual_kw") else {})
         # (token, expert) pairs one real token makes over the model
         self._moe_fanout = (int(cfg.num_experts_per_tok)
                             * int(cfg.num_moe_layers)) if self._routes else 0
@@ -1745,7 +1766,8 @@ class ServeEngine:
         # and those that landed on the experts held here
         self.moe_pairs = 0
         self.moe_pairs_held = 0
-        self._moe_flight: list = []   # (pairs, device counts, is decode)
+        # (pairs, device counts, is decode[, device defect]) a dispatch
+        self._moe_flight: list = []
         self._moe_resolved = 0        # entries taken off its head so far
         self._moe_landed = 0          # entries of the run a fetch has passed
         self.spec_windows = 0       # active (slot, iteration) pairs
@@ -2273,6 +2295,7 @@ class ServeEngine:
             out["latent_bytes_per_token"] = self.blocks.token_bytes
             out["prefill_dispatches_by_form"] = dict(
                 self.prefill_dispatches_by_form)
+        out.update(self._residual_kw)
         if self._routes:
             self._moe_resolve(everything=True)
             out["moe_pairs"] = self.moe_pairs
@@ -2434,6 +2457,7 @@ class ServeEngine:
                                     if self._latent else None),
             moe_pairs=self.moe_pairs,
             moe_pairs_held=self.moe_pairs_held,
+            **self._residual_kw,
             prefill_dispatches_by_form=(
                 dict(self.prefill_dispatches_by_form)
                 if self._latent else None),
@@ -2759,12 +2783,14 @@ class ServeEngine:
         model's own rule on the shape), and for an expanded one over
         ``width`` keys ``"expanded_form": "kernel" | "xla_loop"`` (the
         model's own rule on the shapes and the backend, the one its
-        trace follows); ``{}`` for any other model."""
-        if not self._latent:
-            return {}
-        out = {"latent_path": self.model.latent_path(q_len)}
-        if out["latent_path"] == "expanded":
-            out["expanded_form"] = self.model.expanded_form(q_len, width)
+        trace follows), and beside them what a model with a residual path
+        of its own says of itself (``residual_streams``, ``gate``); ``{}``
+        for any other model."""
+        out = dict(self._residual_kw)
+        if self._latent:
+            out["latent_path"] = self.model.latent_path(q_len)
+            if out["latent_path"] == "expanded":
+                out["expanded_form"] = self.model.expanded_form(q_len, width)
         return out
 
     def _state_kw(self, q_len: int, rows: int) -> dict:
@@ -2800,7 +2826,7 @@ class ServeEngine:
         is a sink to report them to; an untraced run drops them."""
         if moe and obs.has_sink():
             self._moe_flight.append(
-                (tokens * self._moe_fanout, moe[0], decode))
+                (tokens * self._moe_fanout, moe[0], decode, *moe[1:]))
 
     def _moe_mark(self) -> int:
         """How many routed-count entries the run has made so far: the
@@ -2816,8 +2842,9 @@ class ServeEngine:
         the decode steps' part of both (``moe_decode_pairs`` /
         ``moe_decode_pairs_held``) and, per expert layer, of the last
         decode step among them, the held experts that got a pair and the
-        busiest and the mean held expert's pairs. ``{}`` for a model
-        that routes nothing."""
+        busiest and the mean held expert's pairs; for a model that sows it,
+        ``mhc_defect_max`` of those dispatches. ``{}`` for a model that
+        routes nothing."""
         if not self._routes:
             return {}
         n = (len(self._moe_flight) if everything
@@ -2827,8 +2854,13 @@ class ServeEngine:
         self._moe_landed = max(self._moe_landed, self._moe_resolved)
         out = {"moe_pairs": 0, "moe_pairs_held": 0,
                "moe_decode_pairs": 0, "moe_decode_pairs_held": 0}
-        for pairs, counts, decode in landed:
+        for pairs, counts, decode, *defect in landed:
             counts = np.asarray(counts)          # computed: no wait
+            if defect:
+                # hyper-connections: the largest |row or column sum - 1|
+                # of any H_res these dispatches made
+                out["mhc_defect_max"] = max(out.get("mhc_defect_max", 0.0),
+                                            float(defect[0]))
             held = int(counts.sum())
             out["moe_pairs"] += pairs
             out["moe_pairs_held"] += held
@@ -3023,7 +3055,7 @@ class ServeEngine:
             self.prefill_keys_needed += slot.prefill_pos
         self.prefill_chunks += len(slots)
         self.prefill_dispatches += 1
-        if latent_kw:
+        if self._latent:
             self.prefill_dispatches_by_form[latent_kw["expanded_form"]] += 1
         self.prefill_keys_attended += G * width
         if finals:
