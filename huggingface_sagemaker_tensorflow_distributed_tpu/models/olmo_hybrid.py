@@ -24,14 +24,20 @@ A linear layer (:class:`GatedDeltaMixer`), per head of ``linear_num_heads``::
     y = W_o [RMSNorm_dv(S_t^T q_t) * SiLU(W_g x)]
 
 Its decode cache is what the recurrence carries, indexed BY ROW and of a
-size that does not grow with the context: ``recurrent_state`` ``[B, H, dk,
-dv]`` float32 and ``conv_state`` ``[B, K-1, H (2 dk + dv)]`` (the last
+size that does not grow with the context: ``recurrent_state`` ``[B, *
+state_layout(H, dk, dv)]`` float32 (``ops/pallas_gated_delta.py``: the
+one-token step's kernel owns the shape of what it carries, ``[B, H/G, dk,
+G dv]`` where ``G`` heads side by side fill whole lane tiles and ``[B, H,
+dk, dv]`` otherwise; a pure function of the shapes, the same on every
+backend) and ``conv_state`` ``[B, K-1, H (2 dk + dv)]`` (the last
 ``K - 1`` rows before the convolution). The serving engine keeps both in
 per-slot state pools (``serve/engine.py``: the ``state`` kind of its
-cache plan). ``token_mask`` ``[B, T]`` says which tokens are real: the
-real tokens of a row come first, and what follows them (a prompt's pad
-tail, a pad row, an inactive slot) advances neither the state nor the
-convolution's tail.
+cache plan). A one-token call runs the fused kernel on a TPU and the jnp
+step elsewhere (:func:`state_step`); a run of tokens unpacks the rows it
+was handed, runs the chunked form and packs them again. ``token_mask``
+``[B, T]`` says which tokens are real: the real tokens of a row come
+first, and what follows them (a prompt's pad tail, a pad row, an inactive
+slot) advances neither the state nor the convolution's tail.
 
 Out of scope: loading a published checkpoint (``models/convert.py`` has
 no mapping for this family), training-side kernels (the backward of the
@@ -56,13 +62,15 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.models.llama import (
     LlamaRMSNorm,
     _dense,
 )
+from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+    pallas_gated_delta,
+)
 from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
     make_attention_mask,
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.ops.gated_delta import (
     causal_conv,
     gated_delta_chunked,
-    gated_delta_step,
     l2_normalize,
 )
 
@@ -167,6 +175,16 @@ def state_form(q_len: int) -> str:
     return "step" if q_len == 1 else "chunked"
 
 
+def state_step(cfg) -> str:
+    """``kernel`` | ``xla``: how a one-token call of ``cfg``'s linear
+    layers advances the state in this process
+    (``pallas_gated_delta.state_step`` of the shapes and of the backend,
+    asked as the other kernels' wrappers ask it)."""
+    return pallas_gated_delta.state_step(
+        cfg.linear_num_heads, cfg.linear_key_head_dim,
+        cfg.linear_value_head_dim, platform=jax.devices()[0].platform)
+
+
 def _a_log_init(key, shape, dtype=jnp.float32):
     # Gated DeltaNet: A ~ U(0, 16), stored as its logarithm
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-4, 16.0)
@@ -215,7 +233,9 @@ class GatedDeltaMixer(nn.Module):
         norm_scale = self.param("o_norm_scale", nn.initializers.ones, (dv,),
                                 cfg.param_dtype)
 
-        state = jnp.zeros((B, H, dk, dv), jnp.float32)
+        # the state as it is carried: packed (ops/pallas_gated_delta.py)
+        state = jnp.zeros((B,) + pallas_gated_delta.state_layout(H, dk, dv),
+                          jnp.float32)
         tail = jnp.zeros((B, K - 1, C), cfg.dtype)
         carried = False
         if decode:
@@ -238,13 +258,16 @@ class GatedDeltaMixer(nn.Module):
             beta = beta * 2.0
         g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
         if state_form(T) == "step":
-            o, state = gated_delta_step(
+            o, state = pallas_gated_delta.gated_delta_step_carried(
                 q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
-                None if token_mask is None else token_mask[:, 0])
+                None if token_mask is None else token_mask[:, 0],
+                form=state_step(cfg))
             o = o[:, None]
         else:
-            o, state = gated_delta_chunked(q, k, v, g, beta, state,
-                                           token_mask)
+            o, state = gated_delta_chunked(
+                q, k, v, g, beta, pallas_gated_delta.unpack(state, H),
+                token_mask)
+            state = pallas_gated_delta.pack(state)
         if carried:
             state_var.value, tail_var.value = state, tail
         # gated RMSNorm per head, float32: norm(o) * scale * SiLU(gate)
@@ -314,6 +337,11 @@ class OlmoHybridForCausalLM(nn.Module):
 
     state_form = staticmethod(state_form)
     takes_logit_positions = True
+
+    def state_step(self) -> str:
+        """:func:`state_step` for this model in this process: what the
+        serving engine writes beside ``state_form`` on a decode step."""
+        return state_step(self.config)
 
     def setup(self):
         cfg = self.config

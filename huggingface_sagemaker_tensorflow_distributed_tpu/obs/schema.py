@@ -242,6 +242,9 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               "state_bytes_per_slot": (int,),
               "state_pool_bytes": (int,),
               "kv_token_bytes": (int,),
+              # how its one-token steps advance the state (ISSUE 36):
+              # "kernel" (ops/pallas_gated_delta.py) | "xla"
+              "state_step": (str,),
               # a latent-attention model's report (ISSUE 32): prefill
               # dispatches by how the expanded form attended
               # ({"kernel": n, "xla_loop": n})

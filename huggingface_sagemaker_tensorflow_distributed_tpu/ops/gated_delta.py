@@ -12,7 +12,9 @@ Three functions, all float32 inside whatever they are handed:
 - :func:`gated_delta_step`: ONE token a row (a decode step). Elementwise
   over ``S``: two passes read it and one writes it (the projections of
   ``S`` on ``k`` and ``q`` together, then decay and rank-one update in
-  one); a fused kernel would read it once (ROADMAP R3).
+  one). On a TPU a decode step runs ``ops/pallas_gated_delta.py`` instead,
+  which reads a slot's state once and writes it once; this form is what a
+  CPU runs and the reference that kernel is tested against.
 - :func:`gated_delta_chunked`: a run of tokens a row (a prefill chunk, the
   plain forward) in chunks of :data:`CHUNK`: inside a chunk the WY / UT
   transform (``(I + tril(K_b K^T * D, -1))^-1`` by a blocked forward

@@ -1300,6 +1300,9 @@ class EngineStats(NamedTuple):
     state_bytes_per_slot: Optional[int] = None
     state_pool_bytes: Optional[int] = None
     state_slots_peak: Optional[int] = None
+    # how its one-token steps advance the state (ISSUE 36): the model's
+    # ``state_step``, ``kernel`` | ``xla``
+    state_step: Optional[str] = None
 
 
 class ServeEngine:
@@ -2305,6 +2308,7 @@ class ServeEngine:
             out["state_bytes_per_slot"] = self.state_bytes_per_slot
             out["state_pool_bytes"] = self.state_pool_bytes
             out["state_slots_peak"] = self.peak_resident
+            out["state_step"] = self.model.state_step()
             out["kv_token_bytes"] = self.blocks.token_bytes
         # multi-replica serving (ISSUE 14): a router-owned replica's
         # report names itself so the merged cross-host report (and
@@ -2525,7 +2529,9 @@ class ServeEngine:
             state_pool_bytes=(self.state_pool_bytes
                               if self._stateful else None),
             state_slots_peak=(self.peak_resident
-                              if self._stateful else None))
+                              if self._stateful else None),
+            state_step=(self.model.state_step()
+                        if self._stateful else None))
 
     @property
     def prefix_cache_state(self):
@@ -2796,12 +2802,17 @@ class ServeEngine:
     def _state_kw(self, q_len: int, rows: int) -> dict:
         """``{"state_form": "step" | "chunked", "state_rows": n}`` for a
         dispatch of ``q_len`` tokens a row of a model with recurrent
-        state, ``rows`` of them real (their state is read and written);
-        ``{}`` for any other model."""
+        state, ``rows`` of them real (their state is read and written),
+        and for a one-token step ``"state_step": "kernel" | "xla"`` (the
+        model's own rule on the shapes and the backend: the fused kernel
+        or the jnp form); ``{}`` for any other model."""
         if not self._stateful:
             return {}
-        return {"state_form": self.model.state_form(q_len),
-                "state_rows": rows}
+        out = {"state_form": self.model.state_form(q_len),
+               "state_rows": rows}
+        if out["state_form"] == "step":
+            out["state_step"] = self.model.state_step()
+        return out
 
     def _state_args(self, rows=None) -> tuple:
         """The state operands of a step of a model with recurrent state

@@ -8,7 +8,9 @@ the model's plain forward by either form, the gradient through the
 kernel form, and the kernel compiled for the v5e at the published
 widths, with the paged DECODE kernel of the absorbed form
 (``ops/pallas_paged_latent_attention.py``, ISSUE 34) beside it at the
-cell's shape: one file describes the topology."""
+cell's shape, and the fused one-token step of the gated delta rule
+(``ops/pallas_gated_delta.py``, ISSUE 36) alone and inside Olmo-Hybrid's
+whole decode step: one file describes the topology."""
 
 import dataclasses
 
@@ -330,3 +332,116 @@ def test_the_paged_decode_kernel_compiles_for_the_v5e_at_the_cells_shape(
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= 1 << 20
     assert memory.alias_size_in_bytes >= pages * 16 * row * 2
+
+
+# -- the gated delta rule's one-token step (ISSUE 36) ---------------------------
+
+_STATE = dict(B=64, H=30, dk=96, dv=192)      # olmo-hybrid-7b-pp2-gen-sat
+
+
+@pytest.mark.parametrize("packed,pool_bytes", [
+    (True, 141_557_760), (False, 188_743_680)], ids=["whole_tile", "plain"])
+def test_the_state_step_kernel_compiles_for_the_v5e_at_the_cells_shape(
+        one_chip, packed, pool_bytes):
+    """The kernel at 64 slots of 30 heads of ``96 x 192``. The donated
+    pool is written where it lies (its bytes are the program's aliased
+    bytes, and nothing else of any size is allocated), and on the chip
+    the whole-tile pool ``[64, 15, 96, 384]`` is the 141.6 MB its shape
+    counts where ``[64, 30, 96, 192]`` is 188.7 MB: 192 lanes are padded
+    to 256."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+        pallas_gated_delta as G,
+    )
+
+    B, H, dk, dv = (_STATE[k] for k in ("B", "H", "dk", "dv"))
+    assert G.state_step(H, dk, dv, platform="tpu") == "kernel"
+    shape = (B,) + (G.state_layout(H, dk, dv) if packed else (H, dk, dv))
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, g, b, s, m: G.gated_delta_step_packed(
+            q, k, v, g, b, s, m, interpret=False), donate_argnums=5,
+    ).lower(sds((B, H, dk)), sds((B, H, dk)), sds((B, H, dv)), sds((B, H)),
+            sds((B, H)), sds(shape), sds((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_step" in text
+    dims = ",".join(str(d) for d in shape)
+    assert not [line for line in text.splitlines()
+                if f"= f32[{dims}]" in line and " copy(" in line]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes <= 1 << 20
+
+
+def test_olmo_hybrids_decode_step_holds_the_kernel_and_no_pass_over_the_state(
+        one_chip, monkeypatch):
+    """The whole ``_paged_decode_step`` of the published configuration as
+    a TPU runs it: twelve calls of the kernel stand where the jnp step's
+    two fusions a layer were, no reduction runs over a state-shaped
+    operand, no state pool is copied, and the state pools are aliased at
+    the bytes their shapes count (12 x 141.6 MB, not 12 x 188.7 MB)."""
+    import os
+    import re
+
+    from chipbench import spec
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.olmo_hybrid import (
+        OlmoHybridForCausalLM,
+        olmo_hybrid_config_from_hf,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve import engine
+
+    # the kernels' wrappers ask jax.devices() whether to interpret: steer
+    # them here, in the test, to lower for the TPU
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: list(one_chip.device_set))
+    cfg = spec.load_json(os.path.join(spec.HERE, "configs",
+                                      "olmo-hybrid-7b-pp2.json"))
+    dep = cfg["deployment"]
+    model = OlmoHybridForCausalLM(olmo_hybrid_config_from_hf(
+        cfg, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    assert model.state_step() == "kernel"
+    dummy = jnp.ones((1, 8), jnp.int32)
+    pshape = jax.eval_shape(
+        lambda k: model.init(k, dummy, dummy)["params"], jax.random.PRNGKey(0))
+    plan, pool_shapes = engine.build_cache_plan(model, pshape,
+                                                dep["max_model_len"])
+    assert plan.state_shapes.count(((15, 96, 384), "float32")) == 12
+    token_bytes = sum(h * d * np.dtype(t).itemsize for h, d, t in pool_shapes)
+    blocks = 1 + dep["kv_pool_bytes"] // (dep["block_size"] * token_bytes)
+    n, nb = dep["num_slots"], dep["max_model_len"] // dep["block_size"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    step = jax.jit(
+        lambda p, pools, states, *a: engine._paged_decode_step(
+            model, p, pools, *a, plan, 1024, False, states),
+        donate_argnums=(1, 2))
+    pools = [sds(shape, t) for shape, (_h, _d, t) in zip(
+        engine.pool_dims(plan, pool_shapes, blocks, dep["block_size"]),
+        pool_shapes)]
+    states = [sds((n,) + shape, jnp.dtype(t))
+              for shape, t in plan.state_shapes]
+    compiled = step.lower(
+        jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), pshape),
+        pools, states, sds((n,), jnp.int32), sds((n, nb), jnp.int32), sds((n,), jnp.int32),
+        sds((n,), jnp.bool_), sds((n,), jnp.float32), sds((n,), jnp.int32),
+        sds((n,), jnp.float32), sds((n, 2), jnp.uint32),
+        sds((n,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "gated_delta_step" in line]
+    assert len(calls) == 12
+    state = r"f32\[64,(15,96,384|30,96,192)\]"
+    for line in text.splitlines():
+        if re.search(state, line):
+            assert not re.search(state + r"\S* (copy|slice)\(", line), line
+            assert "multiply_reduce_fusion" not in line, line
+            assert "multiply_add_fusion" not in line, line
+    donated = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in pools + states)
+    # (padded pools would be 566 MB more)
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    assert donated <= aliased <= 1.01 * donated

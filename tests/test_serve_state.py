@@ -222,6 +222,63 @@ def test_spans_and_ledger_carry_the_state_counts(hybrid, tmp_path, overlap):
     assert [p for e in events for p in schema.validate_event(e)] == []
 
 
+@pytest.mark.parametrize("seen", ["xla", "kernel"])
+def test_state_step_is_on_the_decode_spans_and_in_the_stats(
+        tmp_path, monkeypatch, seen):
+    """Sixteen heads of ``8 x 16``: eight side by side fill a lane tile,
+    so the state is carried ``[2, 8, 128]`` and the fused kernel has
+    blocks for it. On this CPU the one-token step is the jnp form
+    (``xla``); steered as a TPU would see it (in the test: the program has
+    no option for it) the decode steps run the kernel, interpreted, and
+    end on the reference's tokens all the same. The jitted steps are keyed
+    on the model, not on what it sees, so a case starts and ends with
+    empty caches."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+        pallas_gated_delta,
+    )
+
+    hf = dict(HF, linear_num_key_heads=16, linear_num_value_heads=16)
+    cfg = O.olmo_hybrid_config_from_hf(hf, eos_token_id=100257,
+                                       pad_token_id=0)
+    jax.clear_caches()
+    if seen == "kernel":
+        monkeypatch.setattr(
+            O, "state_step", lambda cfg: pallas_gated_delta.state_step(
+                cfg.linear_num_heads, cfg.linear_key_head_dim,
+                cfg.linear_value_head_dim, platform="tpu"))
+    model = O.OlmoHybridForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.ones((1, 8), jnp.int32))["params"]
+    obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
+    try:
+        prompts = _prompts(8, (5, 23, 17))
+        eng, reqs = _serve(model, params, prompts, 6, num_slots=2,
+                           num_blocks=40)
+        st = eng.stats()
+        obs.flush()
+        events = _events(tmp_path)
+    finally:
+        obs.reset(enabled=False)
+        jax.clear_caches()
+    assert ((2, 8, 128), "float32") in eng._plan.state_shapes
+    assert st.state_step == eng.slo_summary()["state_step"] == seen
+    for p, r in zip(prompts, reqs):
+        lg = np.asarray(reference.logits(
+            params, hf, jnp.asarray(np.concatenate([p, eng.output_ids(r)])),
+            jnp.arange(len(p) - 1, len(p) + 5)))
+        out = eng.output_ids(r)
+        assert float((lg.max(-1) - lg[np.arange(6), out]).max()) <= 1e-5
+    spans = [e for e in events if e.get("type") == "span"]
+    dec = [e["args"] for e in spans if e["name"] == "serve/decode_step"]
+    assert dec and all(a["state_step"] == seen for a in dec)
+    assert all("state_step" not in e["args"] for e in spans
+               if e["name"] == "serve/prefill_chunk")
+    report = [e for e in events if e.get("event") == "report"][-1]
+    assert report["state_step"] == seen
+    from huggingface_sagemaker_tensorflow_distributed_tpu.obs import schema
+    assert [p for e in events for p in schema.validate_event(e)] == []
+
+
 # -- a K/V model is left as it was ----------------------------------------------
 
 def _llama():
@@ -255,7 +312,7 @@ def test_a_k_v_model_has_no_state_operand_and_no_state_field(tmp_path):
     assert st.state_pool_bytes is None and st.state_slots_peak is None
     new = {"state_slots", "kv_tokens_resident", "state_slots_peak",
            "prefill_tokens", "state_bytes_per_slot", "state_pool_bytes",
-           "state_form", "state_rows"}
+           "state_form", "state_rows", "state_step"}
     for e in events:
         assert not new & (set(e) | set(e.get("args") or {})), e
     assert "kv_token_bytes" not in eng.slo_summary()
