@@ -42,6 +42,7 @@ from typing import Optional
 from huggingface_sagemaker_tensorflow_distributed_tpu.obs import core as _core
 from huggingface_sagemaker_tensorflow_distributed_tpu.obs import (  # noqa: F401
     flops,
+    programs,
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.obs.anomaly import (  # noqa: F401
     AnomalyDetector,
@@ -289,11 +290,17 @@ def compile_tracker() -> Optional[CompileTracker]:
     return install_compile_tracker(_state)
 
 
-def flush() -> None:
+def flush(program_maps: bool = True) -> None:
     """Write the pending spans to the event file and refresh trace.json
-    from the span buffer."""
+    from the span buffer; with a sink, also one ``program_map`` event
+    for each registered program that has none yet (``obs.programs``:
+    the first such call compiles, or finds in the compile cache, every
+    registered program). ``program_maps=False`` is for a flush inside
+    a region somebody times."""
     _state.flush_spans()
     _state.flush_trace()
+    if program_maps:
+        programs.flush(_state)
 
 
 def shutdown() -> None:
@@ -307,6 +314,7 @@ def shutdown() -> None:
     if _detector is not None:
         _detector.shutdown()     # close any open profiler window
         _detector = None
+    programs.flush(_state)
     _state.shutdown()
 
 
@@ -317,6 +325,7 @@ def reset(out_dir: Optional[str] = None,
     global _state, _tracer, _metrics, _heartbeat, _budget_agreed
     _budget_agreed = False
     shutdown()
+    programs.reset()
     _state = ObsState()
     _tracer = Tracer(_state)
     _metrics = MetricsSink(_state)

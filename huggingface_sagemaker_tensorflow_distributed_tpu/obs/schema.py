@@ -82,7 +82,26 @@ REQUIRED_FIELDS: dict[str, dict[str, tuple]] = {
     "serve": {"event": (str,)},
     # run metadata, first event after configure()
     "run": {"argv": (list,)},
+    # one compiled hot-path program by the program's own modules
+    # (obs/programs.py; written once a program at obs.flush/shutdown):
+    # "program" is the short name a trace gives its module
+    # (`prefill_chunk` of `jit__prefill_chunk`), "key" the shapes that
+    # tell programs of one name apart (rows, width | bucket, slots |
+    # batch), "scopes" the distinct `[module path, pass]` pairs (numbered
+    # layers collapsed, pass "" | "fwd" | "bwd"), "ops" every instruction
+    # that runs as an operation of its own: `name -> [scope index (-1:
+    # none), component, result type, mixed]`, a fifth element listing a
+    # mixed fusion's other components; "resolve_s" what lowering and
+    # compiling (or finding) it took, "from_cache" whether the
+    # persistent compilation cache had it
+    "program_map": {"program": (str,), "key": (dict,), "scopes": (list,),
+                    "ops": (dict,), "resolve_s": _NUM,
+                    "from_cache": (bool,)},
 }
+
+# the components a program_map files an operation under
+PROGRAM_COMPONENTS = ("mixer", "ffn", "residual", "head", "embed", "cache",
+                      "optimizer", "collective", "other")
 
 # optional per-type fields that are TYPE-CHECKED when present (absence
 # is fine — they ride specific event subtypes): the serve engine's
@@ -92,6 +111,12 @@ REQUIRED_FIELDS: dict[str, dict[str, tuple]] = {
 # prompt tokens served from shared KV blocks and the hit rate; the
 # final report event the aggregates + block-sharing peaks)
 OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
+    # the registry's own name of the program, the HLO module's name,
+    # the program's own jax.named_scopes the text carried (an executable
+    # a cache held from before they were added carries none), whether
+    # the program differentiates (a train step)
+    "program_map": {"name": (str,), "module": (str,), "refined_by": (list,),
+                    "training": (bool,)},
     # nesting: how many spans enclose this one on its thread, and the
     # innermost one's name (absent on a top-level span)
     "span": {"depth": (int,), "parent": (str,), "args": (dict,)},
@@ -408,6 +433,35 @@ ENVELOPE_FIELDS: dict[str, tuple] = {
 }
 
 
+def _program_map_errors(obj: dict) -> list[str]:
+    """What the field types cannot say of a ``program_map``: every scope
+    is ``[path, pass]`` and every operation's row names a scope that
+    exists and a component that does. At most three faults are named."""
+    errors = []
+    scopes = obj["scopes"]
+    for i, scope in enumerate(scopes):
+        if not (isinstance(scope, list) and len(scope) == 2
+                and isinstance(scope[0], str)
+                and scope[1] in ("", "fwd", "bwd")):
+            errors.append(f"program_map: scopes[{i}] is not "
+                          "[path, '' | 'fwd' | 'bwd']")
+    for name, row in obj["ops"].items():
+        if len(errors) >= 3:
+            break
+        if not (isinstance(row, list) and len(row) in (4, 5)
+                and isinstance(row[0], int) and not isinstance(row[0], bool)
+                and isinstance(row[2], str) and isinstance(row[3], bool)):
+            errors.append(f"program_map: ops[{name!r}] is not [scope index, "
+                          "component, result type, mixed]")
+        elif row[1] not in PROGRAM_COMPONENTS:
+            errors.append(f"program_map: ops[{name!r}] has the unknown "
+                          f"component {row[1]!r}")
+        elif not -1 <= row[0] < len(scopes):
+            errors.append(f"program_map: ops[{name!r}] names scope "
+                          f"{row[0]} of {len(scopes)}")
+    return errors
+
+
 def validate_event(obj: object) -> list[str]:
     """Schema errors for one decoded event (empty list = valid)."""
     if not isinstance(obj, dict):
@@ -442,6 +496,8 @@ def validate_event(obj: object) -> list[str]:
                         or (isinstance(val, bool) and bool not in types)):
                     errors.append(f"{etype}: optional field {field!r} "
                                   f"has type {type(val).__name__}")
+    if etype == "program_map" and not errors:
+        errors.extend(_program_map_errors(obj))
     if obj.get("v") not in (None, SCHEMA_VERSION):
         errors.append(f"schema version {obj.get('v')!r} != {SCHEMA_VERSION}")
     return errors

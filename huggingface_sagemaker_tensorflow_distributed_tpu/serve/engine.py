@@ -735,6 +735,18 @@ def _assemble_cache(plan: CachePlan, pools, block_tables, context_lens,
     return jax.tree_util.tree_unflatten(plan.treedef, leaves)
 
 
+def _pick_token(logits, sampled: bool, temps, top_ks, top_ps, keys, folds):
+    """The next token of each row of ``logits`` ``[rows, vocab]``: greedy
+    argmax, or the per-slot seeded sample for rows with ``temperature >
+    0`` when the (static) ``sampled`` mode is on. Under the program's own
+    scope ``serve/sample`` (``obs/programs.py``)."""
+    with jax.named_scope("serve/sample"):
+        logits = logits.astype(jnp.float32)
+        if sampled:
+            return sample_per_slot(logits, temps, top_ks, top_ps, keys, folds)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _decode_step(model, params, pools, tokens, block_tables, context_lens,
                  active, temps, top_ks, top_ps, keys, folds,
                  plan: CachePlan, width: int, sampled: bool, states=()):
@@ -751,8 +763,9 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
     plan with ``state`` kinds, row ``s`` slot ``s``'s) come back last, as
     one list: an active slot's rows advanced by the token, every other
     row as it was."""
-    cache = _assemble_cache(plan, pools, block_tables, context_lens,
-                            width=width, state_rows=states)
+    with jax.named_scope("serve/cache_read"):
+        cache = _assemble_cache(plan, pools, block_tables, context_lens,
+                                width=width, state_rows=states)
     # kv-buffer validity includes the slot being written this step —
     # exactly generate_causal's decode-step mask, at bucket width
     valid = (jnp.arange(width)[None, :]
@@ -765,28 +778,26 @@ def _decode_step(model, params, pools, tokens, block_tables, context_lens,
         mutable=["cache", "moe_stats"] if routes else ["cache"],
         **({"token_mask": active[:, None]}
            if _masks_tokens(model, plan) else {}))
-    last = logits[:, -1, :].astype(jnp.float32)
-    if sampled:
-        next_tok = sample_per_slot(last, temps, top_ks, top_ps, keys, folds)
-    else:
-        next_tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    next_tok = _pick_token(logits[:, -1, :], sampled, temps, top_ks, top_ps,
+                           keys, folds)
     # scatter the step's writes back; inactive slots route to the null
     # block so the scatter itself needs no masking
-    safe_tables = jnp.where(active[:, None], block_tables, 0)
-    pos = jnp.where(active, context_lens, 0)
     mut_leaves = jax.tree_util.tree_leaves(mut["cache"])
     new_pools, new_states = list(pools), list(states)
-    for leaf, kind in zip(mut_leaves, plan.kinds):
-        if kind[0] == "state":
-            # the model held an inactive row's state still (token_mask)
-            new_states[kind[1]] = leaf
-        if kind[0] not in _POOLED:
-            continue
-        written = jnp.take_along_axis(
-            leaf, pos[:, None, None, None], axis=2)[:, :, 0, :]  # [S, H, D]
-        new_pools[kind[1]] = scatter_paged_kv(
-            new_pools[kind[1]], safe_tables, pos,
-            _pool_rows(new_pools[kind[1]], written))
+    with jax.named_scope("serve/cache_write"):
+        safe_tables = jnp.where(active[:, None], block_tables, 0)
+        pos = jnp.where(active, context_lens, 0)
+        for leaf, kind in zip(mut_leaves, plan.kinds):
+            if kind[0] == "state":
+                # the model held an inactive row's state still (token_mask)
+                new_states[kind[1]] = leaf
+            if kind[0] not in _POOLED:
+                continue
+            written = jnp.take_along_axis(
+                leaf, pos[:, None, None, None], axis=2)[:, :, 0, :]  # [S, H, D]
+            new_pools[kind[1]] = scatter_paged_kv(
+                new_pools[kind[1]], safe_tables, pos,
+                _pool_rows(new_pools[kind[1]], written))
     return (next_tok, _constrain_pools(new_pools, plan),
             *_moe_counts(mut), *((new_states,) if states else ()))
 
@@ -841,11 +852,8 @@ def _paged_decode_step(model, params, pools, tokens, block_tables,
         mutable=["cache", "moe_stats"] if _routes(model) else ["cache"],
         **({"token_mask": active[:, None]}
            if _masks_tokens(model, plan) else {}))
-    last = logits[:, -1, :].astype(jnp.float32)
-    if sampled:
-        next_tok = sample_per_slot(last, temps, top_ks, top_ps, keys, folds)
-    else:
-        next_tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    next_tok = _pick_token(logits[:, -1, :], sampled, temps, top_ks, top_ps,
+                           keys, folds)
     # the model scattered into the pools in place (cache mutation);
     # re-extract them BY PATH — the block_tables sibling shifts the
     # flatten order, so positional zip against plan.kinds would skew
@@ -899,13 +907,14 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     bs = pools[0].shape[1]
     max_ctx = block_tables.shape[1] * bs if width is None else width
     fresh = start == 0
-    cache = _assemble_cache(
-        plan, pools, block_tables, start, width=width,
-        state_rows=[jnp.where(fresh.reshape((G,) + (1,) * (st.ndim - 1)),
-                              jnp.zeros((), st.dtype),
-                              st.at[state_rows].get(mode="fill",
-                                                    fill_value=0))
-                    for st in states])
+    with jax.named_scope("serve/cache_read"):
+        cache = _assemble_cache(
+            plan, pools, block_tables, start, width=width,
+            state_rows=[jnp.where(fresh.reshape((G,) + (1,) * (st.ndim - 1)),
+                                  jnp.zeros((), st.dtype),
+                                  st.at[state_rows].get(mode="fill",
+                                                        fill_value=0))
+                        for st in states])
     # chunk slots are marked valid; the model's step mask
     # (key_slot <= cache_index + q_index) imposes causality within the
     # chunk, and pad-tail keys sit AFTER every real query so they are
@@ -932,35 +941,35 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
         {"params": params, "cache": cache}, chunks, valid,
         position_ids=pos_ids, decode=True, deterministic=True,
         mutable=["cache", "moe_stats"] if routes else ["cache"], **extra)
-    if picks:
-        sel = logits[:, 0].astype(jnp.float32)                 # [G, V]
-    else:
-        sel = jnp.take_along_axis(
-            logits.astype(jnp.float32), last[:, None, None],
-            axis=1)[:, 0]                                      # [G, V]
-    if sampled:
-        next_tok = sample_per_slot(sel, temps, top_ks, top_ps, keys, folds)
-    else:
-        next_tok = jnp.argmax(sel, axis=-1).astype(jnp.int32)   # [G]
-    positions = (start[:, None]
-                 + jnp.arange(C, dtype=jnp.int32)[None, :]).reshape(-1)
-    tables_tok = jnp.repeat(block_tables, C, axis=0)       # [G*C, nb]
+    with jax.named_scope("serve/sample"):
+        if picks:
+            sel = logits[:, 0]                                 # [G, V]
+        else:
+            sel = jnp.take_along_axis(
+                logits.astype(jnp.float32), last[:, None, None],
+                axis=1)[:, 0]                                  # [G, V]
+    next_tok = _pick_token(sel, sampled, temps, top_ks, top_ps, keys,
+                           folds)                              # [G]
     mut_leaves = jax.tree_util.tree_leaves(mut["cache"])
     new_pools, new_states = list(pools), list(states)
-    for leaf, kind in zip(mut_leaves, plan.kinds):
-        if kind[0] == "state":
-            new_states[kind[1]] = states[kind[1]].at[state_rows].set(
-                leaf, mode="drop")
-        if kind[0] not in _POOLED:
-            continue
-        h, d = leaf.shape[1], leaf.shape[3]
-        written = jax.vmap(
-            lambda row, s: lax.dynamic_slice(row, (0, s, 0), (h, C, d))
-        )(leaf, start)                                      # [G, H, C, D]
-        written = written.transpose(0, 2, 1, 3).reshape(G * C, h, d)
-        new_pools[kind[1]] = scatter_paged_kv(
-            new_pools[kind[1]], tables_tok, positions,
-            _pool_rows(new_pools[kind[1]], written))
+    with jax.named_scope("serve/cache_write"):
+        positions = (start[:, None]
+                     + jnp.arange(C, dtype=jnp.int32)[None, :]).reshape(-1)
+        tables_tok = jnp.repeat(block_tables, C, axis=0)       # [G*C, nb]
+        for leaf, kind in zip(mut_leaves, plan.kinds):
+            if kind[0] == "state":
+                new_states[kind[1]] = states[kind[1]].at[state_rows].set(
+                    leaf, mode="drop")
+            if kind[0] not in _POOLED:
+                continue
+            h, d = leaf.shape[1], leaf.shape[3]
+            written = jax.vmap(
+                lambda row, s: lax.dynamic_slice(row, (0, s, 0), (h, C, d))
+            )(leaf, start)                                      # [G, H, C, D]
+            written = written.transpose(0, 2, 1, 3).reshape(G * C, h, d)
+            new_pools[kind[1]] = scatter_paged_kv(
+                new_pools[kind[1]], tables_tok, positions,
+                _pool_rows(new_pools[kind[1]], written))
     return (next_tok, _constrain_pools(new_pools, plan),
             *_moe_counts(mut), *((new_states,) if states else ()))
 
@@ -1012,6 +1021,15 @@ def _copy_block(pools, src, dst):
 def _copy_block_jit(donate: bool):
     # graftlint: allow[R3] no static key by design: pools are traced arrays and src/dst are traced scalars, so ONE compile covers every COW a pool geometry performs
     return jax.jit(_copy_block, donate_argnums=(0,) if donate else ())
+
+
+def _mesh_context(mesh):
+    """``use_mesh(mesh)``, or nothing to enter without a mesh."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
+        use_mesh,
+    )
+
+    return use_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
 
 # the parts of an iteration's wall time: indices of `_iter_parts` and,
@@ -2114,6 +2132,14 @@ class ServeEngine:
             C = self.sched.prefill_chunk
             nb = self.max_blocks_per_seq
             S = self.num_slots
+
+            def publish(name, fn, args, static, **key):
+                # the program map (obs/programs.py): the callable and the
+                # SHAPES it is about to run with, for a later resolve
+                obs.programs.register(
+                    name, fn, args, static=static, key=key,
+                    root=type(self.model).__name__,
+                    context=functools.partial(_mesh_context, self.mesh))
             sf = np.zeros((S,), np.float32)
             si = np.zeros((S,), np.int32)
             for mode in modes:
@@ -2137,10 +2163,14 @@ class ServeEngine:
                                       else self.prefill_buckets[:1]):
                             with obs.lifecycle_span(
                                     f"serve/warmup/prefill_g{G}/w{width}"):
-                                tok, _ = self._step_out(self._prefill_fn(
-                                    self.model, self.params, self._pools,
-                                    *null, self._plan, mode, width,
-                                    *self._state_args(null_rows)))
+                                args = (self.model, self.params, self._pools,
+                                        *null, self._plan, mode, width,
+                                        *self._state_args(null_rows))
+                                publish("prefill_chunk", self._prefill_fn,
+                                        args, (0, 12, 13, 14), rows=G,
+                                        width=width, sampled=mode)
+                                tok, _ = self._step_out(
+                                    self._prefill_fn(*args))
                                 if self.speculative and not mode:
                                     tok, self._d_pools, *_ = self._prefill_fn(
                                         self.draft_model, self.draft_params,
@@ -2151,27 +2181,34 @@ class ServeEngine:
                     with obs.lifecycle_span(
                             f"serve/warmup/decode_b{bucket}"):
                         if self.speculative:
-                            (_, _, tok, self._pools,
-                             self._d_pools) = self._spec_fn(
-                                self.model, self.params, self.draft_model,
-                                self.draft_params, self._pools,
-                                self._d_pools, si,
-                                np.zeros((S, nb), np.int32), si,
-                                np.zeros((S,), bool), sf, si, sf,
-                                np.zeros((S, 2), np.uint32), si,
-                                self._plan, self._d_plan, bucket,
-                                self.speculate_k, mode)
-                        else:
-                            def decode(tokens):
-                                return self._decode_fn(
-                                    self.model, self.params, self._pools,
-                                    tokens, np.zeros((S, nb), np.int32),
-                                    si, np.zeros((S,), bool), sf, si, sf,
+                            args = (self.model, self.params, self.draft_model,
+                                    self.draft_params, self._pools,
+                                    self._d_pools, si,
+                                    np.zeros((S, nb), np.int32), si,
+                                    np.zeros((S,), bool), sf, si, sf,
                                     np.zeros((S, 2), np.uint32), si,
-                                    self._plan, bucket, mode,
-                                    *self._state_args())
+                                    self._plan, self._d_plan, bucket,
+                                    self.speculate_k, mode)
+                            publish("spec_decode_step", self._spec_fn, args,
+                                    (0, 2, 15, 16, 17, 18, 19), bucket=bucket,
+                                    slots=S, sampled=mode)
+                            (_, _, tok, self._pools,
+                             self._d_pools) = self._spec_fn(*args)
+                        else:
+                            def decode_args(tokens):
+                                return (self.model, self.params, self._pools,
+                                        tokens, np.zeros((S, nb), np.int32),
+                                        si, np.zeros((S,), bool), sf, si, sf,
+                                        np.zeros((S, 2), np.uint32), si,
+                                        self._plan, bucket, mode,
+                                        *self._state_args())
 
-                            tok, _ = self._step_out(decode(si))
+                            publish(self._decode_fn.__name__.lstrip("_"),
+                                    self._decode_fn, decode_args(si),
+                                    (0, 12, 13, 14), bucket=bucket, slots=S,
+                                    sampled=mode)
+                            tok, _ = self._step_out(
+                                self._decode_fn(*decode_args(si)))
                             if self.overlap:
                                 # the dispatch-ahead loop feeds the
                                 # previous step's device-resident tokens
@@ -2181,7 +2218,8 @@ class ServeEngine:
                                 # a second compile (found on four chips:
                                 # two 5 s compiles mid-serve); on one
                                 # device it is a cache hit
-                                tok, _ = self._step_out(decode(tok))
+                                tok, _ = self._step_out(
+                                    self._decode_fn(*decode_args(tok)))
                         jax.block_until_ready(tok)
             if (self.overlap and not self.speculative
                     and not self._warmed_modes):
@@ -2585,12 +2623,7 @@ class ServeEngine:
             self._step()
 
     def _mesh_ctx(self):
-        from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
-            use_mesh,
-        )
-
-        return (use_mesh(self.mesh) if self.mesh is not None
-                else contextlib.nullcontext())
+        return _mesh_context(self.mesh)
 
     def _step(self) -> None:
         t_iter0 = self._t_lap = time.perf_counter()
@@ -3137,12 +3170,10 @@ class ServeEngine:
             self.blocks.note_gather([s.context_len + 1 for s in ds], bucket)
             # the step's KV read traffic in POOL bytes (every slot row of
             # the dispatch × the bucket width × bytes/token across pools —
-            # int8 pools halve this, which is the point): one scalar per
-            # decode step, aggregated into the SLO report
+            # int8 pools halve this, which is the point): a running sum
+            # the SLO report carries
             step_bytes = self.num_slots * bucket * self.blocks.token_bytes
             self.kv_bytes_read += step_bytes
-            if obs.has_sink():
-                obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
             # blocks_saved() == 0 means no block is shared right now — the
             # per-slot table walk would only accumulate zeros, so skip it
             # (the common case for non-templated traffic with the cache on)
@@ -3257,8 +3288,6 @@ class ServeEngine:
             self.blocks.note_gather([s.context_len + 1 for s in ds], bucket)
             step_bytes = self.num_slots * bucket * self.blocks.token_bytes
             self.kv_bytes_read += step_bytes
-            if obs.has_sink():
-                obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
             if self.prefix_cache and self.blocks.blocks_saved() > 0:
                 self.blocks.note_shared_reads(sum(
                     self.blocks.shared_read_tokens(s.table, s.context_len)
@@ -3418,8 +3447,6 @@ class ServeEngine:
             # isolates, so account the verify read (one bucket per slot row)
             step_bytes = self.num_slots * bucket * self.blocks.token_bytes
             self.kv_bytes_read += step_bytes
-            if obs.has_sink():
-                obs.scalar("serve/kv_bytes_read", step_bytes, self.iterations)
             if self.prefix_cache and self.blocks.blocks_saved() > 0:
                 self.blocks.note_shared_reads(sum(
                     self.blocks.shared_read_tokens(s.table, s.context_len)

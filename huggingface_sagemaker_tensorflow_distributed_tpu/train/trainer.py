@@ -593,14 +593,14 @@ class Trainer:
         # The H2D double buffer's HBM headroom comes from the fit loop
         # dropping batch N's last reference when it rebinds to N+1.
         # graftlint: allow[R3] no static key: state + batch are traced pytrees, the model/config are bound on self._train_step_impl — one compile per trainer (the compile-budget tracker watches it)
-        self._train_step = self._with_mesh(jax.jit(
+        self._train_step = self._published("_train_step", jax.jit(
             self._train_step_impl,
             in_shardings=(self.state_shardings, None),
             out_shardings=(self.state_shardings, None),
             donate_argnums=(0,),
         ))
         # graftlint: allow[R3] no static key: params + batch are traced pytrees, same contract as the train step above
-        self._eval_step = self._with_mesh(jax.jit(
+        self._eval_step = self._published("_eval_step", jax.jit(
             self._eval_step_impl,
             in_shardings=(self.state_shardings.params, None),
             out_shardings=None,
@@ -642,12 +642,42 @@ class Trainer:
 
         return wrapped
 
+    def _published(self, attr: str, jitted):
+        """``jitted`` under this trainer's mesh, as ``self.<attr>``. Its
+        FIRST call leaves the callable and the shapes it ran with in the
+        program map's registry (``obs/programs.py``) and then steps
+        aside: every later call is the plain wrapped function, so the fit
+        loop gains no call and no branch."""
+        from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
+            use_mesh,
+        )
+
+        run = self._with_mesh(jitted)
+        mesh = self.mesh
+
+        @functools.wraps(jitted)
+        def first(*args):
+            obs.programs.register(
+                attr.lstrip("_"), jitted, args,
+                key={"batch": "x".join(
+                    str(n) for n in jax.tree.leaves(args[-1])[0].shape)},
+                root=type(self.model).__name__,
+                context=lambda: use_mesh(mesh))
+            setattr(self, attr, run)
+            return run(*args)
+
+        return first
+
     # -- jitted bodies ------------------------------------------------------
 
     def _train_step_impl(self, state: TrainState, batch):
         rng = jax.random.fold_in(self._base_rng, state.step)
         rngs = {"dropout": rng}
 
+        # `train/loss` and `train/optimizer` are the program's own scopes
+        # (obs/programs.py): the model's modules inside the first keep
+        # their own paths
+        @jax.named_scope("train/loss")
         def loss_of(params):
             if not self._has_sown_losses:
                 loss, sums = self.loss_fn(self.model.apply, params, batch, rngs, True)
@@ -665,8 +695,9 @@ class Trainer:
             return loss, sums
 
         (loss, sums), grads = jax.value_and_grad(loss_of, has_aux=True)(state.params)
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("train/optimizer"):
+            updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt)
         metrics = {
             "loss": loss,
@@ -855,7 +886,10 @@ class Trainer:
             if heartbeat is not None:
                 heartbeat.unwatch()
             if obs_files:
-                obs.flush()
+                # a fit's end may lie inside a window somebody times: the
+                # program maps wait for obs.shutdown() or a caller's own
+                # obs.flush()
+                obs.flush(program_maps=False)
 
         obs_epilogue.callback(_obs_fit_done)
         with obs_epilogue, Stopwatch() as sw:

@@ -15,7 +15,8 @@ lint steps, the bench driver). Exit 0 iff every file parses, every
 event carries the envelope + per-type required fields (`host_pause`
 and a span's `parent` among them; a serve event's typed-when-present
 fields include the iteration ledger's `stage_s` / `dispatch_s` /
-`fetch_wait_s` / `commit_s` / `gap_s`), and at least one
+`fetch_wait_s` / `commit_s` / `gap_s`; a `program_map`'s rows each name
+a scope that exists and a known component), and at least one
 valid event exists per file (an empty artifact is a failure: it means
 the instrumented run emitted nothing). A torn FINAL jsonl line is
 tolerated (crash-safe append contract); torn middle lines are not.

@@ -489,6 +489,11 @@ def main(argv=None) -> dict:
         # raise — a crash after "save started" must not lose the checkpoint
         if checkpointer is not None:
             checkpointer.close()
+    # the program maps (obs/programs.py) while the trainer, whose steps
+    # they describe, is still here; nothing without a telemetry directory
+    from huggingface_sagemaker_tensorflow_distributed_tpu import obs
+
+    obs.flush()
     return results
 
 

@@ -46,6 +46,11 @@ def _clear_jax_caches_per_module():
     yield
     import gc
 
+    from huggingface_sagemaker_tensorflow_distributed_tpu.obs import programs
+
+    # with its executables the module's registered programs go: a later
+    # module's run with a telemetry sink would compile them all anew
+    programs.reset()
     jax.clear_caches()
     gc.collect()
 
