@@ -157,6 +157,9 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               # which way decode steps attend (ISSUE 29): the fused
               # paged kernel or the gathered copy of the cache
               "decode_path": (str,),
+              # how prefill dispatches write their chunks back (ISSUE
+              # 38): "pages" (whole blocks) | "rows" (one a token)
+              "write_path": (str,),
               "kv_dtype": (str,),
               "kv_bytes_read": (int,),
               "kv_bytes_read_per_step": _NUM,

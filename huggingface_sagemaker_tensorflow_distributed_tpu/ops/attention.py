@@ -142,6 +142,23 @@ def make_banded_causal_mask(q_len: int, window: int,
 # ---------------------------------------------------------------------------
 
 
+def _heads_mesh(heads: int):
+    """The ambient mesh, where its ``tensor`` axis is >1 wide and divides
+    ``heads`` (the serve engine's TP mode traces its steps inside
+    ``use_mesh`` and shards every K/V pool on its heads axis); None
+    without one."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
+        AXIS_TENSOR,
+        maybe_current_mesh,
+    )
+
+    mesh = maybe_current_mesh()
+    if mesh is None:
+        return None
+    tp = mesh.shape.get(AXIS_TENSOR, 1)
+    return None if tp <= 1 or heads % tp else mesh
+
+
 def _pin_heads(x, axis: int):
     """Under an ambient mesh with a >1 ``tensor`` axis (the serve
     engine's TP mode traces its steps inside ``use_mesh``), pin ``x``'s
@@ -154,14 +171,10 @@ def _pin_heads(x, axis: int):
     for its own pools; other callers just stay unconstrained)."""
     from huggingface_sagemaker_tensorflow_distributed_tpu.parallel.mesh import (
         AXIS_TENSOR,
-        maybe_current_mesh,
     )
 
-    mesh = maybe_current_mesh()
+    mesh = _heads_mesh(x.shape[axis])
     if mesh is None:
-        return x
-    tp = mesh.shape.get(AXIS_TENSOR, 1)
-    if tp <= 1 or x.shape[axis] % tp:
         return x
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -169,6 +182,28 @@ def _pin_heads(x, axis: int):
     spec[axis] = AXIS_TENSOR
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, PartitionSpec(*spec)))
+
+
+def _key_major_page_rows(pool):
+    """A key-major pool ``[N, bs, H, D]`` as ``[N, bs * H, D]``, a page's
+    rows one a (key, head) pair, or None where that view is not free:
+    a head-major pool (:func:`~.pallas_paged_attention.head_major_rows`),
+    and a pool sharded on its heads (the merged axis could not carry the
+    sharding). On the v5e the view is a bitcast (bf16 ``[N, 16, 2, 128]``
+    tiled ``(2, 128)`` is byte for byte ``[N, 32, 128]`` tiled ``(8,
+    128)``: PR 29) and it is what the compiler reads and writes a WHOLE
+    page of where it lies: through the four axes it re-laid the whole pool
+    out, to ``{3,1,2,0:T(8,128)}``, before a block-windowed scatter and
+    back after it, and before the gather of a one-row dispatch (rehearsal
+    compiles for the v5e, PR 38)."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_attention import (
+        head_major_rows,
+    )
+
+    N, bs, H, D = pool.shape
+    if head_major_rows(H) or _heads_mesh(H) is not None:
+        return None
+    return pool.reshape(N, bs * H, D)
 
 
 def gather_paged_kv(pool, block_tables, width: int | None = None):
@@ -211,8 +246,10 @@ def gather_paged_kv(pool, block_tables, width: int | None = None):
                 f"bucket width {width} needs {nb} blocks/slot but the "
                 f"block table holds {block_tables.shape[1]}")
         block_tables = block_tables[:, :nb]
-    g = pool[block_tables]                     # [S, nb, bs, H, D]
-    S, nb, bs, H, D = g.shape
+    rows = _key_major_page_rows(pool)
+    g = (pool if rows is None else rows)[block_tables]
+    (S, nb), (_, bs, H, D) = block_tables.shape, pool.shape
+    g = g.reshape(S, nb, bs, H, D)
     return _pin_heads(g.transpose(0, 3, 1, 2, 4).reshape(S, H, nb * bs, D),
                       axis=1)
 
@@ -224,7 +261,13 @@ def scatter_paged_kv(pool, block_tables, positions, values):
     here is the [n, blocks_per_slot] table of the written slots (one row
     per written token). Callers route writes for INACTIVE slots to the
     reserved null block 0 (never allocated to a request), so a fully
-    static-shape step can always scatter.
+    static-shape step can always scatter. This is the write of the steps
+    that add ONE token a slot and have no whole block to write (the decode
+    step, gathered or fused: ``serve/engine.py::_decode_step``, the paged
+    branches of ``models/llama.py`` and ``models/deepseek_v2.py``; the
+    speculative window), and of a prefill chunk that is no multiple of the
+    block size; a prefill dispatch on the block grid writes whole blocks
+    (:func:`scatter_paged_blocks`).
 
     Under a tensor-parallel serving mesh the write is shard-local like
     the gather: ``values`` carries the pool's heads axis (sharded by
@@ -254,6 +297,51 @@ def scatter_paged_kv(pool, block_tables, positions, values):
             rows = rows.at[at].set(values.reshape(n * H, D))
             return rows.reshape(N, H, bs, D).transpose(0, 2, 1, 3)
     return pool.at[block_ids, positions % bs].set(values)
+
+
+def scatter_paged_blocks(pool, block_tables, start, values):
+    """Write a prefill dispatch's chunks into the paged ``pool`` as WHOLE
+    BLOCKS: ``values`` ``[G, heads, C, head_dim]`` are row ``g``'s ``C``
+    tokens from logical position ``start[g]``, with ``start`` on the block
+    grid and ``C`` a multiple of the block size (callers guarantee both),
+    so row ``g`` owns exactly blocks ``block_tables[g, start[g] // bs :
+    start[g] // bs + C // bs]`` and each gets ONE update, laid out as the
+    pool stores a block: ``[bs, D]`` in a latent pool (``[N, bs, D]``;
+    ``heads`` is 1), ``[H, bs, D]`` through the ``transpose(0, 2, 1, 3)``
+    view where the chip stores a page head-major, else the page's ``bs *
+    H`` (key, head) rows (:func:`_key_major_page_rows`), or ``[bs, H, D]``
+    as it is where the heads are sharded: the update carries the heads
+    axis and the write stays shard-local, as in :func:`scatter_paged_kv`.
+    The same values reach the same pool rows as one
+    :func:`scatter_paged_kv` row a token; XLA's scatter walks its indices
+    one after another, about 70 ns a 256-byte row on the v5e and 90 ns an
+    8 KB page (0.5 us a 122 KB one: 250 GB/s), so a four-row dispatch's
+    128 pages a pool cost 0.8 ms over Qwen's 72 pools where 2,048 rows
+    cost 10.4, and 0.5 ms over Olmo-Hybrid's 8 where 61,440 (token, head)
+    rows cost 33.9 (chip runs, PR 38). A pad row rides the null table and
+    writes block 0 many times over (never read)."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.pallas_paged_attention import (
+        head_major_rows,
+    )
+
+    bs = pool.shape[1]
+    G, H, C, D = values.shape
+    n = C // bs
+    cols = start[:, None] // bs + jnp.arange(n, dtype=start.dtype)[None, :]
+    ids = jnp.take_along_axis(block_tables, cols, axis=1).reshape(G * n)
+    if pool.ndim == 3:
+        return pool.at[ids].set(values.reshape(G * n, bs, D))
+    if head_major_rows(H):
+        pages = (values.reshape(G, H, n, bs, D).transpose(0, 2, 1, 3, 4)
+                 .reshape(G * n, H, bs, D))
+        return (pool.transpose(0, 2, 1, 3).at[ids].set(pages)
+                .transpose(0, 2, 1, 3))
+    pages = values.transpose(0, 2, 1, 3)          # [G, C, H, D]: key-major
+    rows = _key_major_page_rows(pool)
+    if rows is None:
+        return pool.at[ids].set(pages.reshape(G * n, bs, H, D))
+    return (rows.at[ids].set(pages.reshape(G * n, bs * H, D))
+            .reshape(pool.shape))
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens,

@@ -210,6 +210,7 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.models.generate import (
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
     gather_paged_kv,
+    scatter_paged_blocks,
     scatter_paged_kv,
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.serve.paged_kv import (
@@ -870,6 +871,19 @@ def _paged_decode_step(model, params, pools, tokens, block_tables,
             *((new_states,) if states else ()))
 
 
+def prefill_write_path(chunk: int, block_size: int) -> str:
+    """How a prefill dispatch puts its chunks' K/V (or latent rows) back
+    into the pools: ``"pages"``, one update a WHOLE block
+    (``ops.attention.scatter_paged_blocks``), where the chunk is a multiple
+    of the block size: ``start`` is on the chunk grid (the scheduler's
+    ``prefill_pos``), so a row's chunk is exactly ``chunk // block_size``
+    blocks of its table; ``"rows"``, one update a token
+    (``scatter_paged_kv``), for any other chunk. Static at trace time; it
+    is ``write_path`` on every ``serve/prefill_chunk`` span and in
+    ``stats()``."""
+    return "pages" if chunk % block_size == 0 else "rows"
+
+
 def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
                    temps, top_ks, top_ps, keys, folds, plan: CachePlan,
                    sampled: bool, width: Optional[int] = None, states=(),
@@ -877,7 +891,9 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
     """One BATCHED prefill dispatch: up to G prefilling slots' chunks as
     G independent rows (static [G, C] shape; unused rows carry pad
     tokens against the null block table). Each row writes its chunk's
-    K/V into its own blocks starting at ``start[g]`` and returns the
+    K/V into its own blocks starting at ``start[g]`` (as WHOLE blocks, one
+    update each, where the chunk is a multiple of the block size; a row a
+    token otherwise: :func:`prefill_write_path`) and returns the
     token after prompt position ``rel[g]`` (chunk-relative index of the
     last REAL prompt token; meaningful on a final chunk only — other
     rows return a discarded value). Isolation between the packed
@@ -952,10 +968,12 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
                            folds)                              # [G]
     mut_leaves = jax.tree_util.tree_leaves(mut["cache"])
     new_pools, new_states = list(pools), list(states)
+    by_pages = prefill_write_path(C, bs) == "pages"
     with jax.named_scope("serve/cache_write"):
-        positions = (start[:, None]
-                     + jnp.arange(C, dtype=jnp.int32)[None, :]).reshape(-1)
-        tables_tok = jnp.repeat(block_tables, C, axis=0)       # [G*C, nb]
+        if not by_pages:
+            positions = (start[:, None] + jnp.arange(
+                C, dtype=jnp.int32)[None, :]).reshape(-1)
+            tables_tok = jnp.repeat(block_tables, C, axis=0)   # [G*C, nb]
         for leaf, kind in zip(mut_leaves, plan.kinds):
             if kind[0] == "state":
                 new_states[kind[1]] = states[kind[1]].at[state_rows].set(
@@ -966,10 +984,14 @@ def _prefill_chunk(model, params, pools, chunks, block_tables, start, rel,
             written = jax.vmap(
                 lambda row, s: lax.dynamic_slice(row, (0, s, 0), (h, C, d))
             )(leaf, start)                                      # [G, H, C, D]
-            written = written.transpose(0, 2, 1, 3).reshape(G * C, h, d)
-            new_pools[kind[1]] = scatter_paged_kv(
-                new_pools[kind[1]], tables_tok, positions,
-                _pool_rows(new_pools[kind[1]], written))
+            pool = new_pools[kind[1]]
+            if by_pages:
+                new_pools[kind[1]] = scatter_paged_blocks(
+                    pool, block_tables, start, written)
+            else:
+                rows = written.transpose(0, 2, 1, 3).reshape(G * C, h, d)
+                new_pools[kind[1]] = scatter_paged_kv(
+                    pool, tables_tok, positions, _pool_rows(pool, rows))
     return (next_tok, _constrain_pools(new_pools, plan),
             *_moe_counts(mut), *((new_states,) if states else ()))
 
@@ -1269,6 +1291,9 @@ class EngineStats(NamedTuple):
     # speculative engine's windows are gather steps whatever ``kernel``)
     decode_path: str = "gather"
     decode_steps_by_path: Optional[dict] = None
+    # how prefill dispatches write their chunks back (ISSUE 38): "pages",
+    # one update a whole block, or "rows", one a token
+    write_path: str = "rows"
     kv_dtype: str = "fp"
     kv_bytes_read: int = 0
     kv_token_bytes: int = 0
@@ -1389,7 +1414,11 @@ class ServeEngine:
     an XLA loop elsewhere); the engine writes the answer beside
     ``latent_path`` on each ``serve/prefill_chunk`` span and counts the
     dispatches by form (``prefill_dispatches_by_form`` in ``stats()``,
-    ``slo_summary()`` and the ``report`` event). ``kv_cache_dtype`` (None reads
+    ``slo_summary()`` and the ``report`` event). Every prefill dispatch
+    writes its chunks back as whole blocks where ``prefill_chunk`` is a
+    multiple of ``block_size`` (:func:`prefill_write_path`: ``write_path``
+    beside ``latent_path`` on the span, and in ``stats()`` and the
+    ``report`` event beside ``decode_path``). ``kv_cache_dtype`` (None reads
     ``HSTD_SERVE_KV_DTYPE``, default = the model config's own value)
     selects pool storage; ``int8`` rebuilds the serving module around
     ``kv_cache_dtype='int8'`` (params untouched) and the exactness
@@ -1618,6 +1647,9 @@ class ServeEngine:
         # a speculative window attends a gathered cache (``_spec_fn``)
         # whatever the plain step would have done
         self.decode_path = "gather" if self.speculate_k else path
+        # how a prefill dispatch writes its chunks back: the rule the
+        # traced program follows, on the same two numbers
+        self.write_path = prefill_write_path(prefill_chunk, block_size)
         if (self._latent or self._routes) and self.speculate_k:
             raise ValueError(
                 "speculative decoding is not wired for latent-"
@@ -2330,6 +2362,7 @@ class ServeEngine:
                 self.decode_tokens / self.decode_time_s, 1)
         out["kernel"] = self.kernel
         out["decode_path"] = self.decode_path
+        out["write_path"] = self.write_path
         out["kv_dtype"] = self.kv_cache_dtype
         # latent cache / routed experts: absent for any other model
         if self._latent:
@@ -2541,6 +2574,7 @@ class ServeEngine:
             decode_steps_by_path={
                 p: self.decode_steps if p == self.decode_path else 0
                 for p in ("paged_kernel", "gather")},
+            write_path=self.write_path,
             kv_dtype=self.kv_cache_dtype,
             kv_bytes_read=self.kv_bytes_read,
             kv_token_bytes=self.blocks.token_bytes,
@@ -3068,6 +3102,7 @@ class ServeEngine:
         latent_kw = self._latent_kw(C, width)
         with obs.span("serve/prefill_chunk",
                       {"chunks": len(slots), "rows": G, "width": width,
+                       "write_path": self.write_path,
                        **latent_kw, **self._state_kw(C, len(slots))}
                       if obs.has_sink() else None):
             tok, moe = self._step_out(self._prefill_fn(

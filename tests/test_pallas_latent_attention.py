@@ -10,9 +10,13 @@ widths, with the paged DECODE kernel of the absorbed form
 (``ops/pallas_paged_latent_attention.py``, ISSUE 34) beside it at the
 cell's shape, and the fused one-token step of the gated delta rule
 (``ops/pallas_gated_delta.py``, ISSUE 36) alone and inside Olmo-Hybrid's
-whole decode step: one file describes the topology."""
+whole decode step, and a prefill dispatch's write of whole pages
+(``ops/attention.py::scatter_paged_blocks``, ISSUE 38) alone and inside
+Qwen's prefill programs: one file describes the topology."""
 
 import dataclasses
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -382,9 +386,6 @@ def test_olmo_hybrids_decode_step_holds_the_kernel_and_no_pass_over_the_state(
     two fusions a layer were, no reduction runs over a state-shaped
     operand, no state pool is copied, and the state pools are aliased at
     the bytes their shapes count (12 x 141.6 MB, not 12 x 188.7 MB)."""
-    import os
-    import re
-
     from chipbench import spec
     from huggingface_sagemaker_tensorflow_distributed_tpu.models.olmo_hybrid import (
         OlmoHybridForCausalLM,
@@ -445,3 +446,92 @@ def test_olmo_hybrids_decode_step_holds_the_kernel_and_no_pass_over_the_state(
     # (padded pools would be 566 MB more)
     aliased = compiled.memory_analysis().alias_size_in_bytes
     assert donated <= aliased <= 1.01 * donated
+
+
+# -- a prefill dispatch's pages, written where they lie (ISSUE 38) ---------------
+
+def _pool_ops(text: str, blocks: int) -> set:
+    """The opcodes of the instructions whose result has a pool's full
+    shape (``blocks`` leading rows) in a compiled program."""
+    return {m.group(1) for m in re.finditer(
+        rf"= \S*\[{blocks},[^ ]* ([\w\-]+)\(", text)}
+
+
+# what a pool may be in a program that writes it in place: the argument,
+# free views of it, the scatter (inside its fusion) and the fusion itself
+_IN_PLACE = {"parameter", "bitcast", "scatter", "fusion", "get-tuple-element"}
+
+
+@pytest.mark.parametrize("shape,rows", [
+    ((7630, 16, 2, 128), 4), ((7630, 16, 2, 128), 1),
+    ((3561, 16, 30, 128), 4), ((19532, 16, 640), 4)],
+    ids=["qwen_g4", "qwen_g1", "olmo_g4", "latent_g4"])
+def test_the_block_write_is_in_place_on_the_v5e(one_chip, shape, rows):
+    """Key-major pages of two heads (as ``[N, 32, 128]`` rows: through
+    the four axes the compiler re-lays the whole pool out around a
+    block-windowed scatter), head-major pages of thirty, latent pages: a
+    dispatch's 32 blocks a row go into the donated pool with no
+    instruction of the pool's shape but the write."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
+        scatter_paged_blocks,
+    )
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    heads = shape[2] if len(shape) == 4 else 1
+    compiled = jax.jit(scatter_paged_blocks, donate_argnums=(0,)).lower(
+        sds(shape), sds((rows, 256), jnp.int32), sds((rows,), jnp.int32),
+        sds((rows, heads, 512, shape[-1]))).compile()
+    assert _pool_ops(compiled.as_text(), shape[0]) <= _IN_PLACE
+    pool_bytes = int(np.prod(shape)) * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 0.1 * pool_bytes
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_qwens_prefill_programs_hold_no_operation_of_a_pools_shape(one_chip,
+                                                                  rows):
+    """chat-sat's one-row and four-row ``prefill_chunk`` programs at the
+    cell's pool geometry (two layers of the 36: four pools): nothing
+    copies a pool. Before ISSUE 38 the one-row program re-laid every pool
+    out to feed its gather (``copy bf16[7630,16,2,128]{3,1,2,0}``, 7.1 ms a
+    dispatch over 72 pools), and both scattered a row a token."""
+    from chipbench import spec
+    from chipbench.families.llama import LlamaForCausalLM, llama_config_from_hf
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve import engine
+
+    cfg = spec.load_json(os.path.join(spec.HERE, "configs", "qwen2.5-3b.json"))
+    dep = cfg["deployment"]
+    model = LlamaForCausalLM(llama_config_from_hf(
+        dict(cfg, num_hidden_layers=2), dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16))
+    dummy = jnp.ones((1, 8), jnp.int32)
+    pshape = jax.eval_shape(
+        lambda k: model.init(k, dummy, dummy)["params"], jax.random.PRNGKey(0))
+    plan, pool_shapes = engine.build_cache_plan(model, pshape,
+                                                dep["max_model_len"])
+    # the blocks the whole model's pools have: 36 layers' bytes a token
+    blocks = 1 + dep["kv_pool_bytes"] // (dep["block_size"] * 36_864)
+    assert blocks == 7630
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    g, c, bs = rows, dep["prefill_chunk"], dep["block_size"]
+    assert engine.prefill_write_path(c, bs) == "pages"
+    step = jax.jit(
+        lambda p, pools, *a: engine._prefill_chunk(
+            model, p, pools, *a, plan, False, 2048),
+        donate_argnums=(1,))
+    compiled = step.lower(
+        jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), pshape),
+        [sds((blocks, bs, h, d), t) for h, d, t in pool_shapes],
+        sds((g, c), jnp.int32), sds((g, dep["max_model_len"] // bs), jnp.int32),
+        sds((g,), jnp.int32), sds((g,), jnp.int32), sds((g,), jnp.float32),
+        sds((g,), jnp.int32), sds((g,), jnp.float32),
+        sds((g, 2), jnp.uint32), sds((g,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "copy(%pools_" not in text
+    assert _pool_ops(text, blocks) <= _IN_PLACE | {"tuple"}
