@@ -12,6 +12,8 @@ import pytest
 from chipbench import spec
 
 CHAT, SECOND = "qwen2.5-3b-chat-sat", "qwen2.5-3b-chat-sat-b"
+# the committed cells and the one the fixture below adds
+CELLS = len(spec.load_benchmark()["workloads"]) + 1
 HOST_LOOP = ["engine_host_ms_per_step", "engine_stage_ms_per_step",
              "engine_fetch_wait_share", "engine_fetch_wait_max_ms",
              "host_pause_max_ms", "engine_warmup_s"]
@@ -85,7 +87,7 @@ def _moves_not_reported(bench, root):
 
 
 def _too_many_four_chip_cells(bench, root):
-    bench["workloads"][-1]["chips"] = 4     # two of four cells
+    bench["workloads"][-1]["chips"] = 4     # two four-chip cells
 
 
 def _lists_no_cell(bench, root):
@@ -101,7 +103,7 @@ def _no_traffic_file(bench, root):
     (_reader_without_entry, "expert_load_share.py has no entry"),
     (_moves_not_reported, "'train_mfu' lists 'qwen2.5-3b-chat-sat-b', "
                           "which does not report"),
-    (_too_many_four_chip_cells, "2 four-chip cells of 4"),
+    (_too_many_four_chip_cells, f"2 four-chip cells of {CELLS}"),
     (_lists_no_cell, "'qwen2.5-3b-nowhere', which is no cell"),
     (_no_traffic_file, "no traffic file chat-sat-b.json"),
 ])

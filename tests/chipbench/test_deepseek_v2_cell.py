@@ -36,17 +36,18 @@ def _file():
 # -- the cell in the benchmark ---------------------------------------------------
 
 def test_the_cell_is_appended_and_lists_what_it_reports():
+    # every entry found BY NAME: later cells are appended behind these
     bench = spec.load_benchmark()
     assert spec.check(bench) == []
-    assert bench["configs"][-1]["name"] == "deepseek-v2-ep4"
-    assert bench["configs"][-1]["reduced"] == _file()["reduced"]
-    assert bench["workloads"][-1] == {
+    config = {c["name"]: c for c in bench["configs"]}["deepseek-v2-ep4"]
+    assert config["reduced"] == _file()["reduced"]
+    cells = {w["name"]: w for w in bench["workloads"]}
+    assert cells[CELL] == {
         "name": CELL, "config": "deepseek-v2-ep4", "traffic": "doc-sat",
-        "chips": 1, "why": bench["workloads"][-1]["why"]}
-    assert [m["name"] for m in bench["per_layer"][-3:]] == READERS
-    # a list that names the cell names it last
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        assert CELL not in m.get("workloads", [])[:-1], m["name"]
+        "chips": 1, "why": cells[CELL]["why"]}
+    metrics = {m["name"]: m for m in bench["per_layer"]}
+    for name in READERS:
+        assert CELL in metrics[name]["workloads"]
     cell = spec.load_cell(CELL, False)
     assert {m["name"] for m in cell.end_to_end} == {
         "serve_out_tok_per_s", "itl_p99_ms", "setup_s"}
@@ -55,9 +56,10 @@ def test_the_cell_is_appended_and_lists_what_it_reports():
                            "engine_warmup_s"} <= got
     # its bytes are every weight once: not this cell's roofline
     assert "decode_hbm_roofline_share" not in got
-    # and no other cell reports this cell's metrics
-    for w in bench["workloads"][:-1]:
-        other = {m["name"] for m in spec.load_cell(w["name"], False).per_layer}
+    # and no cell that does not route experts reports this cell's metrics
+    routed = {CELL, "xing4.0-29b-a4b-pp7-gen-sat"}
+    for name in set(cells) - routed:
+        other = {m["name"] for m in spec.load_cell(name, False).per_layer}
         assert not set(READERS) & other
 
 
