@@ -42,10 +42,19 @@ the real chip at real shapes:
   the same widths with all 128 heads: 8 slots whose contexts run from 0
   to the 2,048 bucket's width through scattered pages of 16, bf16 and
   float32, from the absorbed query to ``W_uv`` — anchored on float64
-  under the same two limits.
+  under the same two limits;
+- the gated delta rule's chunked form (``ops/pallas_gated_delta.py::
+  gated_delta_chunked_packed``) against the XLA form it replaces on a TPU
+  (``ops/gated_delta.py::gated_delta_chunked``) at Olmo-Hybrid's
+  four-row prefill dispatch (30 heads of 96 x 192, 512 tokens a row, a
+  row with a pad tail and one masked whole, from a non-zero state): the
+  output and the state, each anchored on the recurrence run one token at
+  a time in float64, under latent prefill's two limits; the masked row's
+  state bit for bit.
 
 Prints one PASS/FAIL line per check and exits non-zero on any FAIL.
-Run on the chip:  python benchmarks/tpu_kernel_parity.py
+Run on the chip:  python benchmarks/tpu_kernel_parity.py [check ...]
+(the names of the functions below; none: every check).
 """
 
 from __future__ import annotations
@@ -377,6 +386,75 @@ def latent_decode_parity() -> None:
                        ceiling=3e-2, label="pallas")
 
 
+def gated_delta_chunked_parity() -> None:
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+        pallas_gated_delta as G,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops.gated_delta import (
+        gated_delta_chunked,
+        l2_normalize,
+    )
+
+    # olmo-hybrid-7b-pp2-gen-sat's four-row prefill dispatch: 30 heads of
+    # 96 x 192 over a chunk of 512, a row with a pad tail and a row masked
+    # whole, from a non-zero state; g and beta in the ranges the layer's
+    # parameters give (decay down to exp(-1.6) a token, beta up to 2)
+    B, T, H, dk, dv = 4, 512, 30, 96, 192
+    rng = np.random.RandomState(6)
+    q = l2_normalize(jnp.asarray(rng.randn(B, T, H, dk), jnp.float32)
+                     ) * dk ** -0.5
+    k = l2_normalize(jnp.asarray(rng.randn(B, T, H, dk), jnp.float32))
+    v = jnp.asarray(rng.randn(B, T, H, dv), jnp.float32)
+    g = jnp.asarray(-rng.uniform(0.0, 1.6, (B, T, H)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 2.0, (B, T, H)), jnp.float32)
+    state = jnp.asarray(rng.randn(B, H, dk, dv) * 0.3, jnp.float32)
+    n_real = np.asarray([T, 300, 0, T])
+    mask = np.arange(T)[None, :] < n_real[:, None]
+
+    # float64 anchor: the recurrence one token at a time, as it is defined
+    q64, k64, v64, g64, b64 = (np.asarray(a, np.float64)
+                               for a in (q, k, v, g, beta))
+    s64 = np.asarray(state, np.float64).copy()
+    o64 = np.zeros((B, T, H, dv))
+    for t in range(T):
+        m = mask[:, t][:, None]
+        a = np.exp(np.where(m, g64[:, t], 0.0))[..., None, None]
+        b = np.where(m, b64[:, t], 0.0)[..., None]
+        s64 = a * s64
+        u = b * (v64[:, t] - np.einsum("bhkv,bhk->bhv", s64, k64[:, t]))
+        s64 = s64 + k64[:, t][..., None] * u[:, :, None, :]
+        o64[:, t] = np.einsum("bhkv,bhk->bhv", s64, q64[:, t])
+    want = {"o": o64, "state": s64}
+
+    m = jnp.asarray(mask)
+    xla_o, xla_s = jax.jit(gated_delta_chunked)(q, k, v, g, beta, state, m)
+    ker_o, ker_s = jax.jit(lambda *a: G.gated_delta_chunked_packed(*a, m))(
+        q, k, v, g, beta, G.pack(state))
+    got = {"pallas": {"o": ker_o, "state": G.unpack(ker_s, H)},
+           "xla": {"o": xla_o, "state": xla_s}}
+    # the row masked whole keeps its state bit for bit
+    same = bool(np.array_equal(np.asarray(got["pallas"]["state"][2]),
+                               np.asarray(state[2])))
+    print(f"{'PASS' if same else 'FAIL'} gated delta chunked (masked row): "
+          "state bit for bit")
+    if not same:
+        FAILED.append("gated delta chunked (masked row)")
+    for part in ("o", "state"):
+        name = f"gated delta chunked ({part})"
+        check_anchored(name, got["pallas"][part], got["xla"][part],
+                       want[part], ceiling=1e-3, label="pallas")
+        rms = {k: float(np.sqrt(np.mean(
+            (np.asarray(v[part], np.float64) - want[part]) ** 2)))
+            for k, v in got.items()}
+        ok = rms["pallas"] <= 1.1 * rms["xla"] + 1e-7
+        print(f"{'PASS' if ok else 'FAIL'} {name} rms: "
+              f"pallas_vs_fp64={rms['pallas']:.3e} "
+              f"xla_vs_fp64={rms['xla']:.3e} "
+              f"ratio={rms['pallas'] / max(rms['xla'], 1e-12):.3f}")
+        if not ok:
+            FAILED.append(name + " rms")
+
+
 def vocab_ce_parity() -> None:
     import optax
 
@@ -461,11 +539,17 @@ def main() -> None:
     if not on_tpu:
         print("WARNING: not a TPU — kernels fall back / interpret "
               "off-TPU, so these checks prove nothing about Mosaic")
-    flash_parity()
-    vocab_ce_parity()
-    paged_parity()
-    latent_prefill_parity()
-    latent_decode_parity()
+    checks = [flash_parity, vocab_ce_parity, paged_parity,
+              latent_prefill_parity, latent_decode_parity,
+              gated_delta_chunked_parity]
+    # names on the command line run those checks alone
+    wanted = sys.argv[1:]
+    unknown = set(wanted) - {c.__name__ for c in checks}
+    if unknown:
+        sys.exit(f"no such check: {sorted(unknown)}")
+    for c in checks:
+        if not wanted or c.__name__ in wanted:
+            c()
     if FAILED:
         print(f"FAILED: {FAILED}")
         sys.exit(1)

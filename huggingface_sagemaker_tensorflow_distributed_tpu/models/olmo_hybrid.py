@@ -32,16 +32,19 @@ dk, dv]`` otherwise; a pure function of the shapes, the same on every
 backend) and ``conv_state`` ``[B, K-1, H (2 dk + dv)]`` (the last
 ``K - 1`` rows before the convolution). The serving engine keeps both in
 per-slot state pools (``serve/engine.py``: the ``state`` kind of its
-cache plan). A one-token call runs the fused kernel on a TPU and the jnp
-step elsewhere (:func:`state_step`); a run of tokens unpacks the rows it
-was handed, runs the chunked form and packs them again. ``token_mask``
+cache plan). A one-token call runs the fused step kernel on a TPU and the
+jnp step elsewhere (:func:`state_step`); a run of tokens runs the fused
+chunk kernel on the packed rows on a TPU, and elsewhere unpacks them,
+runs the XLA chunked form and packs them again (:func:`state_chunk`).
+``token_mask``
 ``[B, T]`` says which tokens are real: the real tokens of a row come
 first, and what follows them (a prompt's pad tail, a pad row, an inactive
 slot) advances neither the state nor the convolution's tail.
 
 Out of scope: loading a published checkpoint (``models/convert.py`` has
 no mapping for this family), training-side kernels (the backward of the
-chunked scan runs as XLA differentiates it), left-padded batches through
+chunked form runs as XLA differentiates the scan, on a TPU too: the chunk
+kernel's gradient recomputes through it), left-padded batches through
 the linear layers.
 """
 
@@ -70,7 +73,6 @@ from huggingface_sagemaker_tensorflow_distributed_tpu.ops.attention import (
 )
 from huggingface_sagemaker_tensorflow_distributed_tpu.ops.gated_delta import (
     causal_conv,
-    gated_delta_chunked,
     l2_normalize,
 )
 
@@ -185,6 +187,16 @@ def state_step(cfg) -> str:
         cfg.linear_value_head_dim, platform=jax.devices()[0].platform)
 
 
+def state_chunk(cfg) -> str:
+    """``kernel`` | ``xla``: how a call of ``cfg``'s linear layers with a
+    run of tokens a row advances the state in this process
+    (``pallas_gated_delta.state_chunk`` of the shapes and of the
+    backend)."""
+    return pallas_gated_delta.state_chunk(
+        cfg.linear_num_heads, cfg.linear_key_head_dim,
+        cfg.linear_value_head_dim, platform=jax.devices()[0].platform)
+
+
 def _a_log_init(key, shape, dtype=jnp.float32):
     # Gated DeltaNet: A ~ U(0, 16), stored as its logarithm
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-4, 16.0)
@@ -264,10 +276,8 @@ class GatedDeltaMixer(nn.Module):
                 form=state_step(cfg))
             o = o[:, None]
         else:
-            o, state = gated_delta_chunked(
-                q, k, v, g, beta, pallas_gated_delta.unpack(state, H),
-                token_mask)
-            state = pallas_gated_delta.pack(state)
+            o, state = pallas_gated_delta.gated_delta_chunked_carried(
+                q, k, v, g, beta, state, token_mask, form=state_chunk(cfg))
         if carried:
             state_var.value, tail_var.value = state, tail
         # gated RMSNorm per head, float32: norm(o) * scale * SiLU(gate)
@@ -342,6 +352,12 @@ class OlmoHybridForCausalLM(nn.Module):
         """:func:`state_step` for this model in this process: what the
         serving engine writes beside ``state_form`` on a decode step."""
         return state_step(self.config)
+
+    def state_chunk(self) -> str:
+        """:func:`state_chunk` for this model in this process: what the
+        serving engine writes beside ``state_form`` on a prefill
+        dispatch."""
+        return state_chunk(self.config)
 
     def setup(self):
         cfg = self.config

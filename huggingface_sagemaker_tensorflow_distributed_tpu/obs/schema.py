@@ -273,6 +273,8 @@ OPTIONAL_FIELDS: dict[str, dict[str, tuple]] = {
               # how its one-token steps advance the state (ISSUE 36):
               # "kernel" (ops/pallas_gated_delta.py) | "xla"
               "state_step": (str,),
+              # and its prefill dispatches: "kernel" | "xla"
+              "state_chunk": (str,),
               # a latent-attention model's report (ISSUE 32): prefill
               # dispatches by how the expanded form attended
               # ({"kernel": n, "xla_loop": n})

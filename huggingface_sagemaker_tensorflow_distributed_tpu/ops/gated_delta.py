@@ -20,7 +20,11 @@ Three functions, all float32 inside whatever they are handed:
   transform (``(I + tril(K_b K^T * D, -1))^-1`` by a blocked forward
   substitution) turns the ``CHUNK`` sequential rank-one updates into matmuls, and
   a ``lax.scan`` over the chunks carries ``S``. Any length: the tail of the
-  last chunk is padded with tokens that do nothing.
+  last chunk is padded with tokens that do nothing. On a TPU a prefill
+  dispatch runs ``ops/pallas_gated_delta.py::gated_delta_chunked_packed``
+  instead, which walks a row's chunks in fast memory on the state as it is
+  carried; this form is what a CPU runs, what a gradient recomputes
+  through, and the reference that kernel is tested against.
 - :func:`causal_conv`: the depthwise causal convolution in front of the
   recurrence, with the last ``K - 1`` pre-convolution rows carried as a
   tail.

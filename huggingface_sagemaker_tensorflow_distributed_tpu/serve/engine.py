@@ -139,7 +139,9 @@ speculation; vLLM + Orca + Sarathi + Leviathan lineage):
   model: the prefix index matches nothing (``stats().prefix_cache`` says
   ``off (recurrent state)``), ``swap``, ``speculate_k``, a mesh and
   ``transport.migrate_request`` raise; preemption recomputes from 0.
-  ``state_form`` / ``state_rows`` on the step spans, ``state_slots`` /
+  ``state_form`` / ``state_rows`` on the step spans (with ``state_step``
+  on a decode step's, ``state_chunk`` on a prefill dispatch's: the
+  model's fused kernel or the XLA form), ``state_slots`` /
   ``kv_tokens_resident`` / ``prefill_tokens`` / ``state_slots_peak`` on
   the ledger lines, ``state_bytes_per_slot`` / ``state_pool_bytes`` in
   ``stats()`` and the report; a model without such layers has none of
@@ -1346,6 +1348,9 @@ class EngineStats(NamedTuple):
     # how its one-token steps advance the state (ISSUE 36): the model's
     # ``state_step``, ``kernel`` | ``xla``
     state_step: Optional[str] = None
+    # and its prefill dispatches: the model's ``state_chunk``,
+    # ``kernel`` | ``xla``
+    state_chunk: Optional[str] = None
 
 
 class ServeEngine:
@@ -2380,6 +2385,7 @@ class ServeEngine:
             out["state_pool_bytes"] = self.state_pool_bytes
             out["state_slots_peak"] = self.peak_resident
             out["state_step"] = self.model.state_step()
+            out["state_chunk"] = self.model.state_chunk()
             out["kv_token_bytes"] = self.blocks.token_bytes
         # multi-replica serving (ISSUE 14): a router-owned replica's
         # report names itself so the merged cross-host report (and
@@ -2603,7 +2609,9 @@ class ServeEngine:
             state_slots_peak=(self.peak_resident
                               if self._stateful else None),
             state_step=(self.model.state_step()
-                        if self._stateful else None))
+                        if self._stateful else None),
+            state_chunk=(self.model.state_chunk()
+                         if self._stateful else None))
 
     @property
     def prefix_cache_state(self):
@@ -2870,15 +2878,18 @@ class ServeEngine:
         """``{"state_form": "step" | "chunked", "state_rows": n}`` for a
         dispatch of ``q_len`` tokens a row of a model with recurrent
         state, ``rows`` of them real (their state is read and written),
-        and for a one-token step ``"state_step": "kernel" | "xla"`` (the
-        model's own rule on the shapes and the backend: the fused kernel
-        or the jnp form); ``{}`` for any other model."""
+        and for a one-token step ``"state_step": "kernel" | "xla"``, for a
+        run of tokens ``"state_chunk": "kernel" | "xla"`` (the model's own
+        rule on the shapes and the backend: the fused kernel or the XLA
+        form); ``{}`` for any other model."""
         if not self._stateful:
             return {}
         out = {"state_form": self.model.state_form(q_len),
                "state_rows": rows}
         if out["state_form"] == "step":
             out["state_step"] = self.model.state_step()
+        else:
+            out["state_chunk"] = self.model.state_chunk()
         return out
 
     def _state_args(self, rows=None) -> tuple:

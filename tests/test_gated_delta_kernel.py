@@ -5,9 +5,12 @@ interpret mode on the CPU: the same mathematics on the state as the
 kernel carries it (heads side by side where that fills whole lane tiles),
 a masked row's state bit for bit, the packing and its inverse, steps of
 the kernel against one call of the chunked form through the packed
-shape, and the rule that says which form a process runs. (The kernel
-compiled for the v5e is in ``tests/test_pallas_latent_attention.py``:
-one file describes the topology.)"""
+shape, and the rule that says which form a process runs. Then the fused
+CHUNK kernel against the chunked XLA form and against steps
+of the jnp step, pad tails and whole masked rows, its rule, and a
+gradient through the mixer on the kernel path. (Both kernels compiled for
+the v5e are in ``tests/test_pallas_latent_attention.py``: one file
+describes the topology.)"""
 
 import jax
 import jax.numpy as jnp
@@ -159,6 +162,7 @@ def test_the_mixer_declares_the_kernels_layout_and_runs_xla_here():
     cfg = O.olmo_hybrid_config_from_hf(hf)
     model = O.OlmoHybridForCausalLM(cfg)
     assert O.state_step(cfg) == model.state_step() == "xla"
+    assert O.state_chunk(cfg) == model.state_chunk() == "xla"
     ids = jnp.asarray(np.random.default_rng(5).integers(3, 60, (2, 6)),
                       jnp.int32)
     params = model.init(jax.random.PRNGKey(0), ids)["params"]
@@ -181,3 +185,154 @@ def test_the_mixer_declares_the_kernels_layout_and_runs_xla_here():
     want = whole["backbone"]["layers_0"]["linear_attn"]["recurrent_state"]
     np.testing.assert_allclose(got, want, atol=2e-5)
     assert float(jnp.abs(got).max()) > 0
+
+
+# -- a run of tokens: the fused chunk kernel ------------------------------------
+
+# (H, dk, dv) packed: the cell's heads two a row, and a small shape of two a
+# row (64 values a head: two fill a lane tile)
+CHUNK_SHAPES = [(30, 96, 192), (4, 8, 64)]
+CHUNK_IDS = ["cell_h30_96x192", "small_h4_8x64"]
+
+
+def _chunk_inputs(seed, B, T, H, dk, dv, n_real):
+    """Operands of a run of ``T`` tokens and a mask whose row ``b`` is
+    real up to ``n_real[b]`` (the pad tail after it; 0: a row masked
+    whole), from a non-zero state."""
+    q, k, v, g, beta, state = _inputs(seed, B, H, dk, dv, T=T)
+    mask = jnp.arange(T)[None, :] < jnp.asarray(n_real)[:, None]
+    return q, k, v, g, beta, state * 0.3, mask
+
+
+@pytest.mark.parametrize("B,T,n_real", [
+    (1, 512, [512]), (4, 64, [64, 30, 0, 64]), (4, 100, [100, 77, 0, 40])],
+    ids=["b1_t512", "b4_t64", "b4_t100_ragged"])
+@pytest.mark.parametrize("shape", CHUNK_SHAPES, ids=CHUNK_IDS)
+def test_chunk_kernel_is_the_xla_form(shape, B, T, n_real):
+    """``o`` and ``S`` to float32 rounding against ``gated_delta_chunked``
+    through the packing; a pad tail mid-chunk (rows of 30, 77 and 40
+    real tokens) advances nothing, and a row masked whole keeps its state
+    bit for bit."""
+    H, dk, dv = shape
+    q, k, v, g, beta, state, mask = _chunk_inputs(11, B, T, H, dk, dv,
+                                                  n_real)
+    want_o, want_s = gated_delta_chunked(q, k, v, g, beta, state, mask)
+    o, s = K.gated_delta_chunked_packed(q, k, v, g, beta, K.pack(state),
+                                        mask, interpret=True)
+    assert o.shape == (B, T, H, dv) and s.shape == (B,) + K.state_layout(
+        H, dk, dv)
+    s = K.unpack(s, H)
+    np.testing.assert_allclose(o, want_o, atol=2e-6, rtol=2e-5)
+    np.testing.assert_allclose(s, want_s, atol=2e-6, rtol=2e-5)
+    for b, n in enumerate(n_real):
+        if n == 0:
+            np.testing.assert_array_equal(s[b], state[b])
+        else:
+            assert float(jnp.abs(s[b] - state[b]).max()) > 1e-2
+
+
+@pytest.mark.parametrize("shape", CHUNK_SHAPES, ids=CHUNK_IDS)
+def test_the_pad_tail_advances_nothing(shape):
+    """A row of 77 real tokens ends on the state that its 77 tokens alone
+    make: the tail's operands reach nothing."""
+    H, dk, dv = shape
+    q, k, v, g, beta, state, mask = _chunk_inputs(12, 1, 100, H, dk, dv,
+                                                  [77])
+    _, s = K.gated_delta_chunked_packed(q, k, v, g, beta, K.pack(state),
+                                        mask, interpret=True)
+    cut = [a[:, :77] for a in (q, k, v, g, beta)]
+    _, alone = K.gated_delta_chunked_packed(*cut, K.pack(state),
+                                            interpret=True)
+    np.testing.assert_allclose(s, alone, atol=1e-6, rtol=1e-6)
+
+
+def test_the_chunk_kernel_is_steps_of_the_step():
+    """One kernel call over 70 tokens against 70 calls of the jnp step."""
+    H, dk, dv = 4, 8, 64
+    B, T = 2, 70
+    q, k, v, g, beta, state, mask = _chunk_inputs(13, B, T, H, dk, dv,
+                                                  [70, 45])
+    o, s = K.gated_delta_chunked_packed(q, k, v, g, beta, K.pack(state),
+                                        mask, interpret=True)
+    want_s, out = state, []
+    for t in range(T):
+        o_t, want_s = gated_delta_step(q[:, t], k[:, t], v[:, t], g[:, t],
+                                       beta[:, t], want_s, mask[:, t])
+        out.append(o_t)
+    real = np.asarray(mask)
+    np.testing.assert_allclose(np.asarray(o)[real],
+                               np.stack(out, 1)[real], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(K.unpack(s, H), want_s, atol=2e-5, rtol=2e-5)
+
+
+def test_a_chunk_on_a_state_that_is_no_packing_is_refused_by_name():
+    q, k, v, g, beta, state, mask = _chunk_inputs(14, 2, 8, 4, 8, 64, [8, 8])
+    with pytest.raises(ValueError, match="no packing"):
+        K.gated_delta_chunked_packed(q, k, v, g, beta,
+                                     state.reshape(2, 4, 64, 8),
+                                     interpret=True)
+    with pytest.raises(ValueError, match="power of two"):
+        K.gated_delta_chunked_packed(q, k, v, g, beta, K.pack(state),
+                                     chunk=48, interpret=True)
+
+
+@pytest.mark.parametrize("seen,want", [
+    (dict(H=30, dk=96, dv=192, platform="tpu"), "kernel"),
+    (dict(H=30, dk=96, dv=192, platform="cpu"), "xla"),
+    (dict(H=4, dk=64, dv=128, platform="tpu"), "kernel"),
+    (dict(H=4, dk=8, dv=64, platform="tpu"), "kernel"),
+    (dict(H=4, dk=8, dv=64, platform="cpu"), "xla"),
+    (dict(H=2, dk=8, dv=16, platform="tpu"), "xla"),      # 16 lanes a row
+    (dict(H=5, dk=96, dv=192, platform="tpu"), "xla"),    # cannot pair
+    (dict(H=16, dk=4, dv=16, platform="tpu"), "xla"),     # half a sublane tile
+], ids=["cell_tpu", "cell_cpu", "whole_tiles", "small_tpu", "small_cpu",
+        "tiny", "odd", "short_rows"])
+def test_the_chunk_form_is_a_function_of_what_the_code_sees(seen, want):
+    assert K.state_chunk(**seen) == want
+    assert want in K.STATE_STEPS
+
+
+def test_a_gradient_through_the_mixer_on_the_kernel_path_is_the_xla_forms(
+        monkeypatch):
+    """The plain forward of a tiny model whose heads pack (four of ``8 x
+    64``, two a row), its loss differentiated with respect to every
+    parameter: through the kernel (steered as a TPU would see it; in the
+    test, the program has no option for it) and through the XLA form, the
+    same gradient, and the same logits."""
+    hf = {"model_type": "olmo_hybrid", "vocab_size": 64, "hidden_size": 32,
+          "intermediate_size": 48, "num_hidden_layers": 2,
+          "num_attention_heads": 2, "num_key_value_heads": 2,
+          "layer_types": [O.LINEAR, O.FULL], "linear_num_key_heads": 4,
+          "linear_num_value_heads": 4, "linear_key_head_dim": 8,
+          "linear_value_head_dim": 64, "linear_conv_kernel_dim": 4,
+          "linear_allow_neg_eigval": True,
+          "rope_parameters": {"rope_theta": None},
+          "max_position_embeddings": 128, "tie_word_embeddings": False}
+    cfg = O.olmo_hybrid_config_from_hf(hf)
+    model = O.OlmoHybridForCausalLM(cfg)
+    ids = jnp.asarray(np.random.default_rng(15).integers(3, 60, (2, 70)),
+                      jnp.int32)
+    attn = jnp.ones((2, 70), jnp.int32).at[1, 50:].set(0)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+
+    def grad():              # a new function each call: a trace of its own
+        def loss(p):
+            lg = model.apply({"params": p}, ids, attn)
+            return jnp.sum(lg[:, :50] ** 2) * 1e-3, lg
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    assert O.state_chunk(cfg) == model.state_chunk() == "xla"
+    (want_l, want_lg), want_g = grad()
+    monkeypatch.setattr(O, "state_chunk", lambda cfg: K.state_chunk(
+        cfg.linear_num_heads, cfg.linear_key_head_dim,
+        cfg.linear_value_head_dim, platform="tpu"))
+    assert model.state_chunk() == "kernel"
+    (got_l, got_lg), got_g = grad()
+    np.testing.assert_allclose(got_lg, want_lg, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got_l, want_l, rtol=1e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_g),
+                            jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+    assert float(jnp.abs(got_g["backbone"]["layers_0"]["linear_attn"][
+        "A_log"]).max()) > 0

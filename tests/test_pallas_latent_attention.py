@@ -10,7 +10,8 @@ widths, with the paged DECODE kernel of the absorbed form
 (``ops/pallas_paged_latent_attention.py``, ISSUE 34) beside it at the
 cell's shape, and the fused one-token step of the gated delta rule
 (``ops/pallas_gated_delta.py``, ISSUE 36) alone and inside Olmo-Hybrid's
-whole decode step, and a prefill dispatch's write of whole pages
+whole decode step, its fused CHUNK kernel inside Olmo-Hybrid's
+prefill programs, and a prefill dispatch's write of whole pages
 (``ops/attention.py::scatter_paged_blocks``, ISSUE 38) alone and inside
 Qwen's prefill programs: one file describes the topology."""
 
@@ -446,6 +447,76 @@ def test_olmo_hybrids_decode_step_holds_the_kernel_and_no_pass_over_the_state(
     # (padded pools would be 566 MB more)
     aliased = compiled.memory_analysis().alias_size_in_bytes
     assert donated <= aliased <= 1.01 * donated
+
+
+# -- the gated delta rule's chunked form in one pass a chunk --------------------
+
+@pytest.mark.parametrize("rows", [4, 1])
+def test_olmo_hybrids_prefill_programs_hold_the_chunk_kernel(one_chip,
+                                                            monkeypatch,
+                                                            rows):
+    """The four-row and the one-row ``prefill_chunk`` programs of the
+    published configuration as a TPU runs them: twelve calls of the fused
+    chunk kernel stand where the XLA form's scan over eight chunks was
+    (a ``while`` carrying ``f32[rows,30,96,192]``), no block of the solve
+    is left (``fusion f32[8,rows,30,16,288]``, its substitution's rows), the
+    state is never unpacked (no operation of ``[..., 30, 96, 192]``) and
+    no state pool or row of it is copied; every kernel fits the fast
+    memory the compiler gives it (else the compile fails)."""
+    from chipbench import spec
+    from huggingface_sagemaker_tensorflow_distributed_tpu.models.olmo_hybrid import (
+        OlmoHybridForCausalLM,
+        olmo_hybrid_config_from_hf,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.serve import engine
+
+    # the model's chooser and the kernel's wrapper ask jax.devices(): steer
+    # them here, in the test, to lower for the TPU
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: list(one_chip.device_set))
+    cfg = spec.load_json(os.path.join(spec.HERE, "configs",
+                                      "olmo-hybrid-7b-pp2.json"))
+    dep = cfg["deployment"]
+    model = OlmoHybridForCausalLM(olmo_hybrid_config_from_hf(
+        cfg, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    assert model.state_chunk() == "kernel"
+    dummy = jnp.ones((1, 8), jnp.int32)
+    pshape = jax.eval_shape(
+        lambda k: model.init(k, dummy, dummy)["params"], jax.random.PRNGKey(0))
+    plan, pool_shapes = engine.build_cache_plan(model, pshape,
+                                                dep["max_model_len"])
+    token_bytes = sum(h * d * np.dtype(t).itemsize for h, d, t in pool_shapes)
+    blocks = 1 + dep["kv_pool_bytes"] // (dep["block_size"] * token_bytes)
+    n, bs, c = dep["num_slots"], dep["block_size"], dep["prefill_chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    step = jax.jit(
+        lambda p, pools, states, state_rows, *a: engine._prefill_chunk(
+            model, p, pools, *a, plan, False, 1024, states, state_rows),
+        donate_argnums=(1, 2))
+    g = rows
+    compiled = step.lower(
+        jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), pshape),
+        [sds(shape, t) for shape, (_h, _d, t) in zip(
+            engine.pool_dims(plan, pool_shapes, blocks, bs), pool_shapes)],
+        [sds((n,) + shape, jnp.dtype(t)) for shape, t in plan.state_shapes],
+        sds((g,), jnp.int32), sds((g, c), jnp.int32),
+        sds((g, dep["max_model_len"] // bs), jnp.int32),
+        sds((g,), jnp.int32), sds((g,), jnp.int32), sds((g,), jnp.float32),
+        sds((g,), jnp.int32), sds((g,), jnp.float32),
+        sds((g, 2), jnp.uint32), sds((g,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "gated_delta_chunk" in line]
+    assert len(calls) == 12
+    for line in text.splitlines():
+        assert not (" while(" in line and re.search(r",96,(192|384)\]", line)
+                    ), line
+        assert f"f32[8,{g},30,16,288]" not in line, line
+        assert not re.search(r"f32\[\d+,30,96,192\]", line), line
+        assert not re.search(r"f32\[\d+,15,96,384\]\S* copy\(", line), line
 
 
 # -- a prefill dispatch's pages, written where they lie (ISSUE 38) ---------------

@@ -7,6 +7,9 @@ down and what raises by name; the spans and counters; a K/V model's plan,
 programs and telemetry left as they were."""
 
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -279,6 +282,74 @@ def test_state_step_is_on_the_decode_spans_and_in_the_stats(
     assert [p for e in events for p in schema.validate_event(e)] == []
 
 
+@pytest.mark.parametrize("seen", ["xla", "kernel"])
+def test_state_chunk_is_on_the_prefill_spans_and_in_the_stats(
+        tmp_path, monkeypatch, seen):
+    """Four heads of ``8 x 64``: two side by side fill a lane tile, so the
+    state is carried ``[2, 8, 128]`` and the fused chunk kernel has blocks
+    for it. On this CPU a prefill dispatch runs the XLA form (``xla``);
+    steered as a TPU would see it (in the test: the program has no option
+    for it) the dispatches run the kernel, interpreted, and the engine
+    serves the reference's tokens all the same. Every prefill span, the
+    stats, the summary and the report say which; the schema types it, and
+    the telemetry linter passes the run's events."""
+    from huggingface_sagemaker_tensorflow_distributed_tpu.ops import (
+        pallas_gated_delta,
+    )
+    from huggingface_sagemaker_tensorflow_distributed_tpu.obs import schema
+
+    hf = dict(HF, linear_num_key_heads=4, linear_num_value_heads=4,
+              linear_value_head_dim=64)
+    cfg = O.olmo_hybrid_config_from_hf(hf, eos_token_id=100257,
+                                       pad_token_id=0)
+    jax.clear_caches()
+    if seen == "kernel":
+        monkeypatch.setattr(
+            O, "state_chunk", lambda cfg: pallas_gated_delta.state_chunk(
+                cfg.linear_num_heads, cfg.linear_key_head_dim,
+                cfg.linear_value_head_dim, platform="tpu"))
+    model = O.OlmoHybridForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.ones((1, 8), jnp.int32))["params"]
+    obs.reset(out_dir=str(tmp_path / "telemetry"), enabled=True)
+    try:
+        prompts = _prompts(9, (5, 23, 40))
+        eng, reqs = _serve(model, params, prompts, 6, num_slots=2,
+                           num_blocks=40)
+        st = eng.stats()
+        obs.flush()
+        events = _events(tmp_path)
+    finally:
+        obs.reset(enabled=False)
+        jax.clear_caches()
+    assert ((2, 8, 128), "float32") in eng._plan.state_shapes
+    assert st.state_chunk == eng.slo_summary()["state_chunk"] == seen
+    assert st.state_step == "xla"
+    for p, r in zip(prompts, reqs):
+        out = eng.output_ids(r)
+        lg = np.asarray(reference.logits(
+            params, hf, jnp.asarray(np.concatenate([p, out])),
+            jnp.arange(len(p) - 1, len(p) + 5)))
+        assert float((lg.max(-1) - lg[np.arange(6), out]).max()) <= 1e-5
+    spans = [e for e in events if e.get("type") == "span"]
+    pre = [e["args"] for e in spans if e["name"] == "serve/prefill_chunk"]
+    assert pre and all(a["state_form"] == "chunked"
+                       and a["state_chunk"] == seen for a in pre)
+    assert all("state_chunk" not in e["args"] for e in spans
+               if e["name"] == "serve/decode_step")
+    report = [e for e in events if e.get("event") == "report"][-1]
+    assert report["state_chunk"] == seen
+    assert [p for e in events for p in schema.validate_event(e)] == []
+    assert schema.validate_event(dict(report, state_chunk=3))
+    lint = subprocess.run(
+        [sys.executable, os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "scripts",
+            "check_telemetry_schema.py"),
+         str(tmp_path / "telemetry" / "events.jsonl")],
+        capture_output=True, text=True)
+    assert lint.returncode == 0, lint.stdout + lint.stderr
+
+
 # -- a K/V model is left as it was ----------------------------------------------
 
 def _llama():
@@ -312,7 +383,7 @@ def test_a_k_v_model_has_no_state_operand_and_no_state_field(tmp_path):
     assert st.state_pool_bytes is None and st.state_slots_peak is None
     new = {"state_slots", "kv_tokens_resident", "state_slots_peak",
            "prefill_tokens", "state_bytes_per_slot", "state_pool_bytes",
-           "state_form", "state_rows", "state_step"}
+           "state_form", "state_rows", "state_step", "state_chunk"}
     for e in events:
         assert not new & (set(e) | set(e.get("args") or {})), e
     assert "kv_token_bytes" not in eng.slo_summary()
